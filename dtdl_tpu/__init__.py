@@ -38,10 +38,6 @@ utils     flags, seeding, timing, profiling, prototxt parsing
 
 __version__ = "0.1.0"
 
-from dtdl_tpu import _compat
-
-_compat.install()   # jax.shard_map / lax.pcast / jax.typeof on legacy jax
-
 from dtdl_tpu.runtime.mesh import build_mesh, hybrid_mesh, local_mesh  # noqa: F401
 from dtdl_tpu.runtime.bootstrap import initialize, is_leader  # noqa: F401
 from dtdl_tpu.obs import Observer  # noqa: F401

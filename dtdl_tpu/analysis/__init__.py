@@ -4,7 +4,7 @@ Two engines and one gate (ISSUE 15):
 
 * **Repo linter** (:mod:`dtdl_tpu.analysis.lint` +
   :mod:`dtdl_tpu.analysis.rules`) — AST-based, repo-specific rules:
-  the hot-path host-sync ban, the _compat shard_map discipline,
+  the hot-path host-sync ban, the one-spelling jax.shard_map rule,
   donation on state-threading jits, trace hygiene (wall clocks / host
   RNG inside traced functions), and cross-file catalog consistency
   (ServeMetrics counters vs ``_WINDOW_COUNTERS``, emitted event names

@@ -49,8 +49,10 @@ _DTYPE_BYTES = {
 COLLECTIVE_OPS = ("all-reduce", "all-gather", "reduce-scatter",
                   "collective-permute", "all-to-all")
 
+# the shape class admits XLA's ``/*index=5*/`` position comments, which
+# it writes inside long tuple shapes (a combined all-reduce's result)
 _OP_RE = re.compile(
-    r"=\s+(?P<shape>\(?[a-z0-9\[\],{}: ]*?\)?)\s+"
+    r"=\s+(?P<shape>\(?[a-z0-9\[\],{}: /*=]*?\)?)\s+"
     r"(?P<op>" + "|".join(COLLECTIVE_OPS) + r")(?P<start>-start)?\(")
 
 _SHAPE_RE = re.compile(r"(?P<dt>[a-z]+[0-9]*)\[(?P<dims>[0-9,]*)\]")
@@ -67,10 +69,11 @@ _TRANSFER_OPS = ("infeed", "outfeed", "send", "send-done", "recv",
 # be read as a neighbor's (tensor types contain no '{' or ',').  The
 # attrs body allows quoted strings with braces inside: mhlo.sharding
 # values look like "{maximal device=0}" and must not truncate the dict
-# before a later tf.aliasing_output entry.
+# before a later tf.aliasing_output entry; shardy values nest one level
+# of bare braces (sdy.sharding = #sdy.sharding<@mesh, [{"data"}, {}]>).
 _ALIAS_ARG_RE = re.compile(
     r"%arg(?P<idx>\d+):\s*[^{,)]*?"
-    r"\{(?P<attrs>(?:[^{}\"]|\"[^\"]*\")*)\}")
+    r"\{(?P<attrs>(?:[^{}\"]|\"[^\"]*\"|\{(?:[^{}\"]|\"[^\"]*\")*\})*)\}")
 
 _IO_ALIAS_ENTRY_RE = re.compile(r"\(\s*(?P<param>\d+)\s*,")
 

@@ -32,6 +32,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax
+import jax.extend.core as jex_core
 import numpy as np
 
 from dtdl_tpu.analysis.findings import Finding
@@ -41,6 +42,10 @@ from dtdl_tpu.analysis.findings import Finding
 COLLECTIVE_PRIMS = ("psum", "all_gather", "ppermute", "all_to_all",
                     "psum_scatter", "pmax", "pmin")
 _CENSUS_PRIMS = frozenset(COLLECTIVE_PRIMS)
+#: under vma-typed shard_map a collective whose result is replicated
+#: traces as ``<name>_invariant`` — the same data movement, censused
+#: under the plain name
+_INVARIANT = "_invariant"
 
 CALLBACK_PRIMS = frozenset({"pure_callback", "io_callback",
                             "debug_callback", "callback", "outfeed",
@@ -88,9 +93,9 @@ def _jaxprs_in(value):
     cond carries 'branches', custom_vjp carries callables we skip)."""
     vals = value if isinstance(value, (tuple, list)) else (value,)
     for v in vals:
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, jex_core.Jaxpr):
             yield v
 
 
@@ -100,7 +105,7 @@ def census_jaxpr(closed) -> dict:
     n_callbacks = 0
     n_bf16_f32 = 0
     for eqn in _iter_all_eqns(closed.jaxpr):
-        name = eqn.primitive.name
+        name = eqn.primitive.name.removesuffix(_INVARIANT)
         if name in _CENSUS_PRIMS:
             ent = coll.setdefault(name, {"count": 0, "bytes": 0})
             ent["count"] += 1
@@ -113,7 +118,7 @@ def census_jaxpr(closed) -> dict:
             if (getattr(src, "dtype", None) == jax.numpy.bfloat16
                     and getattr(dst, "dtype", None) == np.float32):
                 n_bf16_f32 += 1
-    const_bytes = sum(_aval_bytes(jax.core.get_aval(c))
+    const_bytes = sum(_aval_bytes(jax.typeof(c))
                       for c in closed.consts)
     return {"collectives": {k: coll[k] for k in sorted(coll)},
             "callbacks": n_callbacks,
@@ -142,7 +147,7 @@ def audit_jaxpr(fn, *args, name: str = "program",
                 f"host callback '{eqn.primitive.name}' inside the "
                 f"program — a device->host round-trip every execution"))
     for c in closed.consts:
-        nbytes = _aval_bytes(jax.core.get_aval(c))
+        nbytes = _aval_bytes(jax.typeof(c))
         if nbytes > const_limit:
             shape = getattr(c, "shape", ())
             dtype = getattr(c, "dtype", "?")

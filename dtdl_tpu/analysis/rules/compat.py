@@ -1,17 +1,15 @@
-"""The _compat discipline: one forward-compatible spelling per API.
+"""One spelling per API: ``jax.shard_map``.
 
-dtdl_tpu/_compat.py patches ``jax.shard_map`` / ``lax.pcast`` /
-``jax.typeof`` onto legacy jax at package import, so every call site
-keeps the modern spelling.  A call site that reaches around the shim —
-``from jax.experimental.shard_map import shard_map`` — works on today's
-container and silently breaks (or forks semantics: the shim pins
-``check_rep=False``) when either jax bound moves.  These rules keep the
-shim the single owner of that compatibility decision.
+The installed jax still resolves ``jax.experimental.shard_map`` — as a
+deprecation stub whose ``check_rep``-era keyword surface differs from
+``jax.shard_map``'s vma-typed one — so a second spelling can still be
+written and would fork semantics between call sites.  These rules keep
+the package on the one public spelling.
 
 * ``compat-shard-map`` — any import or attribute reference to
-  ``jax.experimental.shard_map`` outside _compat.py itself.
+  ``jax.experimental.shard_map``.
 * ``compat-maps``     — the removed ``jax.experimental.maps`` /
-  ``xmap`` namespace (predates even the legacy bound this repo shims).
+  ``xmap`` namespace.
 """
 
 from __future__ import annotations
@@ -22,16 +20,14 @@ from dtdl_tpu.analysis.findings import Finding
 from dtdl_tpu.analysis.rules import dotted
 
 RULES = {
-    "compat-shard-map": "jax.experimental.shard_map referenced directly "
-                        "(use jax.shard_map via dtdl_tpu._compat)",
+    "compat-shard-map": "deprecated jax.experimental.shard_map "
+                        "referenced (use jax.shard_map)",
     "compat-maps": "removed jax.experimental.maps/xmap namespace "
                    "referenced",
 }
 
 
 def check(mod) -> list[Finding]:
-    if mod.posix.endswith("dtdl_tpu/_compat.py"):
-        return []            # the shim is the one sanctioned reference
     out = []
     for node in ast.walk(mod.tree):
         ref = None
@@ -53,8 +49,8 @@ def check(mod) -> list[Finding]:
         if ref.startswith("jax.experimental.shard_map"):
             out.append(Finding(
                 "compat-shard-map", mod.path, node.lineno,
-                "bypasses dtdl_tpu._compat — call jax.shard_map (the "
-                "shim owns the legacy-jax fallback + check_rep policy)"))
+                "deprecated spelling — call jax.shard_map (its vma-typed "
+                "autodiff is what the step factories are verified on)"))
         elif ref.startswith("jax.experimental.maps"):
             out.append(Finding(
                 "compat-maps", mod.path, node.lineno,

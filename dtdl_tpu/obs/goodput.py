@@ -17,7 +17,7 @@ roofline continuously instead of in one-off docs.  Three pieces:
   norms, activations) never counted (:func:`lm_rope_hbm_bytes` carries
   the BYTE side of the rope-fusion story instead).
 * **chip peaks** — :func:`peak_flops_per_chip` (public bf16 figures by
-  device_kind; None on CPU and unknown chips).
+  exact device_kind; None on CPU, an unknown accelerator raises).
 * :class:`GoodputMeter` — turns (steps, seconds) windows into the
   metric fields, using only numbers the drain already produced: no
   device syncs, per the PR-1 discipline.
@@ -28,32 +28,41 @@ from __future__ import annotations
 from typing import Optional
 
 
-# Dense bf16 peak FLOP/s per chip, by device_kind substring (longest match
-# wins, so "TPU v5 lite" beats "TPU v5").  Public figures: v2 45T, v3 123T,
-# v4 275T, v5e 197T, v5p 459T, v6e (Trillium) 918T.
+# Dense bf16 peak FLOP/s per chip, keyed by the EXACT ``device_kind`` jax
+# reports (the spellings jax's own pallas tpu_info matches; v5e reports
+# "TPU v5 lite", v5p "TPU v5").  Public figures (Google Cloud TPU docs):
+# v2 45T, v3 123T, v4 275T, v5e 197T, v5p 459T, v6e (Trillium) 918T.
 _PEAK_BF16 = {
     "TPU v2": 45e12,
     "TPU v3": 123e12,
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,
     "TPU v5e": 197e12,
-    "TPU v5p": 459e12,
     "TPU v5": 459e12,
+    "TPU v5p": 459e12,
     "TPU v6 lite": 918e12,
     "TPU v6e": 918e12,
-    "TPU v6": 918e12,
 }
 
 
 def peak_flops_per_chip() -> Optional[float]:
-    """bf16 peak for the local chip, or None if unknown (e.g. CPU)."""
+    """bf16 peak of the local chip, by exact ``device_kind``.
+
+    ``None`` on the cpu platform (no MFU is reported there).  An
+    accelerator whose kind is not in the table raises: a substring guess
+    would print an MFU computed against another chip's peak under this
+    device's name."""
     import jax
-    kind = getattr(jax.devices()[0], "device_kind", "") or ""
-    best = None
-    for k, v in _PEAK_BF16.items():
-        if k in kind and (best is None or len(k) > len(best[0])):
-            best = (k, v)
-    return best[1] if best else None
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
+    if dev.device_kind not in _PEAK_BF16:
+        raise ValueError(
+            f"no bf16 peak on record for device_kind "
+            f"{dev.device_kind!r} (platform {dev.platform!r}); add it to "
+            f"dtdl_tpu/obs/goodput.py:_PEAK_BF16 with its source "
+            f"(known: {sorted(_PEAK_BF16)})")
+    return _PEAK_BF16[dev.device_kind]
 
 
 def lm_forward_flops(cfg, batch: int, seq: int) -> float:

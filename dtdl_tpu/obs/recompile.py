@@ -20,8 +20,8 @@ records.  A callable policy receives the event.
 
 The sentinel reads only host-side jit bookkeeping — no device syncs,
 no effect on what compiles.  Persistent-compile-cache note: a disk
-cache hit (DTDL_TEST_CACHE, opt-in) still *traces* the function, so it
-still counts here — correctly so, because tracing + cache lookup is
+cache hit (dtdl_tpu/runtime/compile_cache.py) still *traces* the
+function, so it still counts here — correctly so, because tracing + cache lookup is
 the stall being policed.  Functions without ``_cache_size`` (plain
 Python callables, non-jit wrappers) pass through unwrapped.
 """

@@ -36,8 +36,8 @@ discipline":
   late by one window, exact in total, never a per-step round-trip.
 
 When a ``jax.profiler`` capture is active, each span also opens a
-``TraceAnnotation`` (via :mod:`dtdl_tpu._compat` — never a hard dep) so
-host phases line up with XLA ops inside one Perfetto view.
+``jax.profiler.TraceAnnotation`` (a TraceMe: ~ns while no capture
+runs) so host phases line up with XLA ops inside one Perfetto view.
 
 **Request correlation (round 16).**  Fleet-era serving spreads one user
 request over many threads — router intake, a pump dispatch, one worker
@@ -70,7 +70,7 @@ import os
 import threading
 import time
 
-from dtdl_tpu import _compat
+from jax.profiler import TraceAnnotation
 
 # synthetic track ids inside the exported trace: host spans carry the
 # real thread id; settled device windows live on their own track
@@ -195,19 +195,16 @@ class _Span:
         self.tracer = tracer
         self.name = name
         self.args = args
-        self._ann = None
+        self._ann = TraceAnnotation(name)
 
     def __enter__(self):
-        self._ann = _compat.trace_annotation(self.name)
-        if self._ann is not None:
-            self._ann.__enter__()
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
+        self._ann.__exit__(*exc)
         self.tracer._record(self.name, self.t0, t1 - self.t0,
                             threading.get_ident(), self.args)
         return False

@@ -39,9 +39,10 @@ Design (the standard TPU flash decomposition):
   swings on block shape alone).  Explicit ``block_q``/``block_k`` args
   still override (the tests' fixed geometries).
 
-On non-TPU backends (the 8-virtual-device CPU test mesh, SURVEY §4) the same
+On the CPU platform (the 8-virtual-device test mesh, SURVEY §4) the same
 kernels run under the Pallas interpreter, so every test exercises the exact
-kernel code path the TPU compiles.
+kernel code path the TPU compiles; any third platform is refused
+(:func:`_use_interpret`).
 """
 
 from __future__ import annotations
@@ -53,25 +54,36 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from dtdl_tpu import _compat
 from dtdl_tpu.ops.rope import rope_rows as _rope_rows
 
 NEG_INF = -1e30
 
 
 def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Whether the Pallas kernels run under the interpreter: yes on the
+    CPU platform (tests), no on TPU (Mosaic).  Any other platform is an
+    error — these are TPU kernels, and a quiet interpreter fallback
+    would report interpreter timings under the device's name."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"dtdl_tpu Pallas kernels run on 'tpu' (Mosaic) or 'cpu' (the "
+        f"interpreter, for tests); the default JAX platform is "
+        f"{platform!r}")
 
 
 def _pallas_kwargs():
     """Shared pallas_call extras: the pipelining hint (outer grid axes
-    parallel, the sequential scratch-carrying axis arbitrary) when this
-    jax can express it.  All three kernels use 3D grids with the inner
-    axis sequential, so one spelling serves them all."""
-    cp = _compat.tpu_compiler_params(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
-    return {"compiler_params": cp} if cp is not None else {}
+    parallel, the sequential scratch-carrying axis arbitrary).  All the
+    kernels use 3D grids with the inner axis sequential, so one spelling
+    serves them all."""
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))}
 
 
 def _vma_of(*arrays):
@@ -389,7 +401,6 @@ def _fwd(q, k, v, tabs, scale, causal, block_q, block_k):
 
 
 def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
     return pltpu.VMEM(shape, dtype)
 
 
@@ -707,8 +718,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
     """Flash attention over [batch, heads, seq, head_dim] tensors.
 
     Differentiable (custom VJP, recompute-based backward); O(seq) memory.
-    Falls back to the Pallas interpreter off-TPU so CPU tests run the same
-    kernel code.
+    On the CPU platform the same kernel code runs under the Pallas
+    interpreter (tests).
 
     ``block_q``/``block_k`` default to the static autotune table
     (:func:`resolve_blocks`, keyed on head_dim / seq bucket / causal —

@@ -28,9 +28,12 @@ table INSIDE the attention loop instead:
   in a 32K arena reads 1 page, not ``n_ptab``;
 * int8/fp8 arenas fuse dequant into the tile loads exactly as the
   gather path does: the per-(page, head, offset) key scales ride a
-  sibling ``[1, 1, page_size]`` tile and multiply the f32 logits
-  BEFORE masking, the value scales fold into the softmax weights
-  (quant/core.py:kv_quantize layout, PR 7);
+  sibling ``[1, H, page_size]`` tile (all heads of the page — Mosaic
+  wants a block's last two dims whole or (8, 128)-aligned, and a
+  single head's ``[1, page_size]`` row is neither; the kernel picks
+  its head's row) and multiply the f32 logits BEFORE masking, the
+  value scales fold into the softmax weights (quant/core.py:kv_quantize
+  layout, PR 7);
 * online softmax in VMEM scratch (m, l, acc — same recurrence as
   ops/attention.py:_fwd_kernel) finalizes once per (b, h).
 
@@ -52,14 +55,13 @@ zeros (the engine discards them; the gather path returns garbage there).
 
 On CPU the kernel runs under the Pallas interpreter (correct but slow —
 tests only); ``paged_kernel_enabled`` routes 'auto' to the gather path
-off-TPU so serving never eats interpreter overhead by accident.
+there so a CPU engine never eats interpreter overhead by accident.
 """
 
 from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
@@ -74,13 +76,14 @@ def paged_kernel_enabled(flag) -> bool:
     """Resolve the engine's ``paged_kernel=`` knob to a bool.
 
     ``True``/``False`` are explicit (True on CPU runs the interpreter —
-    tests and debugging); ``'auto'`` enables the kernel only on a real
-    TPU backend, the documented CPU/interpret auto-fallback.
+    tests and debugging); ``'auto'`` enables the kernel exactly where it
+    compiles through Mosaic (TPU) and takes the gather path where it
+    would be interpreted (CPU); any other platform raises.
     """
     if isinstance(flag, bool):
         return flag
     if flag == "auto":
-        return jax.default_backend() == "tpu"
+        return not _use_interpret()
     raise ValueError(
         f"paged_kernel must be True, False or 'auto', got {flag!r}")
 
@@ -93,8 +96,15 @@ def _kernel(tab_ref, pos_ref, act_ref, *refs, scale, page, s_new, quant,
          o_ref, m_scr, l_scr, acc_scr) = refs
     else:
         q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
-    b, j = pl.program_id(0), pl.program_id(2)
+    b, hh, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nj = pl.num_programs(2)
+
+    def head_row(scale_ref):
+        """This head's [1, page] f32 row of a [1, H, page] scale tile."""
+        tile = scale_ref[0].astype(jnp.float32)               # [H, pg]
+        rows = lax.broadcasted_iota(jnp.int32, tile.shape, 0)
+        return jnp.sum(jnp.where(rows == hh, tile, 0.0), axis=0,
+                       keepdims=True)
 
     @pl.when(j == 0)
     def _init():
@@ -118,7 +128,7 @@ def _kernel(tab_ref, pos_ref, act_ref, *refs, scale, page, s_new, quant,
         if quant:
             # key scale multiplies the logits BEFORE the causal scale
             # and mask — the gather path's exact op order
-            s = s * ks_ref[0, 0].astype(jnp.float32)[None, :]
+            s = s * head_row(ks_ref)
         cols = j * page + lax.broadcasted_iota(
             jnp.int32, (s_new, page), 1)
         qpos = pos_ref[b] + lax.broadcasted_iota(
@@ -135,8 +145,7 @@ def _kernel(tab_ref, pos_ref, act_ref, *refs, scale, page, s_new, quant,
         v = v_ref[0, 0]                            # [pg, D]
         if quant:
             # value scale folds into the softmax weights (as gather)
-            w = (p * vs_ref[0, 0].astype(jnp.float32)[None, :]
-                 ).astype(dtype)
+            w = (p * head_row(vs_ref)).astype(dtype)
             v = v.astype(dtype)
         else:
             w = p.astype(v.dtype)
@@ -191,7 +200,7 @@ def paged_attention(q, pages_k, pages_v, page_table, pos, active, *,
         return (_phys(j, tab, p_, act, bi), hh, 0, 0)
 
     def scale_map(bi, hh, j, tab, p_, act):
-        return (_phys(j, tab, p_, act, bi), hh, 0)
+        return (_phys(j, tab, p_, act, bi), 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, s_new, d), q_map),
@@ -201,8 +210,8 @@ def paged_attention(q, pages_k, pages_v, page_table, pos, active, *,
     operands = [q, pages_k, pages_v]
     if quant:
         in_specs += [
-            pl.BlockSpec((1, 1, page), scale_map),
-            pl.BlockSpec((1, 1, page), scale_map),
+            pl.BlockSpec((1, h, page), scale_map),
+            pl.BlockSpec((1, h, page), scale_map),
         ]
         operands += [key_scale, value_scale]
 

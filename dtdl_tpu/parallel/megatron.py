@@ -49,8 +49,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dtdl_tpu import _compat
-from dtdl_tpu.ops.attention import flash_attention
+from dtdl_tpu.ops.attention import _use_interpret, flash_attention
 from dtdl_tpu.ops.rope import apply_rope, rope_frequencies
 from dtdl_tpu.parallel.sequence import (
     ring_attention, zigzag_order, zigzag_positions,
@@ -270,7 +269,9 @@ def _attention(cfg, p, x, cos, sin):
     sp = lax.axis_size(SEQ)               # static: the mesh is known
     fuse = cfg.fuse_rope
     if fuse == "auto":
-        fuse = jax.default_backend() == "tpu"
+        # fuse where the kernels compile through Mosaic, not where they
+        # would be interpreted (raises on a platform that is neither)
+        fuse = not _use_interpret()
     if fuse and sp == 1:
         # seq axis of 1: no ring hops — the local attend IS the whole
         # sequence, so the rotary embedding rides the flash kernel's
@@ -1024,23 +1025,6 @@ def make_megatron_train_step(cfg: MegatronConfig, mesh: Mesh, optimizer):
     if cfg.schedule == "gpipe" and cfg.virtual_stages != 1:
         raise ValueError("virtual_stages (interleaved schedule) requires "
                          "schedule='1f1b'")
-    if cfg.schedule == "gpipe" and _compat.SHIMMED:
-        # the GPipe schedule is jax.value_and_grad THROUGH shard_map; that
-        # is only correct under vma-typed autodiff (current jax).  The
-        # legacy check_rep=False shard_map transposes psum to psum and
-        # skips the pbroadcast-transposes for replicated params, so the
-        # loss comes out right but the GRADS come out shard-local and
-        # mis-scaled (up to ~10% on the embedding in the oracle tests,
-        # structurally — not fp drift).  Refuse loudly instead of
-        # training garbage; 1f1b (the default) is the same math through
-        # a hand-written VJP and is verified against the oracle on this
-        # jax.  Forward-only GPipe (make_megatron_eval_step) is fine.
-        raise ValueError(
-            "schedule='gpipe' differentiates through shard_map "
-            "collectives, which legacy jax (no vma-typed autodiff; see "
-            "dtdl_tpu/_compat.py SHIMMED) gets wrong — use the default "
-            "schedule='1f1b' on this jax version")
-
     def step(params, opt_state, tokens, targets, mask):
         if cfg.schedule == "1f1b":
             loss, grads, aux = _value_and_grad_1f1b(cfg, params, tokens,

@@ -110,6 +110,25 @@ def default_buckets(max_seq: int, start: int = 16) -> tuple[int, ...]:
     return tuple(out)
 
 
+def _snapshot(x, dtype=None):
+    """A host value as a program input holding what it holds NOW.
+
+    Dispatch is asynchronous, and on the CPU platform ``jnp.asarray`` (and
+    jit's own argument handling) may alias a 64-byte-aligned numpy buffer
+    instead of copying it — so a caller that keeps mutating its array
+    after the call (the scheduler's per-slot page tables and sampling
+    knobs: retire zeroes a table row right after the slot's last decode
+    is dispatched) would change what the already-dispatched program
+    reads.  A private host copy, which nobody mutates, makes "inputs are
+    data as of the call" true on every platform; jit uploads it.  jax
+    Arrays are immutable and pass through.
+    """
+    if isinstance(x, jax.Array):
+        return x if dtype is None else x.astype(dtype)
+    # audit: ok[host-sync-asarray] host-to-host copy of a host array — device arrays returned above
+    return np.array(x, dtype=dtype)
+
+
 def _paged_cache(arena, page_table, active, index=None):
     """Insert the per-call data leaves (page tables + active mask, and
     optionally an index override) into every block's attn cache dict of
@@ -440,8 +459,8 @@ class InferenceEngine:
                 lambda s, sh: jax.device_put(
                     jnp.zeros(s.shape, s.dtype), sh),
                 self.arena_shapes(), self._arena_sh)
-        return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                            self.arena_shapes())
+        return self._beside_params(jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype), self.arena_shapes()))
 
     def init_last_tokens(self):
         """The [n_slots] last-sampled-token vector (NOT donated: the
@@ -449,9 +468,21 @@ class InferenceEngine:
         last = jnp.zeros((self.n_slots,), jnp.int32)
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
-            last = jax.device_put(
+            return jax.device_put(
                 last, NamedSharding(self.mesh, PartitionSpec()))
-        return last
+        return self._beside_params(last)
+
+    def _beside_params(self, tree):
+        """Fresh state the caller threads goes where the params live.
+        When the caller committed the params to a device
+        (``jax.device_put``), every program output is committed too —
+        an uncommitted first arena would then meet a committed second
+        one, and each program would trace twice (the recompile sentinel
+        fires on the second prefill)."""
+        leaf = jax.tree.leaves(self.params)[0]
+        if getattr(leaf, "committed", False):
+            return jax.device_put(tree, leaf.sharding)
+        return tree
 
     # ---- bucketing ----------------------------------------------------
 
@@ -766,7 +797,7 @@ class InferenceEngine:
                 aids = (jnp.zeros((), jnp.int32) if scalar
                         else self._zero_aids)
             else:
-                aids = jnp.asarray(adapter_ids, jnp.int32)
+                aids = _snapshot(adapter_ids, jnp.int32)
             return aids, self.adapter_bank.bank
         if adapter_ids is not None:
             raise ValueError("adapter ids require an adapter bank "
@@ -805,8 +836,8 @@ class InferenceEngine:
                 raise ValueError(f"start={start} must be a non-negative "
                                  f"multiple of page_size="
                                  f"{self.page_size}")
-            # audit: ok[host-sync-asarray] admission-time conversion of the caller's host page_row
-            page_row = np.asarray(page_row, np.int32).ravel()
+            # audit: ok[host-sync-asarray] admission-time COPY of the caller's host page_row, which keeps changing (see _snapshot)
+            page_row = np.array(page_row, np.int32).ravel()
             if page_row.size != self.n_ptab:
                 raise ValueError(f"page_row must have {self.n_ptab} "
                                  f"entries, got {page_row.size}")
@@ -867,7 +898,7 @@ class InferenceEngine:
         if page_tables is None:
             raise ValueError("paged engine needs page_tables (see "
                              "Scheduler)")
-        page_tables = jnp.asarray(page_tables, jnp.int32)
+        page_tables = _snapshot(page_tables, jnp.int32)
         if page_tables.shape != (self.n_slots, self.n_ptab):
             raise ValueError(f"page_tables must be [{self.n_slots}, "
                              f"{self.n_ptab}], got {page_tables.shape}")
@@ -890,9 +921,10 @@ class InferenceEngine:
         allowed = (self._ones_decode if allowed is None
                    else jnp.asarray(pack_mask(allowed)))
         return self._decode_fn(self.params, arena, last_tokens,
-                               jnp.asarray(active),
+                               _snapshot(active),
                                self._tables_arg(page_tables), key,
-                               temp, top_k, top_p, allowed, aids, lora)
+                               _snapshot(temp), _snapshot(top_k),
+                               _snapshot(top_p), allowed, aids, lora)
 
     def verify(self, arena, last_tokens, draft_tokens, draft_len, active,
                key, temp, top_k, top_p, page_tables=None, forced=None,
@@ -930,7 +962,7 @@ class InferenceEngine:
         prompt positions).  ``k`` is a compile shape — one compiled
         program per draft width, see :meth:`compile_stats`.
         """
-        draft_tokens = jnp.asarray(draft_tokens, jnp.int32)
+        draft_tokens = _snapshot(draft_tokens, jnp.int32)
         if draft_tokens.ndim != 2 or draft_tokens.shape[0] != self.n_slots:
             raise ValueError(f"draft_tokens must be [n_slots={self.n_slots}"
                              f", k], got {draft_tokens.shape}")
@@ -943,11 +975,11 @@ class InferenceEngine:
                              f"max_seq={self.max_seq}")
         B = self.n_slots
         forced = (jnp.zeros((B,), bool) if forced is None
-                  else jnp.asarray(forced, bool))
+                  else _snapshot(forced, bool))
         first_tok = (jnp.zeros((B,), jnp.int32) if first_tok is None
-                     else jnp.asarray(first_tok, jnp.int32))
+                     else _snapshot(first_tok, jnp.int32))
         pos_set = (jnp.zeros((B,), jnp.int32) if pos_set is None
-                   else jnp.asarray(pos_set, jnp.int32))
+                   else _snapshot(pos_set, jnp.int32))
         if k not in self._verify_fns:
             fn = self._build_verify(k)
             if self.observer is not None:
@@ -964,10 +996,10 @@ class InferenceEngine:
             allowed = jnp.asarray(pack_mask(allowed))
         return self._verify_fns[k](
             self.params, arena, last_tokens, draft_tokens,
-            jnp.asarray(draft_len, jnp.int32), jnp.asarray(active),
+            _snapshot(draft_len, jnp.int32), _snapshot(active),
             forced, first_tok, pos_set,
-            self._tables_arg(page_tables), key, temp, top_k, top_p,
-            allowed, aids, lora)
+            self._tables_arg(page_tables), key, _snapshot(temp),
+            _snapshot(top_k), _snapshot(top_p), allowed, aids, lora)
 
     # ---- prefill/decode disaggregation: page-granular KV handoff ------
 

@@ -1563,7 +1563,7 @@ class Scheduler:
             step_act &= self._active     # growth may have shed slots
             if not step_act.any():
                 return
-            tables = self._ptab          # snapshot copied at dispatch
+            tables = self._ptab          # the engine snapshots it at dispatch
         if k_need > 0:
             entries = []
             for slot in range(B):

@@ -55,9 +55,9 @@ def init_state(model, rng, example_input, tx) -> TrainState:
     """Initialize model variables and wrap them in a TrainState.
 
     The whole initialization (flax init + optimizer slot init) runs under one
-    jit: eager init would dispatch thousands of tiny ops one by one, which is
-    pathologically slow on remote/tunneled TPU backends (minutes for a
-    110-layer model vs seconds jitted).
+    jit: eager init would dispatch (and compile) thousands of tiny ops one by
+    one — minutes for a 110-layer model on an accelerator vs seconds
+    jitted.
     """
     def build(rng):
         variables = model.init(rng, example_input, train=False)

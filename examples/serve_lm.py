@@ -30,7 +30,7 @@ from dtdl_tpu.utils.config import flag, make_parser
 def main():
     parser = make_parser("dtdl_tpu: batched LM serving")
     flag(parser, "--model-size", default="tiny",
-         choices=["tiny", "small", "base"])
+         choices=["tiny", "small", "base", "large", "base-moe8"])
     flag(parser, "--restore", default="",
          help="msgpack weights to serve (default: random init)")
     flag(parser, "--n-slots", type=int, default=4,

@@ -41,7 +41,7 @@ def main():
     flag(parser, "--strategy", default="auto",
          choices=["auto", "single", "dp", "ddp"])
     flag(parser, "--model-size", default="tiny",
-         choices=["tiny", "small", "base"])
+         choices=["tiny", "small", "base", "large", "base-moe8"])
     flag(parser, "--seq-len", type=int, default=128)
     flag(parser, "--attn", default="flash", choices=["flash", "dense"])
     flag(parser, "--vocab-chunk-size", type=int, default=0,
@@ -49,7 +49,8 @@ def main():
               "(e.g. 2048) — the [B,S,V] logits are never materialized, "
               "so large-vocab models fit at long sequence")
     flag(parser, "--n-experts", type=int, default=0,
-         help=">0: switch-MoE MLPs with this many experts")
+         help=">0: switch-MoE MLPs with this many experts (0 keeps the "
+              "preset's own MLPs — 'base-moe8' is routed MoE already)")
     flag(parser, "--moe-dispatch", default="dense",
          choices=["dense", "routed"],
          help="MoE dispatch: dense one-hot oracle, or GShard-style "
@@ -83,12 +84,14 @@ def main():
     strategy = choose_strategy(args.strategy)
 
     train_tokens, _ = load_dataset(args.dataset, seq_len=args.seq_len)
+    # the MoE flags override the preset only when asked for, so the
+    # 'base-moe8' preset keeps its own experts
+    moe = dict(n_experts=args.n_experts, moe_dispatch=args.moe_dispatch,
+               capacity_factor=args.capacity_factor,
+               moe_top_k=args.moe_top_k,
+               moe_group_size=args.moe_group_size) if args.n_experts else {}
     model = transformer_lm(args.model_size, max_seq=args.seq_len,
-                           attn_impl=args.attn, n_experts=args.n_experts,
-                           moe_dispatch=args.moe_dispatch,
-                           capacity_factor=args.capacity_factor,
-                           moe_top_k=args.moe_top_k,
-                           moe_group_size=args.moe_group_size)
+                           attn_impl=args.attn, **moe)
     if train_tokens.max() >= model.vocab_size:
         raise SystemExit("dataset vocab exceeds model vocab")
 

@@ -58,8 +58,8 @@ def bench(size, bs, seq, chunk, remat=None, iters=30, warmup=5):
         "tokens_per_sec": round(bs * (seq - 1) * iters / dt, 0),
         "xla_flops": xla_flops, "analytic_flops": af,
     }
-    if peak:   # omit MFU on chips without a known bf16 peak (bench.py's
-        row["mfu_xla"] = round(xla_flops * iters / dt / peak, 4)   # pattern)
+    if peak:   # None only on the cpu platform: no MFU there
+        row["mfu_xla"] = round(xla_flops * iters / dt / peak, 4)
         row["mfu_analytic"] = round(af * iters / dt / peak, 4)
     return row
 

@@ -44,7 +44,7 @@ BAD = {
             metrics.append(float(jnp.mean(arena)))  # PLANT:host-sync-float
             return np.asarray(arena), host          # PLANT:host-sync-asarray
     """,
-    # _compat bypass + missing donation in a step factory
+    # deprecated shard_map spelling + missing donation in a step factory
     "dtdl_tpu/parallel/bad_compat.py": """
         import jax
         from jax.experimental.shard_map import shard_map  # PLANT:compat-shard-map

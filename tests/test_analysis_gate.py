@@ -1,7 +1,7 @@
 """The tier-1 lint gate (ISSUE 15): dtdl_tpu/ must audit clean.
 
 AST-only — no compilation, seconds — so the invariants the repo's
-performance story rests on (no hot-path host syncs, _compat-owned
+performance story rests on (no hot-path host syncs, one-spelling
 shard_map, donation on step jits, catalog consistency) fail HERE, by
 rule id, instead of surfacing as a mystery MFU drop three PRs later.
 """

@@ -33,6 +33,26 @@ def test_local_launcher_two_process_ddp(capfd):
     assert results[0][3] == results[1][3]
 
 
+def test_local_launcher_refuses_sibling_processes_on_one_tpu(monkeypatch):
+    """Several children that would each open the host's TPU are refused
+    by name before anything spawns; CPU worlds and single processes are
+    not the launcher's business."""
+    from dtdl_tpu.launch import local
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    with pytest.raises(RuntimeError, match="one.*process"):
+        local.launch_local(["-c", "raise SystemExit(0)"], nproc=2)
+    # jax picks the TPU itself when nothing is named and chips exist
+    monkeypatch.delenv("JAX_PLATFORMS")
+    monkeypatch.setattr(local, "_host_has_tpu", lambda: True)
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS=''"):
+        local.launch_local(["-c", "raise SystemExit(0)"], nproc=2)
+    # one process, a carved CPU world, and a CPU-only env all go through
+    local._refuse_tpu_siblings(1, None, {})
+    local._refuse_tpu_siblings(2, 2, {})
+    local._refuse_tpu_siblings(2, None, {"JAX_PLATFORMS": "cpu"})
+
+
 def test_local_launcher_fail_fast():
     """A dying rank must terminate the job, not hang it (SURVEY §5.3)."""
     from dtdl_tpu.launch.local import launch_local

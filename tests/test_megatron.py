@@ -12,31 +12,19 @@ import numpy as np
 import optax
 import pytest
 
-from dtdl_tpu import _compat
 from dtdl_tpu.ops.attention import mha_reference
 from dtdl_tpu.ops.rope import apply_rope, rope_frequencies
 from dtdl_tpu.parallel import megatron as M
 
 
-# Sharded-step-vs-oracle parameter tolerance.  On current jax the updates
-# agree to 2e-4; this container's legacy jax 0.4.x emits differently-ordered
-# XLA:CPU reductions for the shard_map step (cross-version fp drift, see
-# CHANGES.md PR 1), and the reassociation amplifies through two sensitive
-# spots — MoE top-1 routing near-ties (an expert flip rewrites a whole
-# token's grads while barely moving the loss) and the RMSNorm rsqrt chain —
-# to ~4e-3 on single leaves even though the LOSS still matches to 1e-5.
-# Widened with 2x margin, NOT skipped — and only on shimmed jax, so the
-# tight 2e-4 bound keeps guarding current-jax runs: a real semantic
-# divergence (wrong collective, wrong schedule order) must not hide
-# inside the legacy allowance.
-PARAM_TOL = (dict(atol=8e-3, rtol=8e-3) if _compat.SHIMMED
-             else dict(atol=2e-4, rtol=2e-4))
-# same story for the same-engine resume-equivalence comparisons: bitwise
-# on current jax (keep the 1e-6 guard there — a restore bug must not hide
-# under the oracle tolerance), ~1e-3 relative after restore on legacy
-# (re-lowering for restored buffer layouts reorders reductions)
-LOSS_RTOL = 2e-3 if _compat.SHIMMED else 1e-6
-CKPT_PARAM_TOL = PARAM_TOL if _compat.SHIMMED else dict(rtol=1e-6)
+# Sharded-step-vs-oracle parameter tolerance: the updates agree to 2e-4.
+# Kept tight on purpose — a real semantic divergence (wrong collective,
+# wrong schedule order) must not hide inside a wide allowance.
+PARAM_TOL = dict(atol=2e-4, rtol=2e-4)
+# same-engine resume-equivalence comparisons are bitwise up to 1e-6 (a
+# restore bug must not hide under the oracle tolerance)
+LOSS_RTOL = 1e-6
+CKPT_PARAM_TOL = dict(rtol=1e-6)
 
 
 def _cfg(**kw):
@@ -156,17 +144,6 @@ def oracle_eval(cfg, params, tokens, targets, mask):
     (4, "gpipe", "dense"), (4, "1f1b", "routed"), (4, "gpipe", "routed"),
 ])
 def test_4d_step_matches_oracle(devices, n_experts, schedule, dispatch):
-    if schedule == "gpipe" and _compat.SHIMMED:
-        # NOT a tolerance miss: GPipe differentiates through shard_map
-        # collectives, and this container's legacy jax (check_rep=False,
-        # no vma autodiff) mis-transposes them — grads come out
-        # shard-local/mis-scaled (embedding off ~10% structurally) while
-        # the loss matches bitwise.  make_megatron_train_step now refuses
-        # gpipe on legacy jax (pinned below); the schedule stays verified
-        # against this oracle on current jax.
-        pytest.skip("gpipe autodiff needs vma-typed shard_map; legacy "
-                    "jax is guarded by a named error (pinned in "
-                    "test_gpipe_refused_on_legacy_jax)")
     # routed dispatch with capacity_factor == n_experts can never drop a
     # token, so it computes the identical function to the dense oracle
     cfg = _cfg(n_experts=n_experts, schedule=schedule, moe_dispatch=dispatch,
@@ -705,27 +682,6 @@ def test_serve_engine_bridges_4d_training_to_serving(devices):
     for req, prompt in zip(reqs, prompts):
         assert req.tokens == ref_greedy(engine.model, engine.params,
                                         prompt, 4)
-
-
-def test_gpipe_refused_on_legacy_jax(devices):
-    """On a jax whose shard_map lacks vma-typed autodiff, building a
-    gpipe TRAIN step must fail with the named error (silently-wrong
-    gradients otherwise); the gpipe FORWARD (eval step) stays allowed."""
-    if not _compat.SHIMMED:
-        pytest.skip("current jax: gpipe autodiff is supported (and "
-                    "oracle-verified by test_4d_step_matches_oracle)")
-    cfg = _cfg(schedule="gpipe")
-    mesh = M.build_4d_mesh(devices)
-    with pytest.raises(ValueError, match="vma"):
-        M.make_megatron_train_step(cfg, mesh, optax.sgd(0.1))
-    # forward-only gpipe is correct on any jax (no autodiff through it)
-    eval_step = M.make_megatron_eval_step(cfg, mesh)
-    params = M.place_params(mesh, cfg,
-                            M.init_params(cfg, jax.random.PRNGKey(0)))
-    batch = M.shard_lm_batch(mesh, _batch(cfg))
-    got = eval_step(params, batch["tokens"], batch["targets"],
-                    batch["mask"])
-    assert np.isfinite(float(got["loss"]))
 
 
 # ---------------------------------------------------------------------------

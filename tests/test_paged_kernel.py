@@ -33,7 +33,7 @@ import pytest
 from dtdl_tpu.models.transformer import transformer_lm
 from dtdl_tpu.obs import Observer
 from dtdl_tpu.ops.paged_attention import paged_attention, paged_kernel_enabled
-from dtdl_tpu.quant import kv_quantize
+from dtdl_tpu.quant import canon_kv_dtype, kv_quantize
 from dtdl_tpu.serve import InferenceEngine, NGramDraft, Request, Scheduler
 
 MAX_SEQ = 48
@@ -111,21 +111,24 @@ def _pool_case(seed, quant, *, nan_tail=False, b=3, h=2, n_ptab=4,
     pk, pv = jnp.asarray(kf), jnp.asarray(vf)
     ks = vs = None
     if quant:
-        pk, ks = kv_quantize(pk)
-        pv, vs = kv_quantize(pv)
+        # True = int8 payload + f32 scales; 'fp8' = fp8 payload + bf16
+        # scales (the [1, H, page] scale tile holds either dtype)
+        kv_dtype = canon_kv_dtype("int8" if quant is True else quant)
+        pk, ks = kv_quantize(pk, dtype=kv_dtype)
+        pv, vs = kv_quantize(pv, dtype=kv_dtype)
         if nan_tail:
             # poison the dead pages' SCALES too (per-row scales of live
             # pages are untouched, so they still match a clean pool)
             dead_mask = ~np.isin(np.arange(n_pages),
                                  list(live))[:, None, None]
-            ks = jnp.asarray(np.where(dead_mask, np.nan, np.asarray(ks)))
-            vs = jnp.asarray(np.where(dead_mask, np.nan, np.asarray(vs)))
+            ks = jnp.where(dead_mask, jnp.nan, ks)
+            vs = jnp.where(dead_mask, jnp.nan, vs)
     return pk, pv, ks, vs, jnp.asarray(table), jnp.asarray(pos), \
         jnp.asarray(active)
 
 
 @pytest.mark.parametrize("s_new", [1, 5])
-@pytest.mark.parametrize("quant", [False, True])
+@pytest.mark.parametrize("quant", [False, True, "fp8"])
 def test_kernel_matches_gather_reference(s_new, quant):
     pk, pv, ks, vs, table, pos, active = _pool_case(0, quant)
     b, h, d = table.shape[0], pk.shape[1], pk.shape[3]

@@ -266,3 +266,28 @@ def test_engine_rejects_bad_inputs(engine):
         Request([1, 2], 0)
     with pytest.raises(ValueError, match="temperature"):
         SampleParams(temperature=-1.0)
+
+
+def test_engine_inputs_are_snapshots_not_aliases():
+    """What the engine hands a program is the host value AS OF THE CALL.
+    On XLA:CPU a 64-byte-aligned numpy buffer may be aliased rather than
+    copied, and dispatch is async — so without the snapshot a scheduler
+    that zeroes a slot's page-table row right after dispatching its last
+    decode changes what that decode reads (the cause of the once-flaky
+    greedy token-identity pins)."""
+    from dtdl_tpu.serve.engine import _snapshot
+
+    raw = np.zeros(2 * 6 * 4 + 64, np.uint8)
+    off = (-raw.ctypes.data) % 64                 # force the aliasable case
+    table = raw[off:off + 48].view(np.int32).reshape(2, 6)
+    busy = jnp.ones((1500, 1500))
+    f = jax.jit(lambda t, x: t + 0 * x.sum().astype(jnp.int32))
+    for _ in range(5):
+        table[:] = 3
+        y = busy @ busy @ busy                    # keep the queue busy
+        out = f(_snapshot(table, jnp.int32), y)
+        table[:] = 0                              # "retire" after dispatch
+        assert np.asarray(out).tolist() == [[3] * 6] * 2
+    # device arrays pass through untouched (no host round trip)
+    dev = jnp.arange(3)
+    assert _snapshot(dev) is dev

@@ -11,32 +11,9 @@ import optax
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from dtdl_tpu import _compat
 from dtdl_tpu.models import transformer_lm
 from dtdl_tpu.parallel import tensor as T
 from dtdl_tpu.runtime.mesh import build_mesh
-
-# The oracle-equality tests below compare GSPMD-partitioned compute
-# against replicated compute at tight (1e-5 .. 2e-4) tolerances.  On
-# this container's legacy jax 0.4.x the XLA:CPU SPMD partitioner itself
-# is off: ONE f32 forward of the sharded tiny LM differs from the
-# replicated forward by ~2e-3 relative loss and ~7e-3 abs grads —
-# orders beyond fp reassociation, diagnosed as legacy partitioner
-# numerics (CHANGES.md PR 2/PR 4; the megatron fp-drift class is 100x
-# smaller).  Mirroring the gpipe treatment: skip WITH the diagnosis on
-# shimmed jax only, instead of widening oracle tolerances to ~1e-2
-# where they would mask real partitioning bugs on current jax.  The
-# skip is itself pinned by test_legacy_partitioner_skip_is_gated.
-_LEGACY_SPMD_REASON = (
-    "legacy XLA:CPU SPMD partitioner numerics (~2e-3 rel loss / ~7e-3 "
-    "abs grads on a single sharded forward): oracle equality is only "
-    "checkable on current jax; tolerances stay tight there instead of "
-    "being widened 100x to absorb a legacy-backend artifact")
-
-
-def _skip_on_legacy_partitioner():
-    if _compat.SHIMMED:
-        pytest.skip(_LEGACY_SPMD_REASON)
 
 
 def _setup(devices, rules):
@@ -75,7 +52,6 @@ def test_param_shardings(devices, rules, dim, axis):
 
 
 def test_presets_match_replicated(devices):
-    _skip_on_legacy_partitioner()
     ref, _ = _losses(devices, "replicated")
     for rules in ("tp", "fsdp", "tp_fsdp"):
         got, _ = _losses(devices, rules)
@@ -106,7 +82,6 @@ def test_preset_grads_match_replicated(devices, rules):
     """Oracle-equal GRADIENTS per preset (megatron evidence standard,
     tests/test_megatron.py): XLA's partitioning of the backward pass must
     not change the math, leaf by leaf, at 1e-5."""
-    _skip_on_legacy_partitioner()
     ref = _grad_fn(devices, "replicated")
     got = _grad_fn(devices, rules)
     for (path_a, a), (_, b) in zip(
@@ -158,7 +133,6 @@ def test_routed_moe_trains_sharded_and_matches_replicated(devices):
     replicated run, and (capacity permitting) to the dense-dispatch
     oracle: XLA's partitioning of the all-to-all dispatch einsums must
     not change the math."""
-    _skip_on_legacy_partitioner()
     mesh = build_mesh(shape=(2, 4), axes=("data", "model"),
                       devices=devices)
     tx = optax.adamw(1e-3)
@@ -206,7 +180,6 @@ def test_sharded_eval_matches_unsharded(devices):
     """make_sharded_lm_eval_step: loss/accuracy identical to an
     unsharded evaluation of the same params, on 'tp' and 'ep' rules
     (routed MoE under ep)."""
-    _skip_on_legacy_partitioner()
     mesh = build_mesh(shape=(2, 4), axes=("data", "model"),
                       devices=devices)
     tx = optax.adamw(1e-3)
@@ -252,7 +225,6 @@ def test_tp_sharded_decode_token_identical(devices):
     heads sharding from wq/wk/wv) — tokens identical to the unsharded
     run, so a model too big for one chip decodes the same way it
     trains."""
-    _skip_on_legacy_partitioner()
     import flax.linen as nn
 
     from dtdl_tpu.models.transformer import generate, transformer_lm
@@ -274,25 +246,6 @@ def test_tp_sharded_decode_token_identical(devices):
     # the sharded run really was sharded: heads-dim kernel partitioned
     q = params_sh["block_0"]["attn"]["q"]["kernel"]
     assert q.sharding.spec[1] == "model"
-
-
-def test_legacy_partitioner_skip_is_gated():
-    """The oracle skips above exist ONLY for the legacy-jax container:
-    on current jax the GSPMD oracle tests must run for real, and the
-    skip reason must keep naming the diagnosis (not a tolerance story —
-    widening to ~1e-2 would blind the oracle on every backend)."""
-    if not _compat.SHIMMED:
-        # current jax: the gate must be OFF — a wrongly-armed skip here
-        # would silently blind all six GSPMD oracle tests
-        try:
-            _skip_on_legacy_partitioner()
-        except pytest.skip.Exception:
-            pytest.fail("legacy-partitioner gate fired on current jax")
-        return
-    assert "partitioner numerics" in _LEGACY_SPMD_REASON
-    assert "current jax" in _LEGACY_SPMD_REASON
-    with pytest.raises(pytest.skip.Exception):
-        _skip_on_legacy_partitioner()
 
 
 def test_autosharded_per_leaf_spec_through_train_step(devices):
