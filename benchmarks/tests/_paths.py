@@ -1,0 +1,11 @@
+"""Where the benchmark and the repo are, for the tests; importing it puts
+both on ``sys.path`` (``lib``, ``runners``, ``run`` and ``dtdl_tpu``)."""
+
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+for p in (BENCH, ROOT):
+    if p not in sys.path:
+        sys.path.insert(0, p)
