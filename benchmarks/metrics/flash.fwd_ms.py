@@ -1,0 +1,9 @@
+"""Device time of the flash forward kernel (``flash_fwd``: once a layer in
+the forward pass, once more under ``remat``) in one traced step."""
+
+from lib import program_names
+
+
+def read(record):
+    return program_names.kernel_ms_per_step(record.get("trace"),
+                                            program_names.FLASH_FWD_EVENT)

@@ -413,7 +413,8 @@ def main(argv=None) -> int:
 
     import jax
 
-    from dtdl_tpu.runtime.compile_cache import enable_compile_cache
+    from dtdl_tpu.runtime.compile_cache import (compile_totals,
+                                                enable_compile_cache)
 
     cache_dir = enable_compile_cache()
     dev = jax.devices()[0]
@@ -450,6 +451,12 @@ def main(argv=None) -> int:
     if device["count"] >= 4:
         megatron_phase(plan, model)
 
+    # where the start went: jax's own account of every trace, lowering,
+    # compile and cache look-up of this process (smoke, not a benchmark)
+    print("compile account: " + ", ".join(
+        f"{k.removeprefix('compile_')} "
+        f"{v if isinstance(v, int) else format(v, '.1f')}"
+        for k, v in compile_totals().items()), flush=True)
     result = {"ok": True, "device": device}
     if args.rehearse:
         result["rehearsal"] = True

@@ -1062,7 +1062,11 @@ class TransformerLM(nn.Module):
             "embed", _part(nn.initializers.normal(stddev=0.02),
                            "vocab", "embed"),
             (self.vocab_size, self.d_model))
-        x = jnp.take(emb, tokens, axis=0).astype(self.dtype)
+        # the model's own two ops outside any flax submodule carry a scope
+        # of their own (obs/trace.py:DEVICE_SCOPES), or a device trace can
+        # tell them from the blocks by operand names alone
+        with jax.named_scope("embed"):
+            x = jnp.take(emb, tokens, axis=0).astype(self.dtype)
         cos, sin = rope_frequencies(self.head_dim, self.max_seq)
 
         # remat is a training-time memory/FLOPs trade; under decode it
@@ -1093,8 +1097,9 @@ class TransformerLM(nn.Module):
         x = RMSNorm(dtype=self.dtype, name="ln_f")(x)
         if return_hidden:
             return x
-        logits = jnp.einsum("bsd,vd->bsv", x, emb.astype(self.dtype))
-        return logits.astype(jnp.float32)
+        with jax.named_scope("head"):
+            logits = jnp.einsum("bsd,vd->bsv", x, emb.astype(self.dtype))
+            return logits.astype(jnp.float32)
 
 
 def generate(model: TransformerLM, params, prompt, max_new_tokens: int,

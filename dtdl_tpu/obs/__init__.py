@@ -8,7 +8,8 @@ add a host↔device sync to a hot loop.  See each module's docstring:
 trace      span("data"/"dispatch"/"drain") → Chrome trace JSON (Perfetto),
            window-settled device track, jax.profiler annotations,
            request-correlated flow events + request_timeline(rid), the
-           audited span/event catalogs
+           audited span/event catalogs, and the catalogue of device-side
+           names (scopes, kernel and step names) with device_component()
 recompile  jit-cache sentinel: unexpected retraces are named, with the
            differing abstract args (warn / raise / silent)
 goodput    analytic model FLOPs (LM from config, CNNs from netspec),
@@ -48,6 +49,6 @@ from dtdl_tpu.obs.slo import (  # noqa: F401
     SLO, SLOEvaluator, default_train_slos,
 )
 from dtdl_tpu.obs.trace import (  # noqa: F401
-    EVENT_CATALOG, NULL_TRACER, SPAN_CATALOG, Tracer, aggregate,
-    corr_rid, proc_tag, set_proc_tag, xla_events,
+    DEVICE_SCOPES, EVENT_CATALOG, NULL_TRACER, SPAN_CATALOG, Tracer,
+    corr_rid, device_component, proc_tag, set_proc_tag,
 )

@@ -28,6 +28,7 @@ from dtdl_tpu.obs.goodput import GoodputMeter
 from dtdl_tpu.obs.hist import LogHistogram
 from dtdl_tpu.obs.recompile import (NULL_SENTINEL, RecompileSentinel)
 from dtdl_tpu.obs.trace import NULL_TRACER, Tracer
+from dtdl_tpu.runtime.compile_cache import compile_totals
 
 
 class Observer:
@@ -108,11 +109,13 @@ class Observer:
 
     def summary(self) -> dict:
         """Run-level rollup: step-time tails, goodput totals, sentinel
-        events, trace volume."""
+        events, the process's compile account (once an entry point has
+        called ``enable_compile_cache``), trace volume."""
         out = dict(self.step_time_s.summary("step_time_s_"))
         if self.goodput is not None:
             out.update(self.goodput.totals())
         out.update(self.sentinel.summary())
+        out.update(compile_totals())
         n = len(self.tracer)
         if n:
             out["trace_events"] = n

@@ -20,9 +20,7 @@ and the fleet layer its health/lifecycle edges (``replica_suspect`` /
 ``router_shutdown``), so a trace shows exactly where a run skipped,
 rolled back, shed load, or failed over.  Everything is
 thread-safe for the serve scheduler, exported as Chrome-trace-event JSON
-that Perfetto / ``chrome://tracing`` loads directly — the same format
-the XLA profiler emits, so the two traces read with the same tools
-(:func:`xla_events` below parses either).
+that Perfetto / ``chrome://tracing`` loads directly.
 
 Two honesty rules, inherited from SCALING.md "Async dispatch
 discipline":
@@ -59,6 +57,15 @@ The span/event catalogs below (:data:`SPAN_CATALOG` /
 anywhere in dtdl_tpu; tests/test_obs_export.py audits the source tree
 against them, so the catalog can no longer silently lag a new emitter
 (it did twice between PR 5 and PR 9).
+
+**Device-side names.**  The host spans above never reach the device; what
+a ``jax.profiler`` capture shows of the device is named by the program at
+compile time, at no run-time cost: ``jax.named_scope`` around the train
+step's own phases (:data:`DEVICE_SCOPES`, audited like the span catalog),
+flax's module scopes inside the blocks (:data:`MODULE_SCOPES`), a ``name=``
+on every Pallas kernel (:data:`KERNEL_NAMES`) and a name of its own on each
+jitted step (:data:`STEP_NAMES`).  :func:`device_component` is the one map
+from an op's name stack to ``(component, pass)``.
 """
 
 from __future__ import annotations
@@ -67,6 +74,7 @@ import contextlib
 import gzip
 import json
 import os
+import re
 import threading
 import time
 
@@ -145,6 +153,95 @@ EVENT_CATALOG = frozenset({
     "page_spilled", "page_restored", "prefix_directory_hit",
     "prefix_directory_invalidated",
 })
+
+
+# ---------------------------------------------------------------------------
+# device-side names: what the program puts into the compiled step so that a
+# device trace can be read by component.  All of it is compile-time
+# metadata.  tests/test_obs_export.py holds DEVICE_SCOPES, KERNEL_NAMES
+# and STEP_NAMES to the source tree (every ``named_scope("...")`` literal
+# under dtdl_tpu/, and the reverse); tests/test_device_names.py holds
+# MODULE_SCOPES to the model's own module tree and the lowered LM step to
+# the whole catalogue.
+# ---------------------------------------------------------------------------
+
+# jax.named_scope literals, each a component of its own.  ``embed`` /
+# ``head`` sit in TransformerLM.__call__ (the two ops outside any flax
+# submodule); the rest in train/step.py.  With ``vocab_chunk_size > 0`` the
+# head matmul runs inside the chunked loss, tile by tile, and so under
+# ``loss``.
+DEVICE_SCOPES = frozenset({"embed", "head", "loss", "grad_sync", "update",
+                           "guard"})
+
+# flax module scopes of models/transformer.py (flax puts them there; this
+# repo only names the modules) -> component
+MODULE_SCOPES = {
+    "attn": "attn_other", "mlp": "mlp", "moe": "moe",
+    "ln_attn": "norm", "ln_mlp": "norm", "ln_f": "norm",
+}
+_ATTN_PROJECTIONS = frozenset({"q", "k", "v", "out"})
+
+# pallas_call ``name=`` -> component.  On the chip the kernel's HLO
+# instruction takes this name (``%flash_fwd.<n> = ... custom-call(...)
+# custom_call_target="tpu_custom_call"``), and it is a scope of the
+# kernel's own ops in a name stack.
+KERNEL_NAMES = {
+    "flash_fwd": "flash", "flash_bwd_dq": "flash", "flash_bwd_dkv": "flash",
+    "paged_attn": "paged_attn",
+}
+
+# names of the traced step functions of train/step.py: ``jit(<name>)`` in
+# ``XLA Modules`` events, ``jax.log_compiles`` and the ``fun_name`` of jax's
+# compile events, by which a reader of the compile account
+# (runtime/compile_cache.py) tells the step's program from every other
+STEP_NAMES = frozenset({"lm_train_step", "train_step", "eval_step",
+                        "predict_step"})
+
+_AFTER_BACKWARD = frozenset({"grad_sync", "update", "guard"})
+_WRAPPED = re.compile(r"^(\w+)\((.*)\)$")
+
+
+def device_component(name_stack: str):
+    """``(component, pass)`` of an op from its name stack, the ``/``-joined
+    string jax gives every op (``jit(lm_train_step)/transpose(jvp(
+    TransformerLM))/jvp(TransformerLM)/checkpoint/rematted_computation/
+    block_3/attn/q/dot_general``; the last element is the primitive).
+
+    ``component`` is a member of :data:`DEVICE_SCOPES`, a value of
+    :data:`MODULE_SCOPES` or :data:`KERNEL_NAMES`, ``attn_proj`` for
+    ``attn/{q,k,v,out}``, or None where no catalogued scope is on the
+    stack.  ``pass`` is ``update`` for what follows the backward pass
+    (``grad_sync``, ``update``, ``guard``); else ``recompute`` under
+    ``rematted_computation``, ``backward`` under a ``transpose(...)``,
+    ``forward`` otherwise.  jax wraps a scope entered inside a transformed
+    function in the transform's name (``jvp(loss)``,
+    ``transpose(jvp(loss))``); the wrappers are read for the pass and
+    stripped for the name."""
+    names, transposed = [], False
+    for element in name_stack.split("/"):
+        while (m := _WRAPPED.match(element)):
+            transposed = transposed or m.group(1) == "transpose"
+            element = m.group(2)
+        names.append(element)
+    component = None
+    for i, name in enumerate(names):
+        component = (name if name in DEVICE_SCOPES else
+                     MODULE_SCOPES.get(name) or KERNEL_NAMES.get(name))
+        if component is None:
+            continue
+        if name == "attn":
+            rest = names[i + 1:]
+            kernels = [KERNEL_NAMES[n] for n in rest if n in KERNEL_NAMES]
+            if kernels:
+                component = kernels[0]
+            elif rest and rest[0] in _ATTN_PROJECTIONS:
+                component = "attn_proj"
+        break
+    if component in _AFTER_BACKWARD:
+        return component, "update"
+    if "rematted_computation" in names:
+        return component, "recompute"
+    return component, "backward" if transposed else "forward"
 
 
 # ---------------------------------------------------------------------------
@@ -245,18 +342,6 @@ class Tracer:
                 "pid": self._meta["pid"],
                 "tid": threading.get_ident(),
                 **({"args": args} if args else {})})
-
-    def counter(self, name: str, value: float) -> None:
-        """A counter sample (Perfetto renders these as a line track)."""
-        with self._lock:
-            if len(self._events) >= self.max_events:
-                self.dropped += 1
-                return
-            self._events.append({
-                "name": name, "ph": "C",
-                "ts": (time.perf_counter() - self._t0) * 1e6,
-                "pid": self._meta["pid"], "tid": 0,
-                "args": {"value": value}})
 
     _FLOW_PH = {"start": "s", "step": "t", "end": "f"}
 
@@ -361,58 +446,6 @@ class Tracer:
         return path
 
 
-# ---------------------------------------------------------------------------
-# jax.profiler trace parsing (folded in from scripts/trace_utils.py — the
-# script path re-exports these, so existing `from trace_utils import ...`
-# callers keep working)
-# ---------------------------------------------------------------------------
-
-# On this backend the XLA op events live at pid 3 / tid 3; each carries
-# ``hlo_category`` and ``bytes_accessed`` in its args.
-XLA_PID = XLA_TID = 3
-
-
-def xla_events(trace_dir: str) -> list:
-    """XLA op events of the newest jax.profiler trace under ``trace_dir``.
-
-    The tensorboard_plugin_profile converter is incompatible with this
-    box's TF, so the raw Chrome-trace JSON is parsed directly.
-    """
-    import glob
-    path = sorted(glob.glob(
-        trace_dir + "/plugins/profile/*/*.trace.json.gz"))[-1]
-    with gzip.open(path, "rt") as f:
-        trace = json.load(f)
-    return [e for e in trace["traceEvents"]
-            if e.get("ph") == "X" and e.get("pid") == XLA_PID
-            and e.get("tid") == XLA_TID]
-
-
-def aggregate(events, key_fn):
-    """Sum durations/calls/bytes of ``events`` grouped by ``key_fn``.
-
-    Returns (groups, total_s): groups maps key -> [dur_s, calls,
-    hlo_category, bytes_accessed], sorted by descending time.
-    """
-    import collections
-    groups = collections.defaultdict(lambda: [0.0, 0, "", 0.0])
-    total = 0.0
-    for e in events:
-        dur = e.get("dur", 0) / 1e6          # us -> s
-        total += dur
-        args = e.get("args", {})
-        rec = groups[key_fn(e, args)]
-        rec[0] += dur
-        rec[1] += 1
-        rec[2] = args.get("hlo_category", rec[2])
-        try:
-            rec[3] += float(args.get("bytes_accessed", 0) or 0)
-        except (TypeError, ValueError):
-            pass
-    ordered = dict(sorted(groups.items(), key=lambda kv: -kv[1][0]))
-    return ordered, total
-
-
 _NULL_CTX = contextlib.nullcontext()
 
 
@@ -427,9 +460,6 @@ class NullTracer:
         return _NULL_CTX
 
     def instant(self, name: str, **args) -> None:
-        pass
-
-    def counter(self, name: str, value: float) -> None:
         pass
 
     def flow(self, name: str, fid: int, phase: str = "step",

@@ -382,6 +382,7 @@ def _fwd(q, k, v, tabs, scale, causal, block_q, block_k):
         operands += tabs
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -589,6 +590,7 @@ def _bwd(scale, causal, block_q, block_k, res, do_4d, tabs=None):
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_k=sk,
                           off=sk - sq, rope=rope),
+        name="flash_bwd_dq",
         grid=grid_dq,
         in_specs=in_specs_dq,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -629,6 +631,7 @@ def _bwd(scale, causal, block_q, block_k, res, do_4d, tabs=None):
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_k=sk,
                           seq_q=sq, off=sk - sq, rope=rope),
+        name="flash_bwd_dkv",
         grid=grid_dkv,
         in_specs=in_specs_dkv,
         out_specs=[

@@ -75,7 +75,6 @@ def _chunk_logits(h, emb, c, vc, V):
     return logits, cols, valid
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def chunked_lm_loss(h, emb, targets, mask, chunk_size=4096):
     """Masked-sum LM cross entropy with the vocab dim processed in chunks.
 
@@ -91,7 +90,17 @@ def chunked_lm_loss(h, emb, targets, mask, chunk_size=4096):
     seq 4k, batch 8 that is 2 x 4.2 GB).  The backward pass recomputes
     each tile from the saved (h, lse) — the same recompute-not-store
     contract as dtdl_tpu/ops/attention.py.
+
+    Under ``shard_map`` a mask the caller built from constants is
+    replicated while its cotangent varies like ``h``; a custom VJP needs
+    the two to agree, so the mask is cast to vary like the rest here.
     """
+    return _chunked_lm_loss(h, emb, targets,
+                            _vary_like(mask, h, emb, targets), chunk_size)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _chunked_lm_loss(h, emb, targets, mask, chunk_size):
     (loss, correct), _ = _chunked_fwd(h, emb, targets, mask, chunk_size)
     return loss, correct
 
@@ -163,4 +172,4 @@ def _chunked_bwd(chunk_size, res, cot):
     return dh.astype(h.dtype), demb.astype(emb.dtype), dtargets, dmask
 
 
-chunked_lm_loss.defvjp(_chunked_fwd, _chunked_bwd)
+_chunked_lm_loss.defvjp(_chunked_fwd, _chunked_bwd)

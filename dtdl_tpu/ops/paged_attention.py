@@ -231,6 +231,7 @@ def paged_attention(q, pages_k, pages_v, page_table, pos, active, *,
         dtype=q.dtype)
     return pl.pallas_call(
         kernel,
+        name="paged_attn",
         grid_spec=grid_spec,
         out_shape=_sds((b, h, s_new, d), q.dtype,
                        _vma_of(q, pages_k, pages_v)),

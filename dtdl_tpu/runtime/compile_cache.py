@@ -12,22 +12,108 @@ path is part of what makes a later process find the entries again.
 Entry points call :func:`enable_compile_cache` once, before the first
 compile (``examples/common.py:bootstrap``, ``chip_smoke.py``,
 ``bench.py``); nothing else in the repo sets a cache directory.
+
+**The compile account.**  The same call starts the account of what set-up
+costs: jax reports every trace, lowering, backend compile and cache
+look-up through ``jax.monitoring`` with the traced function's name, and
+the listeners registered here keep one :class:`CompileRow` an event, in
+memory, on the ``time.perf_counter`` clock.  Nothing is written anywhere
+and the listeners run only when jax compiles, so a steady loop pays
+nothing for them.  :func:`compile_account` returns the rows,
+:func:`covered_s` the seconds a choice of them covers,
+:func:`compile_totals` the totals (``Observer.summary()`` carries them;
+``chip_smoke.py`` prints them: "why did this job take 100 s to start").
+The account is one a process because jax's listeners are.
 """
 
 from __future__ import annotations
 
 import os
 import pathlib
+import time
+from typing import NamedTuple
 
 import jax
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 DEFAULT_DIR = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
 
+# jax's event -> its key in compile_totals().  The backend event spans jax's
+# whole compile_or_get_cached call, so on a cache hit it holds the retrieval
+# seconds too: the two are never added.
+ACCOUNT_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile_trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile_lower_s",
+    "/jax/core/compile/backend_compile_duration": "compile_backend_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        "compile_cache_retrieval_s",
+    "/jax/compilation_cache/cache_hits": "compile_cache_hits",
+    "/jax/compilation_cache/cache_misses": "compile_cache_misses",
+}
+
+
+class CompileRow(NamedTuple):
+    event: str              # a key of ACCOUNT_EVENTS
+    fun_name: str | None    # jit(<fun_name>), where jax gives it
+    at: float               # time.perf_counter() when the event arrived
+    value: float            # seconds, or 1 for a count
+
+
+_ROWS: list[CompileRow] = []
+_listening = False
+
+
+def _on_duration(event, seconds, fun_name=None, **_):
+    if event in ACCOUNT_EVENTS:
+        _ROWS.append(CompileRow(event, fun_name, time.perf_counter(),
+                                float(seconds)))
+
+
+def _on_count(event, **_):
+    if event in ACCOUNT_EVENTS:
+        _ROWS.append(CompileRow(event, None, time.perf_counter(), 1))
+
 
 def enable_compile_cache() -> str:
-    """Turn the persistent compile cache on; returns the directory used."""
+    """Turn the persistent compile cache and the compile account on;
+    returns the cache directory used."""
+    global _listening
+    if not _listening:
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_count)
+        _listening = True
     if ENV_VAR in os.environ:
         return os.environ[ENV_VAR]
     jax.config.update("jax_compilation_cache_dir", str(DEFAULT_DIR))
     return str(DEFAULT_DIR)
+
+
+def compile_account() -> list[CompileRow]:
+    """The rows so far, oldest first (a copy)."""
+    return list(_ROWS)
+
+
+def covered_s(rows) -> float:
+    """Seconds covered by duration rows: the length of the union of their
+    intervals ``[at - value, at]``.  A jitted function traced inside
+    another reports a trace of its own within the outer one's, and counts
+    once."""
+    total, end = 0.0, float("-inf")
+    for start, stop in sorted((r.at - r.value, r.at) for r in rows):
+        if stop > end:
+            total += stop - max(start, end)
+            end = stop
+    return total
+
+
+def compile_totals() -> dict:
+    """The account's totals under the keys of :data:`ACCOUNT_EVENTS`: the
+    seconds covered by each kind of duration, and the counts.  ``{}``
+    while the account is empty."""
+    if not _ROWS:
+        return {}
+    totals = {}
+    for event, key in ACCOUNT_EVENTS.items():
+        mine = [r for r in _ROWS if r.event == event]
+        totals[key] = covered_s(mine) if key.endswith("_s") else len(mine)
+    return totals

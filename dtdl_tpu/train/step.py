@@ -72,34 +72,39 @@ def make_train_step(strategy: Strategy | None = None,
     """
     strategy = strategy or SingleDevice()
 
-    def step(state: TrainState, batch):
+    def train_step(state: TrainState, batch):
         rngs = _dropout_rngs(state, strategy, seed)
 
         def compute_loss(params):
             logits, new_stats = _forward(state, params, batch, train=True,
                                          rngs=rngs)
-            return loss_fn(logits, batch["label"]), (logits, new_stats)
+            with jax.named_scope("loss"):
+                return loss_fn(logits, batch["label"]), (logits, new_stats)
 
         # Under DataParallel, localize() marks params per-replica so the
         # gradients below are local and grad_sync is a true mean-allreduce
         # (see dtdl_tpu/parallel/collectives.py:localize).
         (loss, (logits, new_stats)), grads = jax.value_and_grad(
             compute_loss, has_aux=True)(strategy.localize(state.params))
-        grads = strategy.grad_sync(grads)
+        with jax.named_scope("grad_sync"):
+            grads = strategy.grad_sync(grads)
         if new_stats is not None:
             new_stats = strategy.stats_sync(new_stats)
-        new_state = state.apply_gradients(grads=grads, batch_stats=new_stats)
+        with jax.named_scope("update"):
+            new_state = state.apply_gradients(grads=grads,
+                                              batch_stats=new_stats)
         metrics = strategy.metric_sync({
             "loss": loss,
             "accuracy": accuracy(logits, batch["label"]),
         })
         if guard is not None:
-            new_state, gm = guard.select(state, new_state,
-                                         metrics["loss"], grads)
+            with jax.named_scope("guard"):
+                new_state, gm = guard.select(state, new_state,
+                                             metrics["loss"], grads)
             metrics.update(gm)
         return new_state, metrics
 
-    return strategy.compile(step)
+    return strategy.compile(train_step)
 
 
 def make_eval_step(strategy: Strategy | None = None,
@@ -116,7 +121,7 @@ def make_eval_step(strategy: Strategy | None = None,
     """
     strategy = strategy or SingleDevice()
 
-    def evaluate(state: TrainState, batch):
+    def eval_step(state: TrainState, batch):
         logits, _ = _forward(state, state.params, batch, train=False)
         labels = batch["label"]
         mask = batch.get("mask")
@@ -131,7 +136,7 @@ def make_eval_step(strategy: Strategy | None = None,
             "count": mask.sum(),
         })
 
-    return strategy.compile_eval(evaluate)
+    return strategy.compile_eval(eval_step)
 
 
 def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
@@ -163,7 +168,7 @@ def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
     """
     strategy = strategy or SingleDevice()
 
-    def step(state: TrainState, batch):
+    def lm_train_step(state: TrainState, batch):
         tokens = batch["tokens"]
         inputs, targets = tokens[:, :-1], tokens[:, 1:]
         mask = batch.get("mask")
@@ -203,11 +208,12 @@ def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
                 emb = params["embed"]
                 if hasattr(emb, "unbox"):   # flax logical-partitioning box
                     emb = emb.unbox()
-                loss_sum, correct = chunked_lm_loss(
-                    h.reshape(b * s, d), emb,
-                    targets.reshape(b * s), mask.reshape(b * s),
-                    vocab_chunk_size)
-                loss = loss_sum * scale
+                with jax.named_scope("loss"):
+                    loss_sum, correct = chunked_lm_loss(
+                        h.reshape(b * s, d), emb,
+                        targets.reshape(b * s), mask.reshape(b * s),
+                        vocab_chunk_size)
+                    loss = loss_sum * scale
                 term, aux = aux_term(muts)
                 if term is not None:
                     loss = loss + term
@@ -217,32 +223,38 @@ def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
                 logits, muts = state.apply_fn({"params": params}, inputs,
                                               train=True, rngs=rngs,
                                               mutable=["aux_loss"])
-                logits = logits.astype(jnp.float32)
-                lse = jax.nn.logsumexp(logits, axis=-1)
-                true = jnp.take_along_axis(
-                    logits, targets[..., None].astype(jnp.int32), -1)[..., 0]
-                loss = jnp.sum((lse - true) * mask) * scale
-                correct = (jnp.argmax(logits, -1) == targets)
+                with jax.named_scope("loss"):
+                    logits = logits.astype(jnp.float32)
+                    lse = jax.nn.logsumexp(logits, axis=-1)
+                    true = jnp.take_along_axis(
+                        logits, targets[..., None].astype(jnp.int32),
+                        -1)[..., 0]
+                    loss = jnp.sum((lse - true) * mask) * scale
+                    correct = jnp.sum(
+                        (jnp.argmax(logits, -1) == targets) * mask) * scale
                 term, aux = aux_term(muts)
                 if term is not None:
                     loss = loss + term
-                return loss, (jnp.sum(correct * mask) * scale, aux)
+                return loss, (correct, aux)
 
         (loss, (acc, aux)), grads = jax.value_and_grad(
             compute_loss, has_aux=True)(strategy.localize(state.params))
-        grads = strategy.grad_sync(grads)
-        new_state = state.apply_gradients(grads=grads, batch_stats=None)
+        with jax.named_scope("grad_sync"):
+            grads = strategy.grad_sync(grads)
+        with jax.named_scope("update"):
+            new_state = state.apply_gradients(grads=grads, batch_stats=None)
         metrics = {"loss": loss, "accuracy": acc}
         if aux is not None:
             metrics["moe_aux_loss"] = aux
         metrics = strategy.metric_sync(metrics)
         if guard is not None:
-            new_state, gm = guard.select(state, new_state,
-                                         metrics["loss"], grads)
+            with jax.named_scope("guard"):
+                new_state, gm = guard.select(state, new_state,
+                                             metrics["loss"], grads)
             metrics.update(gm)
         return new_state, metrics
 
-    return strategy.compile(step)
+    return strategy.compile(lm_train_step)
 
 
 def make_predict_step(strategy: Strategy | None = None,
@@ -254,10 +266,10 @@ def make_predict_step(strategy: Strategy | None = None,
     """
     strategy = strategy or SingleDevice()
 
-    def predict(state: TrainState, batch):
+    def predict_step(state: TrainState, batch):
         logits, _ = _forward(state, state.params, batch, train=False)
         if probabilities:
             logits = jax.nn.softmax(logits, axis=-1)
         return logits
 
-    return strategy.compile_predict(predict)
+    return strategy.compile_predict(predict_step)

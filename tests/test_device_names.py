@@ -1,0 +1,401 @@
+"""The program names its own device work and accounts for its own set-up.
+
+* **scopes** — in the lowered LM train step every ``dot_general``, every op
+  of a Pallas kernel and every op that comes from optax lies under a
+  component of the catalogue in ``dtdl_tpu/obs/trace.py``; the jitted steps
+  carry their own names;
+* **the map** — ``device_component`` sends recorded name stacks to the right
+  ``(component, pass)``;
+* **kernel names** — the flash and paged ``pallas_call`` equations carry
+  ``name=`` (read from the jaxpr, so the interpreter path shows it);
+* **the compile account** — a fresh ``jit`` adds a trace, a lowering and a
+  compile row under the function's name, a second call adds none, the
+  listeners register once, ``Observer.summary()`` carries the totals.
+
+The audit that holds ``DEVICE_SCOPES`` to the source tree sits with the
+span/event audit in tests/test_obs_export.py.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.extend import core as jex_core
+
+from dtdl_tpu.models.transformer import TransformerLM
+from dtdl_tpu.obs import Observer
+from dtdl_tpu.obs.trace import (_ATTN_PROJECTIONS, DEVICE_SCOPES,
+                                KERNEL_NAMES, MODULE_SCOPES, STEP_NAMES,
+                                device_component)
+from dtdl_tpu.parallel import DataParallel, SingleDevice
+from dtdl_tpu.runtime import compile_cache
+from dtdl_tpu.runtime.mesh import DATA_AXIS
+from dtdl_tpu.train import (make_eval_step, make_lm_train_step,
+                            make_predict_step, make_train_step)
+from dtdl_tpu.train.state import TrainState
+
+# ---------------------------------------------------------------------------
+# the lowered step: every op with its whole name stack
+# ---------------------------------------------------------------------------
+
+_QUOTED = re.compile(r'"([^"]*)"')
+
+
+def _op_stacks(lowered):
+    """``[(op, name stack, from_optax)]`` of every stablehlo op.
+
+    An op's location starts with its name stack, then the Python frames it
+    came from.  jax lowers a jitted callee and a scan body inside a custom
+    VJP as private functions, and a ``shard_map`` body as the region of an
+    ``sdy.manual_computation``; the ops inside carry stacks relative to
+    that function or region, and the ``call`` op or the region's op carries
+    the caller's.  Joining the two is what XLA's call inliner does with
+    ``op_name``, so a function called from several places gives each of its
+    ops several stacks."""
+    from jaxlib.mlir import ir
+
+    def own_stack(op):
+        found = _QUOTED.search(str(op.location))
+        return found.group(1) if found else ""
+
+    module = lowered.compiler_ir("stablehlo")
+    ops, callers = {}, {}       # function -> its ops / its call sites
+    for func in module.body.operations:
+        name = ir.StringAttr(func.attributes["sym_name"]).value
+        mine = ops.setdefault(name, [])
+
+        def visit(op, name=name, mine=mine):
+            op = op.operation
+            loc, stack = str(op.location), own_stack(op)
+            around = op.parent
+            while around is not None and around.name != "func.func":
+                if around.name == "sdy.manual_computation":
+                    stack = own_stack(around) + "/" + stack
+                around = around.parent
+            if op.name in ("func.call", "stablehlo.call"):
+                callee = ir.FlatSymbolRefAttr(op.attributes["callee"]).value
+                callers.setdefault(callee, []).append((name, stack))
+            elif op.name.startswith("stablehlo."):
+                mine.append((op.name, stack, "/optax/" in loc))
+            return ir.WalkResult.ADVANCE
+
+        func.operation.walk(visit)
+
+    def prefixes(func, seen=()):
+        if func not in callers or func in seen:
+            return [""]
+        return [p + stack + "/" for caller, stack in callers[func]
+                for p in prefixes(caller, seen + (func,))]
+
+    return [(op, prefix + stack, optax_op)
+            for func, rows in ops.items() for prefix in prefixes(func)
+            for op, stack, optax_op in rows]
+
+
+def _tiny_lm_step(strategy, vocab_chunk_size):
+    model = TransformerLM(vocab_size=256, d_model=64, n_layers=2, n_heads=4,
+                          d_ff=128, max_seq=64, attn_impl="flash",
+                          remat=True, dtype=jnp.bfloat16)
+    tokens = jnp.zeros((2 * strategy.num_replicas, 64), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens[:, :-1])["params"]
+    state = TrainState.create(apply_fn=model.apply, params=params,
+                              tx=optax.adamw(3e-4))
+    step = make_lm_train_step(strategy, vocab_chunk_size=vocab_chunk_size)
+    return step.lower(state, {"tokens": tokens})
+
+
+@pytest.fixture(scope="module")
+def two_devices(devices):
+    return DataParallel(mesh=jax.sharding.Mesh(np.asarray(devices[:2]),
+                                               (DATA_AXIS,)))
+
+
+@pytest.mark.parametrize("vocab_chunk_size", [0, 96],
+                         ids=["dense_head", "chunked_loss"])
+@pytest.mark.parametrize("parallel", [False, True],
+                         ids=["single", "ddp2"])
+def test_lowered_lm_step_leaves_no_matmul_kernel_or_update_op_unscoped(
+        parallel, vocab_chunk_size, two_devices):
+    strategy = two_devices if parallel else SingleDevice()
+    lowered = _tiny_lm_step(strategy, vocab_chunk_size)
+    assert "module @jit_lm_train_step" in lowered.as_text()
+    rows = _op_stacks(lowered)
+    seen = {}
+    for op, stack, from_optax in rows:
+        component, phase = device_component(stack)
+        kernel = any(k in stack.split("/") for k in KERNEL_NAMES)
+        if op == "stablehlo.dot_general" or from_optax or kernel:
+            assert component is not None, (op, stack)
+            assert "jit(lm_train_step)" in stack, (op, stack)
+        if from_optax:
+            assert (component, phase) == ("update", "update"), (op, stack)
+        if kernel:
+            assert component == "flash", (op, stack)
+        if op == "stablehlo.all_reduce" and component is not None:
+            seen.setdefault("all_reduce", set()).add(component)
+        if op == "stablehlo.dot_general":
+            seen.setdefault(component, set()).add(phase)
+    # what the tiny step must show of each component (remat: the block's
+    # forward runs again in the backward pass, the head's does not)
+    every = {"forward", "recompute", "backward"}
+    assert seen["attn_proj"] == seen["mlp"] == seen["flash"] == every
+    if vocab_chunk_size:
+        assert seen["loss"] == {"forward", "backward"} and "head" not in seen
+    else:
+        assert seen["head"] == {"forward", "backward"} and "loss" not in seen
+    if parallel:
+        assert "grad_sync" in seen["all_reduce"]
+
+
+def _tiny_classifier_state():
+    import flax.linen as nn
+
+    class Tiny(nn.Module):
+        @nn.compact
+        def __call__(self, x, train=False):
+            return nn.Dense(4)(x.reshape(x.shape[0], -1))
+
+    model = Tiny()
+    batch = {"image": jnp.zeros((4, 2, 2, 1)), "label": jnp.zeros(4, jnp.int32)}
+    params = model.init(jax.random.PRNGKey(0), batch["image"])["params"]
+    return TrainState.create(apply_fn=model.apply, params=params,
+                             tx=optax.sgd(0.1)), batch
+
+
+@pytest.mark.parametrize("make, name", [
+    (make_train_step, "train_step"), (make_eval_step, "eval_step"),
+    (make_predict_step, "predict_step")])
+@pytest.mark.parametrize("parallel", [False, True], ids=["single", "ddp2"])
+def test_jitted_steps_carry_their_own_names(make, name, parallel,
+                                            two_devices):
+    assert name in STEP_NAMES and "lm_train_step" in STEP_NAMES
+    state, batch = _tiny_classifier_state()
+    lowered = make(two_devices if parallel else SingleDevice()).lower(
+        state, batch)
+    assert f"module @jit_{name}" in lowered.as_text()
+    if name == "train_step":
+        found = {device_component(stack) for op, stack, _ in
+                 _op_stacks(lowered) if device_component(stack)[0]}
+        assert {("loss", "forward"), ("loss", "backward"),
+                ("update", "update")} <= found
+        assert (("grad_sync", "update") in found) == parallel
+
+
+# ---------------------------------------------------------------------------
+# the map, on name stacks recorded from the lowered step (jax 0.9.0)
+# ---------------------------------------------------------------------------
+
+_BWD = "jit(lm_train_step)/transpose(jvp(TransformerLM))/"
+
+STACKS = [
+    ("jit(lm_train_step)/jvp(TransformerLM)/embed/jit(_take)/gather",
+     "embed", "forward"),
+    (_BWD + "embed/jit(_take)/scatter-add", "embed", "backward"),
+    ("jit(lm_train_step)/jvp(TransformerLM)/block_0/attn/q/dot_general",
+     "attn_proj", "forward"),
+    ("jit(lm_train_step)/jvp(TransformerLM)/block_0/attn/reshape",
+     "attn_other", "forward"),
+    ("jit(lm_train_step)/jvp(TransformerLM)/block_0/attn/flash_fwd/"
+     "pallas_call", "flash", "forward"),
+    (_BWD + "jvp(TransformerLM)/checkpoint/rematted_computation/block_3/"
+     "attn/flash_fwd/pallas_call", "flash", "recompute"),
+    (_BWD + "jvp(TransformerLM)/checkpoint/block_3/attn/flash_bwd_dq/"
+     "pallas_call", "flash", "backward"),
+    (_BWD + "jvp(TransformerLM)/checkpoint/block_3/attn/flash_bwd_dkv/"
+     "pallas_call", "flash", "backward"),
+    (_BWD + "jvp(TransformerLM)/checkpoint/rematted_computation/block_1/"
+     "mlp/wg/dot_general", "mlp", "recompute"),
+    (_BWD + "jvp(TransformerLM)/checkpoint/block_1/mlp/wo/dot_general",
+     "mlp", "backward"),
+    (_BWD + "jvp(TransformerLM)/checkpoint/rematted_computation/block_1/"
+     "ln_mlp/rsqrt", "norm", "recompute"),
+    ("jit(lm_train_step)/jvp(TransformerLM)/ln_f/mul", "norm", "forward"),
+    ("jit(lm_train_step)/jvp(TransformerLM)/head/bsd,vd->bsv/dot_general",
+     "head", "forward"),
+    (_BWD + "head/bsd,vd->bsv/dot_general", "head", "backward"),
+    ("jit(lm_train_step)/jvp(loss)/reduce_max", "loss", "forward"),
+    ("jit(lm_train_step)/transpose(jvp(loss))/mul", "loss", "backward"),
+    ("jit(lm_train_step)/jvp(loss)/while/body/closed_call/td,vd->tv/"
+     "dot_general", "loss", "forward"),
+    ("jit(lm_train_step)/shard_map/grad_sync/psum", "grad_sync", "update"),
+    ("jit(lm_train_step)/update/sqrt", "update", "update"),
+    ("jit(lm_train_step)/update/jit(_where)/select_n", "update", "update"),
+    ("jit(lm_train_step)/guard/select_n", "guard", "update"),
+    ("jit(decode)/block_0/attn/paged_attn/pallas_call", "paged_attn",
+     "forward"),
+    ("jit(lm_train_step)/jvp(TransformerLM)/block_1/moe/router/dot_general",
+     "moe", "forward"),
+    # the step's own scalar bookkeeping and the rope table carry no scope
+    ("jit(lm_train_step)/div", None, "forward"),
+    ("jit(lm_train_step)/jvp(TransformerLM)/cos", None, "forward"),
+]
+
+
+@pytest.mark.parametrize("stack, component, phase", STACKS,
+                         ids=[f"{c}-{p}-{i}" for i, (_, c, p)
+                              in enumerate(STACKS)])
+def test_device_component_maps_recorded_name_stacks(stack, component, phase):
+    assert device_component(stack) == (component, phase)
+    assert phase in ("forward", "recompute", "backward", "update")
+    assert component is None or component in (
+        set(DEVICE_SCOPES) | set(MODULE_SCOPES.values())
+        | set(KERNEL_NAMES.values()) | {"attn_proj"})
+
+
+def test_module_scopes_are_the_models_own_modules():
+    """MODULE_SCOPES against the module tree flax builds (every module of
+    the LM holds a parameter, so the parameter tree shows each by name): a
+    renamed or a new module fails here by name, where its ops would go
+    unattributed in silence."""
+    model = TransformerLM(vocab_size=64, d_model=16, n_layers=2, n_heads=2,
+                          d_ff=32, max_seq=16, n_experts=2, moe_every=2)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+    blocks = [v for k, v in params.items() if k.startswith("block_")]
+    modules = {name for block in blocks for name in block} | {
+        k for k in params if not k.startswith("block_")}
+    # the embedding is a parameter of the LM itself, under a scope
+    assert len(blocks) == 2 and "embed" in DEVICE_SCOPES
+    assert modules - {"embed"} == set(MODULE_SCOPES)
+    assert {name for block in blocks
+            for name in block["attn"]} == set(_ATTN_PROJECTIONS)
+
+
+# ---------------------------------------------------------------------------
+# kernel names, from the jaxpr's pallas_call equations
+# ---------------------------------------------------------------------------
+
+def _pallas_names(jaxpr, out=None):
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+        for value in eqn.params.values():
+            for item in value if isinstance(value, (tuple, list)) else (value,):
+                if isinstance(item, jex_core.ClosedJaxpr):
+                    _pallas_names(item.jaxpr, out)
+                elif isinstance(item, jex_core.Jaxpr):
+                    _pallas_names(item, out)
+    return out
+
+
+@pytest.mark.parametrize("fused_rope", [False, True], ids=["plain", "rope"])
+def test_flash_pallas_calls_carry_their_names(fused_rope):
+    from dtdl_tpu.ops.attention import flash_attention
+    from dtdl_tpu.ops.rope import rope_frequencies
+
+    q = jnp.zeros((1, 2, 16, 8), jnp.float32)
+    rope = rope_frequencies(8, 16) if fused_rope else None
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, rope=rope).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
+    assert _pallas_names(jaxpr.jaxpr) == [
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+
+
+def test_paged_pallas_call_carries_its_name():
+    from dtdl_tpu.ops.paged_attention import paged_attention
+
+    b, h, page, d, n_ptab = 2, 2, 8, 16, 2
+    pool = jnp.zeros((b * n_ptab + 1, h, page, d), jnp.float32)
+    table = 1 + jnp.arange(b * n_ptab, dtype=jnp.int32).reshape(b, n_ptab)
+    jaxpr = jax.make_jaxpr(
+        lambda q: paged_attention(q, pool, pool, table,
+                                  jnp.asarray([3, 9], jnp.int32),
+                                  jnp.ones(b, jnp.int32), scale=0.25))(
+        jnp.zeros((b, h, 1, d), jnp.float32))
+    assert _pallas_names(jaxpr.jaxpr) == ["paged_attn"]
+    assert set(KERNEL_NAMES) == {"flash_fwd", "flash_bwd_dq",
+                                 "flash_bwd_dkv", "paged_attn"}
+
+
+# ---------------------------------------------------------------------------
+# the compile account
+# ---------------------------------------------------------------------------
+
+def _rows_of(rows, fun):
+    return [(r.event.rsplit("/", 1)[-1], r.fun_name) for r in rows
+            if r.fun_name in (fun, f"jit({fun})")]
+
+
+def test_compile_account_rows_totals_and_single_registration():
+    compile_cache.enable_compile_cache()
+    compile_cache.enable_compile_cache()
+    # registered once: one event from jax arrives as one row
+    start = len(compile_cache.compile_account())
+    jax.monitoring.record_event_duration_secs(
+        "/jax/core/compile/backend_compile_duration", 0.5, fun_name="probe")
+    jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+    jax.monitoring.record_event_duration_secs("/some/other/event", 0.5)
+    probe = compile_cache.compile_account()[start:]
+    assert [(r.fun_name, r.value) for r in probe] == [("probe", 0.5),
+                                                      (None, 1)]
+
+    def accounted_for(x):
+        return (x * 3.0).sum()
+
+    fn = jax.jit(accounted_for)
+    x = jnp.arange(8.0)
+    start = len(compile_cache.compile_account())
+    fn(x).block_until_ready()
+    rows = compile_cache.compile_account()[start:]
+    assert _rows_of(rows, "accounted_for") == [
+        ("jaxpr_trace_duration", "accounted_for"),
+        ("jaxpr_to_mlir_module_duration", "jit(accounted_for)"),
+        ("backend_compile_duration", "jit(accounted_for)")]
+    assert all(r.value >= 0 and r.event in compile_cache.ACCOUNT_EVENTS
+               for r in rows)
+    ats = [r.at for r in rows]
+    assert ats == sorted(ats)
+
+    mid = len(compile_cache.compile_account())
+    fn(x).block_until_ready()                   # steady state: no event
+    assert len(compile_cache.compile_account()) == mid
+
+    whole = compile_cache.compile_totals()
+    assert set(whole) == set(compile_cache.ACCOUNT_EVENTS.values())
+    assert whole["compile_trace_s"] > 0 and whole["compile_backend_s"] > 0
+    summary = Observer().summary()
+    assert {k: summary[k] for k in whole} == whole
+
+
+def test_compile_totals_count_a_nested_trace_and_a_retrieval_once(
+        monkeypatch):
+    Row = compile_cache.CompileRow
+    trace = "/jax/core/compile/jaxpr_trace_duration"
+    rows = [
+        Row(trace, "inner", 10.5, 0.25),            # [10.25, 10.5] inside
+        Row(trace, "outer", 11.0, 1.0),             # [10.0, 11.0]
+        Row(trace, "later", 13.0, 0.5),             # [12.5, 13.0]
+        Row("/jax/core/compile/backend_compile_duration", "jit(outer)",
+            14.0, 2.0),
+        Row("/jax/compilation_cache/cache_retrieval_time_sec", None, 13.5,
+            1.5),
+        Row("/jax/compilation_cache/cache_hits", None, 13.5, 1),
+        Row("/jax/compilation_cache/cache_misses", None, 20.0, 1),
+    ]
+    assert compile_cache.covered_s(rows[:3]) == pytest.approx(1.5)
+    assert compile_cache.covered_s(rows[3:5]) == pytest.approx(2.0)
+    assert compile_cache.covered_s([]) == 0.0
+    monkeypatch.setattr(compile_cache, "_ROWS", [])
+    assert compile_cache.compile_totals() == {}
+    monkeypatch.setattr(compile_cache, "_ROWS", rows)
+    assert compile_cache.compile_totals() == {
+        "compile_trace_s": pytest.approx(1.5), "compile_lower_s": 0.0,
+        "compile_backend_s": pytest.approx(2.0),
+        "compile_cache_retrieval_s": pytest.approx(1.5),
+        "compile_cache_hits": 1, "compile_cache_misses": 1}
+
+
+def test_tracer_has_no_dead_counter_or_profile_parser():
+    from dtdl_tpu.obs import trace
+    # the Chrome-JSON profile parser at a hard-coded pid/tid is gone
+    assert not [n for n in dir(trace)
+                if n.lower().startswith("xla") or n == "aggregate"]
+    assert not hasattr(trace.Tracer, "counter")
+    assert not hasattr(trace.NullTracer, "counter")

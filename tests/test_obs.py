@@ -457,17 +457,3 @@ def test_tensorboard_warning_fires_once(caplog, monkeypatch, tmp_path):
     # degraded sinks still accept writes/close silently
     b.write({"step": 1, "loss": 1.0})
     b.close()
-
-
-def test_trace_utils_script_path_still_works():
-    import importlib.util
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "trace_utils", os.path.join(os.path.dirname(__file__), "..",
-                                    "scripts", "trace_utils.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    from dtdl_tpu.obs import trace
-    assert mod.xla_events is trace.xla_events
-    assert mod.aggregate is trace.aggregate
-    assert mod.XLA_PID == 3

@@ -193,6 +193,45 @@ def test_event_catalog_audit_no_silent_drift():
         f"stale catalog entries: {sorted(EVENT_CATALOG - events)}")
 
 
+def test_device_scope_catalog_audit_no_silent_drift():
+    """The same audit for the device-side names: every
+    ``named_scope("...")`` literal under dtdl_tpu/ is a key of
+    DEVICE_SCOPES and every key has a scope in the source; every
+    ``pallas_call`` carries a ``name=`` line and the names are
+    KERNEL_NAMES' keys; the functions train/step.py hands to
+    ``strategy.compile*`` are STEP_NAMES.  Device time and the compile
+    account are read by these names, so a scope, a kernel or a step added
+    without its catalogue entry would go unattributed in silence."""
+    from dtdl_tpu.obs.trace import DEVICE_SCOPES, KERNEL_NAMES, STEP_NAMES
+    pkg = pathlib.Path(dtdl_tpu.__file__).parent
+    scope_pat = re.compile(r"named_scope\(\s*(f?)\"(\w[^\"]*)\"")
+    name_pat = re.compile(r"^\s+name=\"(\w+)\",$", re.M)
+    scopes, kernels = set(), set()
+    for py in pkg.rglob("*.py"):
+        text = py.read_text()
+        for m in scope_pat.finditer(text):
+            assert not m.group(1), f"{py.name}: dynamic scope {m.group(2)!r}"
+            scopes.add(m.group(2))
+        calls = len(re.findall(r"\bpl\.pallas_call\(", text))
+        if calls:
+            names = name_pat.findall(text)
+            assert len(names) == calls, (
+                f"{py.name}: {calls} pallas_call(s), {len(names)} name= "
+                f"lines")
+            kernels.update(names)
+    assert scopes == set(DEVICE_SCOPES), (
+        f"uncataloged scopes: {sorted(scopes - set(DEVICE_SCOPES))}; "
+        f"stale catalog entries: {sorted(set(DEVICE_SCOPES) - scopes)}")
+    assert kernels == set(KERNEL_NAMES), (
+        f"uncataloged kernels: {sorted(kernels - set(KERNEL_NAMES))}; "
+        f"stale catalog entries: {sorted(set(KERNEL_NAMES) - kernels)}")
+    steps = set(re.findall(r"strategy\.compile\w*\((\w+)\)",
+                           (pkg / "train" / "step.py").read_text()))
+    assert steps == set(STEP_NAMES), (
+        f"uncataloged steps: {sorted(steps - set(STEP_NAMES))}; "
+        f"stale catalog entries: {sorted(set(STEP_NAMES) - steps)}")
+
+
 # ---------------------------------------------------------------------------
 # exporter: sources -> sinks, prometheus text, scrape endpoint
 # ---------------------------------------------------------------------------
