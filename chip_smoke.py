@@ -130,6 +130,7 @@ def train_phase(plan: Plan):
     from dtdl_tpu.data import load_dataset
     from dtdl_tpu.models import transformer_lm
     from dtdl_tpu.parallel import choose_strategy
+    from dtdl_tpu.runtime.compile_cache import remat_plans
     from dtdl_tpu.train import init_state, make_lm_train_step
 
     strategy = choose_strategy("auto")
@@ -183,6 +184,17 @@ def train_phase(plan: Plan):
     print(f"train: trace+lower {lower_s:.1f} s, compile {compile_s:.1f} "
           f"s, steady step {float(np.median(step_s[1:])):.3f} s (median "
           f"of {len(step_s) - 1}) — smoke, not a benchmark")
+    if model.remat:     # 'large'; the rehearsal's 'tiny' recomputes nothing
+        kept = remat_plans()[-1]
+        check(len(kept.rungs) == model.n_layers and
+              kept.kept_bytes <= kept.budget_bytes,
+              f"the step's checkpoint plan covers every block within its "
+              f"budget: {kept}")
+        print(f"train: remat keeps {kept.kept_bytes / 1e9:.3f} GB a chip "
+              f"(rung of each block {list(kept.rungs)}; budget "
+              f"{kept.budget_bytes / 1e9:.3f} GB = limit {kept.limit_bytes} "
+              f"less margin less an estimate of "
+              f"{kept.estimate_bytes / 1e9:.3f} GB under rung 0)")
     return model, state.params
 
 
@@ -455,7 +467,7 @@ def main(argv=None) -> int:
     # compile and cache look-up of this process (smoke, not a benchmark)
     print("compile account: " + ", ".join(
         f"{k.removeprefix('compile_')} "
-        f"{v if isinstance(v, int) else format(v, '.1f')}"
+        f"{format(v, '.1f') if isinstance(v, float) else v}"
         for k, v in compile_totals().items()), flush=True)
     result = {"ok": True, "device": device}
     if args.rehearse:

@@ -17,7 +17,9 @@ model zoo gains a modern decoder-only LM:
   runs replicated, FSDP, or tensor-parallel under pjit by flipping the
   logical→mesh rules (dtdl_tpu/parallel/tensor.py)
 * ``remat`` applies ``jax.checkpoint`` per block — the standard TPU
-  memory/FLOPs trade for long sequences
+  memory/FLOPs trade for long sequences; what each block keeps beside its
+  input is planned from shapes and the device's memory limit
+  (models/remat_plan.py), nothing where neither is known
 
 Logical axis names: 'vocab', 'embed', 'heads', 'head_dim' (attention
 projections), 'mlp' (FFN hidden), 'expert' (MoE).
@@ -31,12 +33,15 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
+from dtdl_tpu.models import remat_plan
 from dtdl_tpu.ops.attention import flash_attention, mha_reference
 from dtdl_tpu.ops.paged_attention import paged_attention
 from dtdl_tpu.ops.rope import apply_rope, rope_frequencies
 from dtdl_tpu.quant import (QuantDenseGeneral, canon_kv_dtype, kv_quantize,
                             kv_scale_dtype, weight_dtypes)
+from dtdl_tpu.runtime.compile_cache import record_remat_plan
 
 Dtype = Any
 
@@ -173,6 +178,7 @@ class Attention(nn.Module):
                 kernel_init=_part(nn.initializers.lecun_normal(),
                                   "heads", "head_dim", "embed"),
                 name="out")(o)
+        out = checkpoint_name(out, remat_plan.ATTN_OUT)
         if lora:
             a = jnp.take(self.get_variable("lora", "out_a"),
                          aid, axis=0)                        # [B, H, D, r]
@@ -637,8 +643,8 @@ class SwiGLU(nn.Module):
                     features, use_bias=False, dtype=self.dtype,
                     kernel_init=_part(nn.initializers.lecun_normal(),
                                       *names), name=name)
-        wi = dense(self.d_ff, "wi")(x)
-        wg = dense(self.d_ff, "wg")(x)
+        wi = checkpoint_name(dense(self.d_ff, "wi")(x), remat_plan.MLP_UP)
+        wg = checkpoint_name(dense(self.d_ff, "wg")(x), remat_plan.MLP_UP)
         h = nn.silu(wg) * wi
         return dense(d_model, "wo")(h)
 
@@ -892,6 +898,13 @@ class Block(nn.Module):
         return x
 
 
+@functools.cache
+def _remat_block(rung: int):
+    """``Block`` under ``jax.checkpoint`` with the policy of ``rung``
+    (models/remat_plan.py); rung 0 is no policy, the whole forward again."""
+    return nn.remat(Block, static_argnums=(), policy=remat_plan.policy(rung))
+
+
 class TransformerLM(nn.Module):
     """Decoder-only LM; input int32 tokens [batch, seq] -> logits f32."""
     vocab_size: int = 32000
@@ -1044,6 +1057,25 @@ class TransformerLM(nn.Module):
                             self.paged_cache_shapes(n_slots, n_pages,
                                                     page_size, kv_dtype))
 
+    def _checkpoint_plan(self, tokens_shape, is_moe, return_hidden):
+        """What each rematerialized block keeps (models/remat_plan.py), from
+        the traced token shape and this model's widths; recorded in the
+        compile account beside the step that traces it."""
+        batch, seq = tokens_shape
+        itemsize = jnp.dtype(self.dtype).itemsize
+        costs = [remat_plan.residual_bytes(
+            batch, seq, self.d_model, self.n_heads,
+            0 if moe else self.d_ff, itemsize) for moe in is_moe]
+        held = remat_plan.model_held_bytes(
+            batch, seq, self.d_model, self.d_ff, self.n_layers,
+            0 if return_hidden else self.vocab_size,
+            remat_plan.tree_bytes(self.variables.get("params", {})),
+            itemsize)
+        plan = remat_plan.plan_checkpoints(costs, held)
+        if plan.fun_name is not None:
+            record_remat_plan(plan)
+        return plan
+
     @nn.compact
     def __call__(self, tokens, train: bool = False,
                  return_hidden: bool = False, decode: bool = False):
@@ -1072,12 +1104,14 @@ class TransformerLM(nn.Module):
         # remat is a training-time memory/FLOPs trade; under decode it
         # would also trace the `decode` flag into a tracer (remat treats
         # every call arg as dynamic) — plain blocks for decode
-        block_cls = Block
+        is_moe = [self.n_experts > 0 and (i + 1) % self.moe_every == 0
+                  for i in range(self.n_layers)]
+        rungs = None
         if self.remat and not decode:
-            block_cls = nn.remat(Block, static_argnums=())
-        for i in range(self.n_layers):
-            moe = (self.n_experts > 0 and
-                   (i + 1) % self.moe_every == 0)
+            rungs = self._checkpoint_plan(tokens.shape, is_moe,
+                                          return_hidden).rungs
+        for i, moe in enumerate(is_moe):
+            block_cls = Block if rungs is None else _remat_block(rungs[i])
             block = block_cls(
                 self.n_heads, self.head_dim, self.d_ff,
                 n_experts=self.n_experts if moe else 0,

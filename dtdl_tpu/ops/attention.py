@@ -53,12 +53,20 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dtdl_tpu.ops.rope import rope_rows as _rope_rows
 
 NEG_INF = -1e30
+
+# Names (``jax.ad_checkpoint.checkpoint_name``) on what the backward kernels
+# read of the forward pass.  Identity outside a ``jax.checkpoint`` policy;
+# under one that saves ``FLASH_OUT`` the second ``flash_fwd`` call of a
+# rematerialized block is dead code (models/remat_plan.py chooses).
+FLASH_OUT = "flash_out"     # o and its log-sum-exp
+FLASH_QKV = "flash_qkv"     # q, k, v as the kernels take them (unrotated)
 
 
 def _use_interpret() -> bool:
@@ -661,7 +669,9 @@ def _flash(q, k, v, scale, causal, block_q, block_k):
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
-    o, lse = _fwd(q, k, v, None, scale, causal, block_q, block_k)
+    o, lse = checkpoint_name(
+        _fwd(q, k, v, None, scale, causal, block_q, block_k), FLASH_OUT)
+    q, k, v = checkpoint_name((q, k, v), FLASH_QKV)
     return o, (q, k, v, o, lse)
 
 
@@ -680,9 +690,12 @@ def _flash_rope(q, k, v, qc, qs, kc, ks, scale, causal, block_q, block_k):
 
 def _flash_rope_fwd(q, k, v, qc, qs, kc, ks, scale, causal, block_q,
                     block_k):
-    o, lse = _fwd(q, k, v, (qc, qs, kc, ks), scale, causal, block_q, block_k)
+    o, lse = checkpoint_name(
+        _fwd(q, k, v, (qc, qs, kc, ks), scale, causal, block_q, block_k),
+        FLASH_OUT)
     # residuals keep q/k UNROTATED — the backward kernels re-rotate on
     # tile load, so the rotation never round-trips HBM
+    q, k, v = checkpoint_name((q, k, v), FLASH_QKV)
     return o, (q, k, v, o, lse, qc, qs, kc, ks)
 
 

@@ -46,6 +46,9 @@ class Strategy:
 
     mesh: Mesh | None = None
     axis: str | None = None
+    # the step function is traced with one device's shapes (one device, or
+    # inside shard_map), so byte counts from shapes are one device's
+    traces_one_device = True
 
     def localize(self, tree):
         """Hook: mark replicated values as per-replica before local compute."""
@@ -221,6 +224,8 @@ class AutoSharded(MeshStrategy):
     optimizer-state leaves mirror the param shapes, so one shape rule shards
     both consistently).
     """
+
+    traces_one_device = False     # global shapes; the partitioner splits
 
     def __init__(self, mesh: Mesh | None = None, axis: str = DATA_AXIS,
                  param_spec=None):

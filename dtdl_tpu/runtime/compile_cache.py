@@ -24,6 +24,13 @@ nothing for them.  :func:`compile_account` returns the rows,
 :func:`compile_totals` the totals (``Observer.summary()`` carries them;
 ``chip_smoke.py`` prints them: "why did this job take 100 s to start").
 The account is one a process because jax's listeners are.
+
+**The checkpoint plan.**  Beside the step's rows the account keeps what a
+``remat=True`` model chose to keep each time a train step traced it
+(``models/remat_plan.py``): :func:`record_remat_plan` appends,
+:func:`remat_plans` returns them, and :func:`compile_totals` carries the
+newest as ``remat_blocks_by_rung``, ``remat_kept_bytes``,
+``remat_budget_bytes`` and ``remat_estimate_bytes``.
 """
 
 from __future__ import annotations
@@ -60,6 +67,7 @@ class CompileRow(NamedTuple):
 
 
 _ROWS: list[CompileRow] = []
+_PLANS: list = []       # models.remat_plan.RematPlan, one a traced step
 _listening = False
 
 
@@ -88,6 +96,16 @@ def enable_compile_cache() -> str:
     return str(DEFAULT_DIR)
 
 
+def record_remat_plan(plan) -> None:
+    """Keep the checkpoint plan a train step was just traced with."""
+    _PLANS.append(plan)
+
+
+def remat_plans() -> list:
+    """The plans so far, oldest first (a copy)."""
+    return list(_PLANS)
+
+
 def compile_account() -> list[CompileRow]:
     """The rows so far, oldest first (a copy)."""
     return list(_ROWS)
@@ -108,12 +126,21 @@ def covered_s(rows) -> float:
 
 def compile_totals() -> dict:
     """The account's totals under the keys of :data:`ACCOUNT_EVENTS`: the
-    seconds covered by each kind of duration, and the counts.  ``{}``
+    seconds covered by each kind of duration, and the counts; and of the
+    newest checkpoint plan the blocks at each rung (rung 0 first), the bytes
+    it keeps, its budget and the estimate it was chosen against.  ``{}``
     while the account is empty."""
-    if not _ROWS:
-        return {}
     totals = {}
-    for event, key in ACCOUNT_EVENTS.items():
-        mine = [r for r in _ROWS if r.event == event]
-        totals[key] = covered_s(mine) if key.endswith("_s") else len(mine)
+    if _ROWS:
+        for event, key in ACCOUNT_EVENTS.items():
+            mine = [r for r in _ROWS if r.event == event]
+            totals[key] = (covered_s(mine) if key.endswith("_s")
+                           else len(mine))
+    if _PLANS:
+        plan = _PLANS[-1]
+        totals["remat_blocks_by_rung"] = [
+            plan.rungs.count(r) for r in range(max(plan.rungs, default=0) + 1)]
+        totals["remat_kept_bytes"] = plan.kept_bytes
+        totals["remat_budget_bytes"] = plan.budget_bytes
+        totals["remat_estimate_bytes"] = plan.estimate_bytes
     return totals

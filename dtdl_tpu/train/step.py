@@ -21,6 +21,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from dtdl_tpu.models import remat_plan
 from dtdl_tpu.ops import accuracy, softmax_cross_entropy
 from dtdl_tpu.parallel.strategy import Strategy, SingleDevice
 from dtdl_tpu.train.state import TrainState
@@ -237,8 +238,18 @@ def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
                     loss = loss + term
                 return loss, (correct, aux)
 
-        (loss, (acc, aux)), grads = jax.value_and_grad(
-            compute_loss, has_aux=True)(strategy.localize(state.params))
+        # what a remat=True model may keep of its forward pass is planned
+        # against what this step holds beside it (models/remat_plan.py)
+        held = remat_plan.step_held_bytes(
+            remat_plan.tree_bytes(state),
+            [remat_plan.tree_bytes(p) for p in jax.tree.leaves(state.params)],
+            strategy.num_replicas > 1 or guard is not None,
+            inputs.size, vocab_chunk_size)
+        limit = (remat_plan.device_bytes_limit()
+                 if strategy.traces_one_device else None)
+        with remat_plan.step_memory("lm_train_step", held, limit):
+            (loss, (acc, aux)), grads = jax.value_and_grad(
+                compute_loss, has_aux=True)(strategy.localize(state.params))
         with jax.named_scope("grad_sync"):
             grads = strategy.grad_sync(grads)
         with jax.named_scope("update"):

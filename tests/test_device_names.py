@@ -25,6 +25,7 @@ import optax
 import pytest
 from jax.extend import core as jex_core
 
+from dtdl_tpu.models import remat_plan
 from dtdl_tpu.models.transformer import TransformerLM
 from dtdl_tpu.obs import Observer
 from dtdl_tpu.obs.trace import (_ATTN_PROJECTIONS, DEVICE_SCOPES,
@@ -113,14 +114,28 @@ def two_devices(devices):
                                                (DATA_AXIS,)))
 
 
-@pytest.mark.parametrize("vocab_chunk_size", [0, 96],
-                         ids=["dense_head", "chunked_loss"])
-@pytest.mark.parametrize("parallel", [False, True],
-                         ids=["single", "ddp2"])
+# the rung of the checkpoint plan (models/remat_plan.py) from which a block
+# no longer runs a component's matmuls or kernel a second time
+_KEPT_FROM = {"flash": 1, "attn_proj": 2, "mlp": 3}
+
+
+@pytest.mark.parametrize("parallel, vocab_chunk_size, limit", [
+    (False, 0, None), (False, 96, None), (True, 0, None), (True, 96, None),
+    (False, 0, 2_100_000), (True, 96, 10**9)],
+    ids=["single-dense_head", "single-chunked_loss", "ddp2-dense_head",
+         "ddp2-chunked_loss", "single-dense_head-flash_kept",
+         "ddp2-chunked_loss-all_kept"])
 def test_lowered_lm_step_leaves_no_matmul_kernel_or_update_op_unscoped(
-        parallel, vocab_chunk_size, two_devices):
+        parallel, vocab_chunk_size, limit, two_devices, monkeypatch):
+    # no device here reports a memory limit, so the plan is rung 0 (every
+    # component shows a recompute pass); with a limit the step keeps what it
+    # buys, and the expectation below follows the plan it recorded (2.1 MB:
+    # the tiny step's 1.93 MB estimate, a sixteenth of margin, 36 KB to keep)
+    monkeypatch.setattr(remat_plan, "device_bytes_limit", lambda: limit)
     strategy = two_devices if parallel else SingleDevice()
     lowered = _tiny_lm_step(strategy, vocab_chunk_size)
+    rungs = compile_cache.remat_plans()[-1].rungs
+    assert rungs == {None: (0, 0), 2_100_000: (1, 1), 10**9: (3, 3)}[limit]
     assert "module @jit_lm_train_step" in lowered.as_text()
     rows = _op_stacks(lowered)
     seen = {}
@@ -139,9 +154,12 @@ def test_lowered_lm_step_leaves_no_matmul_kernel_or_update_op_unscoped(
         if op == "stablehlo.dot_general":
             seen.setdefault(component, set()).add(phase)
     # what the tiny step must show of each component (remat: the block's
-    # forward runs again in the backward pass, the head's does not)
-    every = {"forward", "recompute", "backward"}
-    assert seen["attn_proj"] == seen["mlp"] == seen["flash"] == every
+    # forward runs again in the backward pass unless every block keeps its
+    # outputs, the head's does not)
+    for component, rung in _KEPT_FROM.items():
+        every = {"forward", "backward"} | (
+            {"recompute"} if min(rungs) < rung else set())
+        assert seen[component] == every, (component, rungs)
     if vocab_chunk_size:
         assert seen["loss"] == {"forward", "backward"} and "head" not in seen
     else:
@@ -358,7 +376,10 @@ def test_compile_account_rows_totals_and_single_registration():
     assert len(compile_cache.compile_account()) == mid
 
     whole = compile_cache.compile_totals()
-    assert set(whole) == set(compile_cache.ACCOUNT_EVENTS.values())
+    # (and the newest checkpoint plan, where a step of this process made
+    # one: tests/test_remat_plan.py)
+    assert ({k for k in whole if not k.startswith("remat_")}
+            == set(compile_cache.ACCOUNT_EVENTS.values()))
     assert whole["compile_trace_s"] > 0 and whole["compile_backend_s"] > 0
     summary = Observer().summary()
     assert {k: summary[k] for k in whole} == whole
@@ -383,6 +404,7 @@ def test_compile_totals_count_a_nested_trace_and_a_retrieval_once(
     assert compile_cache.covered_s(rows[3:5]) == pytest.approx(2.0)
     assert compile_cache.covered_s([]) == 0.0
     monkeypatch.setattr(compile_cache, "_ROWS", [])
+    monkeypatch.setattr(compile_cache, "_PLANS", [])
     assert compile_cache.compile_totals() == {}
     monkeypatch.setattr(compile_cache, "_ROWS", rows)
     assert compile_cache.compile_totals() == {
