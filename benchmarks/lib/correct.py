@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from . import modules
 from . import reference as ref
 from . import tokens as tok
 from . import weights
@@ -46,7 +47,13 @@ STEPS = 2
 PROJECTIONS = 32          # signed sums a leaf (the bits of one random word)
 NEVER = 1e30              # a gap that is not a number (JSON has no inf)
 DEAD_GRADIENT = 1e-3     # of the median leaf's reference gradient norm
-FAULTS = ("half_batch", "state_unchanged")
+
+
+def faults_of(chips: int) -> tuple:
+    """The faults a cell on ``chips`` chips can have: on one chip there is
+    no exchange to leave out."""
+    return ("half_batch", "state_unchanged") + (
+        ("exchange_left_out",) if chips > 1 else ())
 
 
 def _read(x, leaf_key):
@@ -88,23 +95,24 @@ def leaf_readings(tree: dict, key, scale: float = 1.0) -> dict:
         x, weights.leaf_key(key, p), scale), tree)
 
 
-def change_readings(params: dict, key) -> dict:
+def change_readings(params: dict, key, leaf_moments) -> dict:
     """:func:`leaf_readings` of ``params - (the seed's initial weights)``,
-    which are made again from the seed inside each leaf's program, never
-    kept."""
+    which are made again from the seed (by the family's ``leaf_moments``)
+    inside each leaf's program, never kept."""
     return _by_leaf(lambda p, x: _leaf_change_reading(
-        x, weights.leaf_key(key, p), *weights.leaf_moments(p, x.shape)),
-        params)
+        x, weights.leaf_key(key, p), *leaf_moments(p, x.shape)), params)
 
 
 def reference_step(cfg: dict, lr: float, precision: str = "f32",
                    fault: str | None = None):
     """``step(params, m, v, tokens, t) -> (params, m, v, loss, grads)``:
-    one step of the plain reference, not yet under ``jit``."""
+    one step of the plain reference (the family's forward pass, the shared
+    AdamW), not yet under ``jit``."""
     hyper = dict(ref.ADAMW, lr=lr)
+    loss_and_grads = modules.family_of(cfg).loss_and_grads
 
     def step(params, m, v, tokens, t):
-        loss, grads = ref.loss_and_grads(params, tokens, cfg, precision)
+        loss, grads = loss_and_grads(params, tokens, cfg, precision)
         if fault != "state_unchanged":
             params, m, v = ref.adamw_update(params, m, v, grads, t, **hyper)
         return params, m, v, loss, grads
@@ -115,21 +123,26 @@ def reference_step(cfg: dict, lr: float, precision: str = "f32",
 def reference_readings(cfg: dict, shapes: dict, seed: int, rows: int,
                        row_tokens: int, distribution: str, lr: float,
                        precision: str = "f32", fault: str | None = None,
-                       device=None) -> dict:
+                       device=None, chips: int = 1) -> dict:
     """Follow the first ``STEPS`` steps; returns on the host
     ``{"loss": [STEPS], "grad": readings, "change": readings}`` with
     :func:`leaf_readings`' form.
 
     ``precision`` other than ``"f32"`` makes this the control; ``fault``
-    plants one of :data:`FAULTS` in the reference put in the program's
-    place."""
-    if fault not in (None,) + FAULTS:
-        raise ValueError(f"unknown fault {fault!r}")
+    plants one of :func:`faults_of` ``chips`` in the reference put in the
+    program's place: no update, the mean over the first half of the rows,
+    or over the first chip's rows alone (what a data-parallel step applies
+    when the gradients' exchange between the ``chips`` is left out)."""
+    if fault not in (None,) + faults_of(chips):
+        raise ValueError(f"unknown fault {fault!r} on {chips} chip(s)")
+    kept_rows = {"half_batch": max(1, rows // 2),
+                 "exchange_left_out": rows // chips}.get(fault, rows)
     key = weights.seed_key(seed)
+    leaf_moments = modules.family_of(cfg).leaf_moments
 
     @jax.jit
     def init(key):
-        p = weights.make_params(key, shapes)
+        p = weights.make_params(key, shapes, leaf_moments)
         zeros = jax.tree.map(jnp.zeros_like, p)
         return p, zeros, jax.tree.map(jnp.zeros_like, p)
 
@@ -141,15 +154,14 @@ def reference_readings(cfg: dict, shapes: dict, seed: int, rows: int,
         for i in range(STEPS):
             batch = tok.batch_tokens(seed, i, rows, row_tokens,
                                      cfg["vocab_size"], distribution)
-            if fault == "half_batch":
-                batch = batch[: max(1, rows // 2)]
             params, m, v, loss, grads = step(
-                params, m, v, jnp.asarray(batch), jnp.float32(i + 1))
+                params, m, v, jnp.asarray(batch[:kept_rows]),
+                jnp.float32(i + 1))
             losses.append(loss)
             if i == 0:
                 grad = leaf_readings(grads, key)
             del grads
-        change = change_readings(params, key)
+        change = change_readings(params, key, leaf_moments)
         out = jax.device_get({"loss": losses, "grad": grad, "change": change})
     del params, m, v
     return on_host(out)

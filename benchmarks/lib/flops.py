@@ -41,8 +41,9 @@ def flash_train_work(cfg: dict, batch: int, positions: int,
     """Attention work of one train step, all layers: FLOPs and HBM bytes.
 
     Forward 4*B*H*S^2*D/2 (QK^T and PV, causal half), backward twice that
-    (dQ, dK, dV and dP; the recomputed QK^T is not credited).  Under
-    ``remat`` the forward runs twice; the second is not credited either.
+    (dQ, dK, dV and dP; the recomputed QK^T is not credited).  A forward
+    that ``remat`` runs again in the backward pass (a block whose checkpoint
+    plan keeps no ``flash_out``) is not credited either.
     Bytes: forward reads q, k, v and writes o; backward reads q, k, v, o,
     do and writes dq, dk, dv — 12 tensors of B*H*S*D activations.  Shapes
     only: the same whatever kernel ran.

@@ -1,14 +1,15 @@
-"""How the program's kernels are named in a device trace today.
+"""The flash kernels' events in a device trace, as one lump.
 
-The Pallas calls in ``dtdl_tpu/ops/attention.py`` carry no ``name=``.  On
-the chip (jax 0.9.0, my trace of ``olmo1b-train-b4s2048``, PR 24) an event
-of the ``XLA Ops`` line is named by its whole HLO text, and each flash
-kernel (forward, the forward again under ``remat``, the two backward
-kernels) is an instruction that XLA calls ``%attn.<n>`` after the flax
-module it sits in, with ``custom_call_target="tpu_custom_call"``: 32 of
-them a step at 8 layers.  A kernel that a later PR names (``name=`` on the
-``pallas_call``) keeps matching while its HLO name holds ``attn`` or
-``flash``; PERF.md says so to the tracing issue.
+On the chip (jax 0.9.0) an event of the ``XLA Ops`` line is named by its
+whole HLO text.  Until PR 25 the Pallas calls in
+``dtdl_tpu/ops/attention.py`` carried no ``name=`` and XLA called each
+``%attn.<n>`` after the flax module it sits in; since then they are
+``%flash_fwd.<n>``, ``%flash_bwd_dq.<n>`` and ``%flash_bwd_dkv.<n>``
+(``lib/program_names.py`` reads each alone), always with
+``custom_call_target="tpu_custom_call"``.  The pattern takes both: any
+Mosaic call whose instruction name holds ``attn`` or ``flash``.  A step of
+8 layers holds 24 of them where the checkpoint plan keeps ``flash_out``
+on every block (PR 26 on), and 8 more forward calls where it keeps none.
 """
 
 from . import xplane

@@ -5,7 +5,9 @@ reference can make the same ones again from the seed and takes nothing the
 program has made.  A leaf is addressed by its path in the program's
 parameter tree (``block_0/attn/q/kernel``); its key is the seed's key
 folded with a CRC of that path, so the values do not depend on the order
-or the number of leaves.
+or the number of leaves.  How a leaf is drawn (its mean and standard
+deviation, from its path and shape) is its family's to say
+(``lib/modules.py``): the callers hand ``leaf_moments`` in.
 """
 
 from __future__ import annotations
@@ -26,7 +28,8 @@ def seed_key(seed: int):
 
 
 def leaf_moments(path: str, shape) -> tuple[float, float]:
-    """``(mean, std)`` of a leaf's normal draw."""
+    """``(mean, std)`` of a leaf's normal draw: the dense family's rule
+    (``families/olmo.py`` binds it)."""
     name = path.split("/")
     if name[-1] == "scale":             # norm scale: 1 +- 10%
         return 1.0, 0.1
@@ -50,10 +53,10 @@ def draw_leaf(leaf_key_, shape, mean, std):
     return mean + std * jax.random.normal(leaf_key_, shape, jnp.float32)
 
 
-def make_leaf(key, path: str, shape):
+def make_leaf(key, path: str, shape, leaf_moments):
     return draw_leaf(leaf_key(key, path), shape, *leaf_moments(path, shape))
 
 
-def make_params(key, shapes: dict) -> dict:
+def make_params(key, shapes: dict, leaf_moments) -> dict:
     """``{path: shape}`` -> ``{path: f32 array}``; call it under ``jit``."""
-    return {p: make_leaf(key, p, s) for p, s in shapes.items()}
+    return {p: make_leaf(key, p, s, leaf_moments) for p, s in shapes.items()}

@@ -1,5 +1,7 @@
 """Device time of the flash forward kernel (``flash_fwd``: once a layer in
-the forward pass, once more under ``remat``) in one traced step."""
+the forward pass, and once more in the backward pass of each block whose
+checkpoint plan keeps no ``flash_out``; PERF.md section 3) in one traced
+step."""
 
 from lib import program_names
 

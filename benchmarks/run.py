@@ -8,13 +8,15 @@ branch for any cell, configuration, runner or metric:
 
 * the cell       -> ``BENCHMARK.json`` ``workloads`` + ``workloads/<cell>.json``
 * its traffic    -> ``traffic/<traffic>.json`` (parameters of the one generator)
-* its config     -> ``configs/<config>.json``
+* its config     -> ``configs/<config>.json``, and from that file its family
+  -> ``families/<model_type>.py`` beside ``configs/`` (``lib/modules.py``)
 * its runner     -> ``runners/<runner>.py`` (``run(cell, cfg, opts)``)
 * each per-layer metric that lists the cell -> ``metrics/<name>.py``
   (``read(record)`` returns a number, or None when there is nothing to read)
 
-No chip is an error.  ``--rehearse`` swaps in ``configs/rehearse-tiny.json``
-on the CPU platform (as many virtual devices as the cell has chips), says so
+No chip is an error.  ``--rehearse`` swaps in the stand-in that the
+configuration's file names (``"rehearsal"``, a file beside it) on the CPU
+platform (as many virtual devices as the cell has chips), says so
 in its output, and is never the default: a rehearsal's numbers are not
 device numbers.
 """
@@ -23,13 +25,15 @@ import time
 T_START = time.perf_counter()
 
 import argparse          # noqa: E402
-import importlib.util    # noqa: E402
 import json              # noqa: E402
 import os                # noqa: E402
 import sys               # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
+sys.path[:0] = [p for p in (HERE, ROOT) if p not in sys.path]
+
+from lib import modules  # noqa: E402
 
 
 def load_json(path):
@@ -38,14 +42,16 @@ def load_json(path):
 
 
 def load_module(kind, name):
-    path = os.path.join(HERE, kind, name + ".py")
-    if not os.path.isfile(path):
-        raise SystemExit(f"no {kind} file {path}")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+    return modules.load_file(os.path.join(HERE, kind, name + ".py"), kind)
+
+
+def load_config(path):
+    """A configuration's file as it is run, with where its family's file
+    is: ``families/<model_type>.py`` beside the file's ``configs/``."""
+    cfg = load_json(path)
+    cfg["family"] = os.path.join(os.path.dirname(os.path.dirname(path)),
+                                 "families", cfg["model_type"] + ".py")
+    return cfg
 
 
 def resolve(manifest, workload, rehearse, data=HERE):
@@ -67,11 +73,12 @@ def resolve(manifest, workload, rehearse, data=HERE):
     config = [c for c in manifest["configs"] if c["name"] == cell["config"]]
     if len(config) != 1:
         raise SystemExit(f"config {cell['config']!r} is not in the manifest")
-    cfg = load_json(os.path.join(ROOT, config[0]["file"]))
+    path = os.path.join(ROOT, config[0]["file"])
+    cfg = load_config(path)
     if rehearse:
-        tiny = load_json(os.path.join(HERE, "configs", "rehearse-tiny.json"))
-        cell.update(tiny.pop("cell"))
-        cfg = tiny
+        cfg = load_config(os.path.join(os.path.dirname(path),
+                                       cfg["rehearsal"] + ".json"))
+        cell.update(cfg.pop("cell"))
     return cell, cfg
 
 
@@ -81,7 +88,6 @@ def load_cell(workload, rehearse=False,
     ``rehearse`` it also puts JAX on the CPU platform with as many virtual
     devices as the cell has chips, so call it before anything else touches
     JAX's backend."""
-    sys.path[:0] = [p for p in (HERE, ROOT) if p not in sys.path]
     manifest = load_json(manifest)
     cell, cfg = resolve(manifest, workload, rehearse, data)
     if rehearse:

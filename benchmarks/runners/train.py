@@ -7,7 +7,9 @@ does — ``make_lm_train_step(strategy)`` on a ``TrainState`` placed by
 ``shard_map`` DDP with no change here.
 
 From the program it takes the system under test only.  Weights, batches,
-clocks, spans, the reference and every number come from ``benchmarks/``.
+clocks, spans, the reference and every number come from ``benchmarks/``;
+what differs from one model family to the next comes from the
+configuration's family (``lib/modules.py``).
 """
 
 from __future__ import annotations
@@ -18,22 +20,10 @@ import os
 import shutil
 import time
 
-from lib import correct, tokens, weights, xplane
+from lib import correct, modules, tokens, weights, xplane
 
 MAX_IN_FLIGHT = 2
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-
-def _model_kwargs(cfg: dict, remat: bool) -> dict:
-    """The program's names for the configuration file's (HF) keys."""
-    if cfg["hidden_size"] % cfg["num_attention_heads"]:
-        raise ValueError("hidden_size is not a multiple of the head count")
-    return dict(vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"],
-                n_layers=cfg["num_hidden_layers"],
-                n_heads=cfg["num_attention_heads"],
-                d_ff=cfg["intermediate_size"],
-                max_seq=cfg["max_position_embeddings"],
-                attn_impl="flash", remat=remat)
 
 
 def _leaf_path(path) -> str:
@@ -80,8 +70,9 @@ def make_plan(cell: dict, cfg: dict):
 
     if cell["optimizer"]["name"] != "adamw":
         raise ValueError("the train runner knows optax.adamw alone")
+    family = modules.family_of(cfg)
     model = TransformerLM(dtype=jnp.bfloat16,
-                          **_model_kwargs(cfg, bool(cell["remat"])))
+                          **family.model_kwargs(cfg, bool(cell["remat"])))
     tx = optax.adamw(float(cell["optimizer"]["lr"]))
     example = jnp.zeros((1, int(cell["row_tokens"]) - 1), jnp.int32)
     abstract = jax.eval_shape(
@@ -94,13 +85,13 @@ def make_plan(cell: dict, cfg: dict):
     shapes = {p: tuple(leaf.shape) for p, (_, leaf) in zip(paths, flat)}
 
     def build(key):
-        made = weights.make_params(key, shapes)
+        made = weights.make_params(key, shapes, family.leaf_moments)
         params = jax.tree_util.tree_unflatten(treedef,
                                               [made[p] for p in paths])
         return TrainState.create(apply_fn=model.apply, params=params, tx=tx)
 
     return types.SimpleNamespace(model=model, tx=tx, shapes=shapes,
-                                 paths=paths, build=build)
+                                 paths=paths, build=build, family=family)
 
 
 class Session:
@@ -217,7 +208,8 @@ class Session:
         for i in range(1, correct.STEPS):
             state = self.drive(state, i)
         change = correct.change_readings(self._by_path(state.params),
-                                         self.key)
+                                         self.key,
+                                         self.plan.family.leaf_moments)
         jax.block_until_ready(state)
         return state, correct.on_host(jax.device_get({
             "loss": self.losses[: correct.STEPS], "grad": grad,
@@ -228,7 +220,7 @@ class Session:
             self.cfg, self.plan.shapes, self.seed, self.rows,
             self.row_tokens, self.distribution,
             float(self.cell["optimizer"]["lr"]), precision=precision,
-            fault=fault)
+            fault=fault, chips=self.chips)
 
 
 def _first_moment(opt_state):
@@ -337,7 +329,8 @@ def run(cell: dict, cfg: dict, opts: dict, wrap_step=None) -> dict:
     chips = ses.chips
     target_tokens = ses.rows * (ses.row_tokens - 1)
     return {
-        "cell": cell, "config": cfg, "correct": ok, "compared": compared,
+        "cell": cell, "config": cfg, "shapes": ses.plan.shapes,
+        "correct": ok, "compared": compared,
         "notes": dict(
             {k: nums[k] for k in nums if k not in limits},
             setup_spans_s=dict(

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from _paths import BENCH
-from lib import flops, peaks, tokens, weights
+from lib import flops, modules, peaks, tokens, weights
 
 
 def _cfg(name):
@@ -19,11 +19,11 @@ def _cfg(name):
 @pytest.mark.parametrize("name", ["rehearse-tiny", "olmo-1b", "olmo-7b"])
 def test_train_flops_equal_the_programs(name):
     from dtdl_tpu.obs import goodput
-    from runners.train import _model_kwargs
-
     from dtdl_tpu.models.transformer import TransformerLM
     cfg = _cfg(name)
-    model = TransformerLM(**_model_kwargs(cfg, remat=True))
+    olmo = modules.load_file(os.path.join(BENCH, "families", "olmo.py"),
+                             "families")
+    model = TransformerLM(**olmo.model_kwargs(cfg, remat=True))
     for batch, row in ((1, 128), (4, 2048)):
         assert flops.lm_train_flops(cfg, batch, row) == pytest.approx(
             goodput.lm_train_flops(model, batch, row), rel=1e-12)
@@ -72,13 +72,15 @@ def test_batches_depend_on_seed_and_index_alone_and_rows_differ():
 def test_weights_depend_on_seed_and_path_alone():
     import jax
     key = weights.seed_key(2 ** 31 + 7)
-    a = weights.make_leaf(key, "block_0/attn/q/kernel", (8, 2, 4))
+    moments = weights.leaf_moments
+    a = weights.make_leaf(key, "block_0/attn/q/kernel", (8, 2, 4), moments)
     again = weights.make_params(key, {"x/kernel": (3, 3),
-                                      "block_0/attn/q/kernel": (8, 2, 4)})
+                                      "block_0/attn/q/kernel": (8, 2, 4)},
+                                moments)
     assert np.array_equal(a, again["block_0/attn/q/kernel"])
     other = weights.make_leaf(weights.seed_key(7), "block_0/attn/q/kernel",
-                              (8, 2, 4))
+                              (8, 2, 4), moments)
     assert not np.array_equal(a, other)
-    scale = weights.make_leaf(key, "ln_f/scale", (4096,))
+    scale = weights.make_leaf(key, "ln_f/scale", (4096,), moments)
     assert abs(float(scale.mean()) - 1.0) < 0.02
     assert jax.numpy.isfinite(a).all()

@@ -1,16 +1,19 @@
 """What decides ``correct``, at the rehearsal's size on the CPU: the plain
 reference agrees with the program, the float8 control does not, and a run
-whose timed path is broken underneath comes out as not correct."""
+whose timed path is broken underneath comes out as not correct: once for
+each fault a cell can have, on one CPU device and on four."""
 
 import json
 import os
-import time
+import subprocess
+import sys
 
 import pytest
 
-from _paths import ROOT
+from _paths import BENCH, ROOT
 
 CELL = "olmo1b-train-b4s2048"
+DDP_CELL = "olmo1b-train-ddp4"
 
 
 def _resolved():
@@ -19,22 +22,23 @@ def _resolved():
     return harness.resolve(manifest, CELL, rehearse=True)  # no JAX config
 
 
-def _run(tmp_path, seed, wrap_step=None):
-    """The rest of a run, without the harness's look for a chip."""
-    import jax
-    from runners import train
-    if jax.device_count() != 1:
-        pytest.skip("the one-chip cell rehearses on one CPU device")
-    cell, cfg = _resolved()
-    return train.run(cell, cfg, {
-        "seed": seed, "seconds": 0.2, "trace": False, "rehearse": True,
-        "t_start": time.perf_counter(), "scratch": str(tmp_path)},
-        wrap_step=wrap_step)
+def _run(cell, seed, fault=None):
+    """The rest of a run of a cell's rehearsal, without the harness's look
+    for a chip, in a process of its own (``_faults.py``): it takes as many
+    CPU devices as the cell has chips, whatever this process holds."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    done = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "tests", "_faults.py"), cell,
+         str(seed)] + ([fault] if fault else []),
+        env=env, capture_output=True, text=True, timeout=300)
+    assert done.returncode == 0, done.stderr[-2000:]
+    return json.loads(done.stdout.strip().splitlines()[-1])
 
 
-def test_sound_run_is_correct_and_prints_each_number_beside_its_limit(
-        tmp_path):
-    record = _run(tmp_path, seed=2 ** 31 + 5)
+@pytest.mark.parametrize("cell", [CELL, DDP_CELL])
+def test_sound_run_is_correct_and_prints_each_number_beside_its_limit(cell):
+    record = _run(cell, seed=2 ** 31 + 5)
     assert record["correct"] is True
     assert set(record["compared"]) == {
         "loss1_gap", "loss2_gap", "grad_gap", "change_gap", "grad_dir_gap",
@@ -46,36 +50,13 @@ def test_sound_run_is_correct_and_prints_each_number_beside_its_limit(
     assert record["attempted"] == record["window"]["steps"] + 3  # warm-up
 
 
-def _state_unchanged(compiled, ses):
-    import jax
-    import jax.numpy as jnp
-
-    def step(state, batch):
-        _, metrics = compiled(jax.tree.map(jnp.copy, state), batch)
-        return state, metrics
-    return step
-
-
-def _half_batch(compiled, ses):
-    """Half of the rows left out, the mean taken over the rest: the
-    program's own step with a mask over the targets of the first half."""
-    import jax.numpy as jnp
-
-    from dtdl_tpu.train import make_lm_train_step
-    masked = make_lm_train_step(ses.strategy)
-
-    def step(state, batch):
-        rows, row_tokens = batch["tokens"].shape
-        mask = (jnp.arange(rows) < rows // 2).astype(jnp.float32)
-        mask = jnp.broadcast_to(mask[:, None], (rows, row_tokens - 1))
-        return masked(state, dict(batch, mask=mask))
-    return step
-
-
-@pytest.mark.parametrize("fault, failing", [
-    (_state_unchanged, "change_gap"), (_half_batch, "grad_gap")])
-def test_broken_timed_path_is_not_correct(tmp_path, fault, failing):
-    record = _run(tmp_path, seed=77, wrap_step=fault)
+@pytest.mark.parametrize("cell, fault, failing", [
+    (CELL, "state_unchanged", "change_gap"), (CELL, "half_batch", "grad_gap"),
+    (DDP_CELL, "state_unchanged", "change_gap"),
+    (DDP_CELL, "half_batch", "grad_gap"),
+    (DDP_CELL, "exchange_left_out", "grad_gap")])
+def test_broken_timed_path_is_not_correct(cell, fault, failing):
+    record = _run(cell, seed=77, fault=fault)
     assert record["correct"] is False
     row = record["compared"][failing]
     assert row["value"] > row["limit"], record["compared"]
@@ -106,11 +87,14 @@ def test_planted_faults_in_the_reference_fail_too():
     args = (cfg, shapes, 5, cell["batch_per_chip"], cell["row_tokens"],
             "uniform", cell["optimizer"]["lr"])
     refr = correct.reference_readings(*args)
-    for fault in correct.FAULTS:
+    assert correct.faults_of(1) == ("half_batch", "state_unchanged")
+    for fault in correct.faults_of(4):
         nums = correct.numbers(
-            correct.reference_readings(*args, fault=fault), refr)
+            correct.reference_readings(*args, fault=fault, chips=4), refr)
         ok, table = correct.judge(nums, cell["limits"])
         assert not ok, (fault, table)
+    with pytest.raises(ValueError, match="exchange_left_out"):
+        correct.reference_readings(*args, fault="exchange_left_out")
     unchanged = correct.numbers(correct.reference_readings(
         *args, fault="state_unchanged"), refr)
     assert unchanged["change_gap"] == pytest.approx(1.0, abs=1e-4)

@@ -86,22 +86,26 @@ def test_cells_name_config_traffic_and_runner_files(manifest):
         for name, limit in cell["limits"].items():
             # every limit stands between the two readings it was set from:
             # the upper one is the least of the control's (3x the lower or
-            # more), the half-batch fault's (10x) and a state left
-            # unchanged's (3x)
+            # more), the half-batch fault's (10x), a state left
+            # unchanged's (3x) and, across chips, the left-out exchange's
+            # (10x)
             read = cell["limits_from"][name]
             lower = read["lower"]
             uppers = [read[key] for key, times in (
                 ("control_fp8_min", 3), ("half_batch_min", 10),
-                ("state_unchanged", 3))
+                ("state_unchanged", 3), ("exchange_left_out_min", 10))
                 if key in read and read[key] >= times * lower]
             assert read["upper"] == min(uppers), (w["name"], name)
             assert read["lower_runs"] >= 12
             assert read["control_seeds"] >= 3 and read["half_batch_seeds"] >= 3
+            if w["chips"] > 1:
+                assert read["exchange_left_out_seeds"] >= 3
             assert lower < limit < read["upper"], (w["name"], name)
             assert limit / lower >= read["upper"] / limit, (w["name"], name)
         # the control fails one of the cell's numbers, and so does each fault
         for reading in ("control_fp8_min", "half_batch_min",
-                        "state_unchanged"):
+                        "state_unchanged") + (
+                ("exchange_left_out_min",) if w["chips"] > 1 else ()):
             assert any(cell["limits_from"][n].get(reading, 0) > limit
                        for n, limit in cell["limits"].items()), reading
         pairs.add((w["config"], w["traffic"]))
@@ -129,7 +133,7 @@ def test_metrics_have_readers_and_move_what_their_cells_report(manifest):
                           "moves", "workloads"}
         assert os.path.isfile(
             os.path.join(BENCH, "metrics", m["name"] + ".py")), m["name"]
-        assert m["moves"] in e2e and m["moves"] != "setup_s"
+        assert m["moves"] in e2e
         assert reported_in(m) <= reported_in(e2e[m["moves"]])
         assert reported_in(m) <= set(cells)
         layers.add(m["layer"])

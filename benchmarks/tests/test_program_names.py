@@ -2,8 +2,7 @@
 the three flash kernels by their ``pallas_call`` names, on a recorded step
 with the new names and on the old one without; the set-up readers on a
 hand-built compile account and through ``run.py --rehearse --trace 1``; the
-manifest's new entries and the ones held back; and ``tools/scope_dump.py``'s
-reductions."""
+manifest's entries for both; and ``tools/scope_dump.py``'s reductions."""
 
 import glob
 import gzip
@@ -23,9 +22,6 @@ DATA = os.path.join(BENCH, "tests", "data")
 FLASH_READERS = ("flash.fwd_ms", "flash.bwd_dq_ms", "flash.bwd_dkv_ms")
 SETUP_READERS = ("setup.trace_lower_s", "setup.compile_load_s",
                  "setup.other_programs_s", "setup.cache_hit_pct")
-# BENCHMARK.json with the set-up entries, which move ``setup_s`` and wait
-# for the ``benchmark`` PR that lets a per-layer metric do so (PERF.md 7)
-HELD_BACK = os.path.join(DATA, "BENCHMARK.setup.json")
 NAMED = sorted(glob.glob(os.path.join(DATA, "trace_events.named.*.json.gz")))
 OLD = os.path.join(DATA, "trace_events.olmo1b-train-b4s2048.json.gz")
 
@@ -222,7 +218,7 @@ def test_rehearsed_traced_line_carries_the_setup_metrics():
     done = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
          "olmo7b-train-b2s2048", "--seed", str(2 ** 31 + 77), "--seconds",
-         "0.5", "--trace", "1", "--rehearse", "--manifest", HELD_BACK],
+         "0.5", "--trace", "1", "--rehearse"],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=300)
     assert done.returncode == 0, done.stderr[-2000:]
     line = json.loads(done.stdout.strip().splitlines()[-1])
@@ -244,7 +240,7 @@ def test_rehearsed_traced_line_carries_the_setup_metrics():
 
 
 # ---------------------------------------------------------------------------
-# the manifest's new entries, and the ones held back
+# the manifest's entries
 # ---------------------------------------------------------------------------
 
 def _manifest(path):
@@ -255,25 +251,21 @@ def _manifest(path):
 def test_manifest_entries_of_the_kernel_metrics():
     manifest = _manifest(os.path.join(ROOT, "BENCHMARK.json"))
     by_name = {m["name"]: m for m in manifest["per_layer"]}
-    assert [m["name"] for m in manifest["per_layer"]][-3:] == list(
-        FLASH_READERS)
     for name in FLASH_READERS:
         m = by_name[name]
         assert (m["moves"], m["source"], m["layer"], m["better"]) == (
             "train_tokens_per_s", "device_trace", "flash kernels", "lower")
-        assert m["workloads"] == by_name["flash_roofline"]["workloads"]
+        # every training cell goes through a runner that refuses a step
+        # without a Mosaic kernel: the flash metrics list no cells
+        assert "workloads" not in m and "workloads" not in by_name[
+            "flash_roofline"]
 
 
-def test_setup_entries_are_held_back_whole():
-    """``test_manifest.py`` lets no per-layer metric move ``setup_s``, so
-    the set-up entries wait in a manifest of their own: BENCHMARK.json, the
-    four entries at the end of ``per_layer``, and nothing else."""
+def test_manifest_entries_of_the_setup_metrics():
+    """The four set-up entries move ``setup_s``, in every cell (PR 27 took
+    them out of a manifest of their own into BENCHMARK.json)."""
     manifest = _manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    held = _manifest(HELD_BACK)
-    assert not [m["name"] for m in manifest["per_layer"]
-                if m["moves"] == "setup_s"]
-    entries = held["per_layer"][len(manifest["per_layer"]):]
-    assert dict(held, per_layer=held["per_layer"][:-len(entries)]) == manifest
+    entries = [m for m in manifest["per_layer"] if m["moves"] == "setup_s"]
     assert [m["name"] for m in entries] == list(SETUP_READERS)
     with open(os.path.join(ROOT, "PERF.md")) as f:
         perf = f.read()

@@ -1,5 +1,8 @@
 """``run.py`` as the driver runs it: the last line's keys, on one and on
-four virtual CPU devices (DDP as data), and its refusals."""
+four virtual CPU devices (DDP as data: a test cell and the benchmark's own
+``olmo1b-train-ddp4``), a cell of a second family (``tests/data/``: its
+family file, configuration, cell and manifest, and no file outside knows of
+them), and its refusals."""
 
 import json
 import os
@@ -25,7 +28,10 @@ def _run(*args, cwd=ROOT, env=None):
 @pytest.mark.parametrize("extra, cell, chips", [
     ([], "olmo1b-train-b4s2048", 1),
     (["--manifest", os.path.join(DATA, "BENCHMARK.ddp4.json"),
-      "--data", DATA], "test-ddp4", 4)])
+      "--data", DATA], "test-ddp4", 4),
+    ([], "olmo1b-train-ddp4", 4),
+    (["--manifest", os.path.join(DATA, "BENCHMARK.toy.json"),
+      "--data", DATA], "test-toy", 1)])
 @pytest.mark.parametrize("trace", [0, 1])
 def test_rehearsal_prints_the_contracts_last_line(extra, cell, chips, trace):
     done = _run("--workload", cell, "--seed", str(2 ** 31 + 99),

@@ -69,8 +69,8 @@ def _reader(name):
 
 
 def _record(events, steps=2, t0=0.0, t1=2000.0):
-    with open(os.path.join(BENCH, "configs", "olmo-1b.json")) as f:
-        cfg = json.load(f)
+    import run as harness
+    cfg = harness.load_config(os.path.join(BENCH, "configs", "olmo-1b.json"))
     ops = {0: xplane.clip(events, t0, t1)}
     return {
         "config": cfg,

@@ -10,8 +10,9 @@
 * each ``--control-seeds`` seed: the reference in float8 put in the
   program's place (upper readings);
 * each ``--fault-seeds`` seed: the reference with half of the batch left
-  out put in the program's place (a state left unchanged reads 1 by the
-  measure and needs no run).
+  out put in the program's place and, for a cell across chips, with all
+  but the first chip's rows left out, as when the gradients' exchange is
+  (a state left unchanged reads 1 by the measure and needs no run).
 
 One JSON object per reading on standard output, and all of them in
 ``chiprun_out/readings.<cell>.json``.  Not part of a benchmark run.
@@ -75,8 +76,10 @@ def main(argv=None):
              refr, control_s=time.perf_counter() - t0)
     for seed in args.fault_seeds:
         refr = reference(seed)
-        emit("fault:half_batch", seed,
-             ses.reference("f32", fault="half_batch"), refr)
+        for fault in correct.faults_of(ses.chips):
+            if fault != "state_unchanged":
+                emit("fault:" + fault, seed,
+                     ses.reference("f32", fault=fault), refr)
 
     os.makedirs(args.out, exist_ok=True)
     with open(os.path.join(args.out, f"readings.{args.workload}.json"),
