@@ -18,6 +18,8 @@ name            value                                        placed in
                 them (unrotated)
 ``attn_out``    the ``out`` projection's output              Attention
 ``mlp_up``      the ``wi`` and ``wg`` outputs                SwiGLU
+``gdn_t``       the delta rule's ``T``, 64 x 64 a chunk a     ops/gated_delta.py
+                value head (a linear-attention block)
 ==============  ===========================================  =================
 
 **The ladder.**  :data:`RUNGS` orders them by the recomputation a kept byte
@@ -30,6 +32,10 @@ elementwise and fuses into ``wo``'s backward).  :func:`ladder` fills rung 1
 on every block, then rung 2, then rung 3, block 0 first, and stops at the
 first residual that does not fit the budget: so the first *k* blocks may
 stand one rung above the rest.  MoE blocks carry the attention names only.
+A linear-attention block (Gated DeltaNet) has one rung of its own, ``gdn_t``
+(the ten small matmuls that invert ``I + A`` go; measured 4.4 ms a layer a
+step on a v5e, PERF.md section 6, PR 29), filled in the ladder's first pass
+beside the other blocks' ``flash_out``.
 
 **The budget** is computed, never set: the device's
 ``memory_stats()["bytes_limit"]`` (:func:`device_bytes_limit`) less
@@ -55,9 +61,13 @@ from typing import NamedTuple, Sequence
 import jax
 
 from dtdl_tpu.ops.attention import FLASH_OUT, FLASH_QKV
+from dtdl_tpu.ops.gated_delta import GDN_LOOP, GDN_T
+from dtdl_tpu.ops.grouped_matmul import held_buffer_rows
 
 ATTN_OUT = "attn_out"
 MLP_UP = "mlp_up"
+GDN_IN = "gdn_in"       # a linear-attention block's ``in_qkvz`` output
+MOE_PLAN = "moe_plan"   # the held experts' choice and sort (a megabyte)
 
 RUNGS = ("recompute", "flash", "attn_proj", "mlp")
 # the names each rung adds to those of the rung below it
@@ -72,27 +82,60 @@ _RUNG_NAMES = ((), (FLASH_OUT,), (FLASH_QKV, ATTN_OUT), (MLP_UP,))
 MARGIN = 1 / 16
 
 
-def saved_names(rung: int) -> tuple[str, ...]:
-    """The checkpoint names a block at ``rung`` keeps."""
-    return sum(_RUNG_NAMES[:rung + 1], ())
+# a linear-attention block's own ladder, by the recomputation a kept byte
+# removes (measured on a v5e, PERF.md section 6, PR 29: 66, 14 and 11 ms/GB)
+_LINEAR_RUNG_NAMES = ((), (GDN_T,), (GDN_LOOP,), (GDN_IN,))
 
 
-def policy(rung: int):
-    """The ``jax.checkpoint`` policy of a block at ``rung``; None at rung 0
-    (no policy: the program ``remat=True`` compiled before there was a plan)."""
-    if rung == 0:
+def saved_names(rung: int, linear: bool = False,
+                held: bool = False) -> tuple[str, ...]:
+    """The checkpoint names a block at ``rung`` keeps.  A linear-attention
+    block has rungs of its own: the delta rule's ``T`` (ten small matmuls a
+    chunk go), what its loop reads (the chunk-local part goes), the
+    ``in_qkvz`` output (the projection goes).  A block with held experts
+    always keeps ``moe_plan``: the top-k and the sort of the assignments are
+    a megabyte to keep and milliseconds to run again."""
+    names = _LINEAR_RUNG_NAMES if linear else _RUNG_NAMES
+    return sum(names[:rung + 1], ()) + ((MOE_PLAN,) if held else ())
+
+
+def policy(rung: int, linear: bool = False, held: bool = False):
+    """The ``jax.checkpoint`` policy of a block at ``rung``; None where it
+    keeps nothing (rung 0 of a dense block: the program ``remat=True``
+    compiled before there was a plan)."""
+    names = saved_names(rung, linear, held)
+    if not names:
         return None
-    return jax.checkpoint_policies.save_only_these_names(*saved_names(rung))
+    return jax.checkpoint_policies.save_only_these_names(*names)
+
+
+def gdn_residual_bytes(batch: int, seq: int, d_model: int, gdn,
+                       itemsize: int, chunk: int = 64) -> tuple[int, int, int]:
+    """Bytes a linear-attention block (``gdn``: models/transformer.py's
+    ``GdnSpec``) keeps at its rungs 1, 2 and 3, each beyond the rung below:
+    ``T`` in float32; the loop's five inputs in the compute dtype; the
+    ``in_qkvz`` output."""
+    hk, hv, dk, dv = (gdn.key_heads, gdn.value_heads, gdn.key_dim,
+                      gdn.value_dim)
+    padded = -(-seq // chunk) * chunk * batch
+    return (padded * hv * chunk * 4,
+            padded * hv * (3 * dk + dv + chunk) * itemsize,
+            batch * seq * (2 * hk * dk + 2 * hv * dv) * itemsize)
 
 
 def residual_bytes(batch: int, seq: int, d_model: int, n_heads: int,
-                   d_ff: int, itemsize: int) -> tuple[int, int, int]:
+                   d_ff: int, itemsize: int,
+                   attn_width: int | None = None) -> tuple[int, int, int]:
     """Bytes one block keeps at rungs 1, 2 and 3, each beyond the rung below:
     ``o`` + f32 log-sum-exp; ``q k v`` + the ``out`` projection's output;
-    ``wi`` + ``wg`` (``d_ff`` 0 for a MoE block, which has no rung 3)."""
+    ``wi`` + ``wg`` (``d_ff`` 0 for a MoE block, which has no rung 3).
+    ``attn_width`` is heads times head size where the model states a head
+    size of its own (``q k v`` as the kernels take them, K/V repeated to
+    the query heads); ``d_model`` otherwise."""
     t = batch * seq
-    return (t * d_model * itemsize + batch * n_heads * seq * 4,
-            4 * t * d_model * itemsize,
+    width = d_model if attn_width is None else attn_width
+    return (t * width * itemsize + batch * n_heads * seq * 4,
+            (3 * width + d_model) * t * itemsize,
             2 * t * d_ff * itemsize)
 
 
@@ -141,19 +184,73 @@ def step_held_bytes(state_bytes: int, param_leaf_bytes: Sequence[int],
 
 def model_held_bytes(batch: int, seq: int, d_model: int, d_ff: int,
                      n_layers: int, vocab_size: int, param_bytes: int,
-                     itemsize: int) -> int:
+                     itemsize: int,
+                     block_live_bytes: int | None = None) -> int:
     """What the model's forward and backward hold under rung 0, from shapes:
     the f32 logits and their cotangent in the compute dtype (``vocab_size``
     0 where the caller takes the hidden states instead), the parameters'
     copy in the compute dtype (made in the forward pass and read again in
-    the backward), every block's input, and one block's live set while it is
+    the backward; an untied head's table is a parameter like any other),
+    every block's input, and one block's live set while it is
     recomputed and differentiated (``wi wg``, their product and the three
-    cotangents; eight ``[tokens, d_model]`` values of the attention half)."""
+    cotangents; eight ``[tokens, d_model]`` values of the attention half),
+    or ``block_live_bytes`` where the blocks are not the dense one
+    (:func:`hybrid_block_live_bytes`, the largest block's)."""
     t = batch * seq
+    if block_live_bytes is None:
+        block_live_bytes = t * (6 * d_ff + 8 * d_model) * itemsize
     return (t * vocab_size * (4 + itemsize)
             + param_bytes * itemsize // 4
             + n_layers * t * d_model * itemsize
-            + t * (6 * d_ff + 8 * d_model) * itemsize)
+            + block_live_bytes)
+
+
+def hybrid_block_live_bytes(batch: int, seq: int, d_model: int,
+                            itemsize: int, attn_width: int = 0,
+                            gdn=None, held=None, d_ff: int = 0,
+                            chunk: int = 64) -> int:
+    """One hybrid block's live set (models/transformer.py:BlockSpec) while
+    it is recomputed and differentiated.
+
+    A full-attention block: the doubled q, K/V repeated to the query heads,
+    the rotated copies, ``o`` and the gated ``o`` (eight ``attn_width``
+    values), each with its cotangent.  A Gated DeltaNet block (``gdn``, a
+    ``GdnSpec``): the ``in_qkvz`` projection, the conv's input and output,
+    and the delta rule's values as
+    ops/gated_delta.py holds them (q and k in float32 at the key heads; v
+    and the loop's five inputs in the compute dtype; the decay, ``A`` and
+    ``T`` in float32, ``chunk x chunk`` a chunk a value head; ``o`` in
+    float32; the state at every chunk), and half as much again for the
+    cotangents that are live at once.  Held experts (``held``, a
+    ``HeldSpec``): the ``R``-row buffers (rows, gate, up, their product,
+    the output), each with its cotangent, and the three weights' float32
+    gradients, which a grouped matmul writes whole.  A dense MLP: six
+    ``d_ff`` values.  How the step's estimate stands against the v5e
+    compiler's total for the Qwen3-Next cell is in PERF.md section 4."""
+    t = batch * seq
+    live = 4 * t * d_model * itemsize
+    if attn_width:
+        live += 2 * 8 * t * attn_width * itemsize
+    if gdn:
+        hk, hv, dk, dv = (gdn.key_heads, gdn.value_heads, gdn.key_dim,
+                          gdn.value_dim)
+        conv = 2 * hk * dk + hv * dv
+        states = -(-seq // chunk) * batch * hv * dk * dv * 4
+        values = (t * (conv + hv * dv) * itemsize       # in_qkvz
+                  + 2 * t * conv * itemsize             # the conv, in and out
+                  + 2 * t * hk * dk * 4                 # q, k
+                  + t * hv * (dv + 3 * dk + dv + chunk) * itemsize
+                  + 3 * t * hv * chunk * 4              # decay, A, T
+                  + t * hv * dv * 4 + states)           # o, the states
+        live += values + values // 2
+    if held:
+        rows, _ = held_buffer_rows(t, held.top_k, held.held,
+                                   held.router_width)
+        live += 2 * rows * (2 * d_model + 3 * held.d_ff) * itemsize
+        live += (3 * held.held * d_model * held.d_ff * 4
+                 + 2 * t * held.router_width * 4)
+        live += 2 * 3 * t * held.shared_d_ff * itemsize
+    return live + 2 * 3 * t * d_ff * itemsize
 
 
 class StepMemory(NamedTuple):
@@ -176,6 +273,12 @@ class RematPlan(NamedTuple):
 # outside a train step: nobody to plan for, no limit known
 _STEP: contextvars.ContextVar[StepMemory] = contextvars.ContextVar(
     "dtdl_tpu_step_memory", default=StepMemory(None, 0, None))
+
+
+def traced_step_name() -> str | None:
+    """The name of the train step that is tracing the model now, None
+    outside one (an ``init``, an ``eval_shape``, a forward pass alone)."""
+    return _STEP.get().fun_name
 
 
 def device_bytes_limit() -> int | None:

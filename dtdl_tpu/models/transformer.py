@@ -27,8 +27,10 @@ projections), 'mlp' (FFN hidden), 'expert' (MoE).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Any
+import math
+from typing import Any, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -37,11 +39,15 @@ from jax.ad_checkpoint import checkpoint_name
 
 from dtdl_tpu.models import remat_plan
 from dtdl_tpu.ops.attention import flash_attention, mha_reference
+from dtdl_tpu.ops.gated_delta import gated_delta_rule
+from dtdl_tpu.ops.grouped_matmul import (
+    ROW_TILE, grouped_matmul, held_buffer_rows, rows_of, weighted_rows_sum)
 from dtdl_tpu.ops.paged_attention import paged_attention
 from dtdl_tpu.ops.rope import apply_rope, rope_frequencies
 from dtdl_tpu.quant import (QuantDenseGeneral, canon_kv_dtype, kv_quantize,
                             kv_scale_dtype, weight_dtypes)
-from dtdl_tpu.runtime.compile_cache import record_remat_plan
+from dtdl_tpu.runtime.compile_cache import (record_expert_buffer,
+                                            record_remat_plan)
 
 Dtype = Any
 
@@ -94,14 +100,22 @@ def _required_cache_leaf(name):
 class RMSNorm(nn.Module):
     eps: float = 1e-6
     dtype: Dtype = jnp.float32
+    # ``x_hat * (1 + scale)`` with the scale drawn around 0, in place of
+    # ``x_hat * scale`` around 1
+    zero_centered: bool = False
+    axis_name: str = "embed"      # logical axis of the scale
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", _part(nn.initializers.ones, "embed"),
+        init = nn.initializers.zeros if self.zero_centered \
+            else nn.initializers.ones
+        scale = self.param("scale", _part(init, self.axis_name),
                            (x.shape[-1],))
         x32 = x.astype(jnp.float32)
         norm = x32 * jax.lax.rsqrt(
             jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
+        if self.zero_centered:
+            scale = 1.0 + scale
         return (norm * scale).astype(self.dtype)
 
 
@@ -113,9 +127,28 @@ class Attention(nn.Module):
     quantize: Any = False         # weight-only projections (serve):
     #                               True/'int8' -> int8, 'w8f' -> fp8
     paged_kernel: bool = False    # Pallas paged attend (kernel round 2)
+    # grouped-query / gated attention (all off: the module above, bit for
+    # bit).  ``n_kv_heads`` K/V heads each serve ``n_heads // n_kv_heads``
+    # query heads; ``rope_dims`` rotates only the head's first dims (the
+    # rotation then runs outside the kernel, whose fused one turns the
+    # whole head); ``qk_norm`` is an RMSNorm over the head on q and k;
+    # ``gate`` doubles the q projection and multiplies the attention's
+    # output by the sigmoid of the second half.
+    n_kv_heads: int = 0
+    rope_dims: int = 0
+    qk_norm: bool = False
+    gate: bool = False
+    norm_zero_centered: bool = False
+
+    @property
+    def grouped(self) -> bool:
+        return bool(self.n_kv_heads or self.rope_dims or self.qk_norm
+                    or self.gate)
 
     @nn.compact
     def __call__(self, x, cos, sin, decode: bool = False):
+        if self.grouped:
+            return self._grouped_attend(x, cos, sin, decode)
         d_model = x.shape[-1]
         def proj(name):
             if self.quantize:
@@ -187,6 +220,68 @@ class Attention(nn.Module):
             t = jnp.einsum("bshe,bher->bsr", o.astype(a.dtype), a)
             out = out + jnp.einsum("bsr,brd->bsd", t, bb).astype(out.dtype)
         return out
+
+    def _grouped_attend(self, x, cos, sin, decode):
+        """Grouped-query heads with partial rotary, q/k norm and an output
+        gate (class fields).  K and V are repeated to the query heads
+        before the flash kernels, which take as many K/V heads as Q heads,
+        and the rotation is applied outside them: both are copies a later
+        change can remove."""
+        if decode or self.quantize:
+            raise NotImplementedError(
+                "grouped-query / gated attention trains only: decoding "
+                "through a KV cache and weight-only serving of it are "
+                "missing (Attention._decode_attend takes as many K/V "
+                "heads as Q heads and rotates the whole head)")
+        d_model = x.shape[-1]
+        h, d = self.n_heads, self.head_dim
+        kv = self.n_kv_heads or h
+        if h % kv:
+            raise ValueError(f"{h} query heads over {kv} K/V heads")
+
+        def proj(name, heads, width):
+            return nn.DenseGeneral(
+                features=(heads, width), axis=-1, use_bias=False,
+                dtype=self.dtype,
+                kernel_init=_part(nn.initializers.lecun_normal(),
+                                  "embed", "heads", "head_dim"),
+                name=name)(x)
+
+        q = proj("q", h, 2 * d if self.gate else d)
+        gate = None
+        if self.gate:
+            q, gate = q[..., :d], q[..., d:]
+        k, v = proj("k", kv, d), proj("v", kv, d)
+        if self.qk_norm:
+            def head_norm(name):
+                return RMSNorm(dtype=self.dtype, axis_name="head_dim",
+                               zero_centered=self.norm_zero_centered,
+                               name=name)
+            q, k = head_norm("q_norm")(q), head_norm("k_norm")(k)
+        # [B, S, H, D] -> [B, H, S, D]
+        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+        r = self.rope_dims or d
+        q = jnp.concatenate([apply_rope(q[..., :r], cos, sin), q[..., r:]],
+                            axis=-1)
+        k = jnp.concatenate([apply_rope(k[..., :r], cos, sin), k[..., r:]],
+                            axis=-1)
+        k, v = (jnp.repeat(t, h // kv, axis=1) for t in (k, v))
+        if self.attn_impl == "flash":
+            o = flash_attention(q, k, v, causal=True)
+        else:
+            o = mha_reference(q, k, v, causal=True).astype(self.dtype)
+        o = o.transpose(0, 2, 1, 3)
+        if gate is not None:
+            with jax.named_scope("gate"):
+                o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+                    o.dtype)
+        out = nn.DenseGeneral(
+            features=d_model, axis=(-2, -1), use_bias=False,
+            dtype=self.dtype,
+            kernel_init=_part(nn.initializers.lecun_normal(),
+                              "heads", "head_dim", "embed"),
+            name="out")(o)
+        return checkpoint_name(out, remat_plan.ATTN_OUT)
 
     # prefill query rows are processed in blocks of this many: peak
     # attention memory stays O(chunk * max_seq) instead of the
@@ -862,6 +957,282 @@ class MoE(nn.Module):
         return out.reshape(-1, s_full + pad, d_model)[:, :s_full]
 
 
+class DepthwiseConv(nn.Module):
+    """Causal depthwise conv over the sequence, no bias: ``y_t = sum_j
+    w_j x_{t - (width - 1) + j}`` a channel, zeros before the first token."""
+    width: int = 4
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param(
+            "kernel", _part(nn.initializers.normal(stddev=0.5), None, "mlp"),
+            (self.width, x.shape[-1]))
+        seq = x.shape[1]
+        xp = jnp.pad(x.astype(self.dtype),
+                     ((0, 0), (self.width - 1, 0), (0, 0)))
+        taps = kernel.astype(self.dtype)
+        return sum(taps[j] * xp[:, j:j + seq] for j in range(self.width))
+
+
+class GatedDeltaNet(nn.Module):
+    """Gated DeltaNet linear attention (arXiv:2412.06464), as Qwen3-Next
+    lays it out: ``in_qkvz`` gives each of ``key_heads`` key heads its q, k
+    (``key_dim``), and for the ``value_heads // key_heads`` value heads it
+    serves v and the output gate z (``value_dim`` each); ``in_ba`` their
+    write strength and decay inputs.  q, k, v pass a causal depthwise conv
+    and SiLU; q and k are L2-normalised over the head, q scaled by
+    ``key_dim ** -0.5``; ``beta = sigmoid(b)``, ``g = -exp(A_log) *
+    softplus(a + dt_bias)`` in float32.  The rule itself is
+    ops/gated_delta.py; its output passes an RMSNorm over the value head,
+    times ``silu(z)``, and ``out``.  Trains only: decoding needs the
+    recurrent state and the conv's tail beside the K/V cache."""
+    key_heads: int
+    value_heads: int
+    key_dim: int
+    value_dim: int
+    conv_width: int = 4
+    eps: float = 1e-6
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, d_model = x.shape
+        hk, hv, dk, dv = (self.key_heads, self.value_heads, self.key_dim,
+                          self.value_dim)
+        r = hv // hk
+        if hv % hk:
+            raise ValueError(f"{hv} value heads over {hk} key heads")
+
+        def proj(name, width, dtype):
+            return nn.DenseGeneral(
+                features=(hk, width), axis=-1, use_bias=False, dtype=dtype,
+                kernel_init=_part(nn.initializers.lecun_normal(),
+                                  "embed", "heads", "head_dim"),
+                name=name)
+
+        qkvz = checkpoint_name(
+            proj("in_qkvz", 2 * dk + 2 * r * dv, self.dtype)(x),
+            remat_plan.GDN_IN)
+        ba = proj("in_ba", 2 * r, jnp.float32)(x.astype(jnp.float32))
+        q, k, v, z = jnp.split(qkvz, [dk, 2 * dk, 2 * dk + r * dv], axis=-1)
+        mixed = jnp.concatenate(
+            [t.reshape(b, s, -1) for t in (q, k, v)], axis=-1)
+        mixed = nn.silu(DepthwiseConv(self.conv_width, self.dtype,
+                                      name="conv")(mixed))
+        q, k, v = jnp.split(mixed, [hk * dk, 2 * hk * dk], axis=-1)
+        q = q.reshape(b, s, hk, dk).astype(jnp.float32)
+        k = k.reshape(b, s, hk, dk).astype(jnp.float32)
+        v = v.reshape(b, s, hv, dv)
+
+        def l2(t):
+            return t * jax.lax.rsqrt(
+                jnp.sum(t * t, axis=-1, keepdims=True) + self.eps)
+
+        q, k = l2(q) * dk ** -0.5, l2(k)
+        a_log = self.param("A_log", _part(nn.initializers.zeros, "heads"),
+                           (hv,))
+        dt_bias = self.param("dt_bias", _part(nn.initializers.ones, "heads"),
+                             (hv,))
+        beta = jax.nn.sigmoid(ba[..., :r].reshape(b, s, hv))
+        g = -jnp.exp(a_log) * jax.nn.softplus(
+            ba[..., r:].reshape(b, s, hv) + dt_bias)
+        o = gated_delta_rule(                            # [B, S, Hv, Dv] f32
+            q, k, v, g, beta,
+            operand_dtype=None if self.dtype == jnp.float32 else self.dtype)
+        o = RMSNorm(eps=self.eps, dtype=jnp.float32, axis_name="head_dim",
+                    name="norm")(o)
+        o = o * nn.silu(z.reshape(b, s, hv, dv).astype(jnp.float32))
+        return nn.DenseGeneral(
+            features=d_model, axis=(-2, -1), use_bias=False,
+            dtype=self.dtype,
+            kernel_init=_part(nn.initializers.lecun_normal(),
+                              "heads", "head_dim", "embed"),
+            name="out")(o.astype(self.dtype))
+
+
+class _RoutedExperts(nn.Module):
+    """The held experts' weights and their grouped SwiGLU over the sorted
+    buffer (``HeldExperts`` plans the buffer)."""
+    held: int
+    d_ff: int
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, rows, tile_expert):
+        d_model = rows.shape[-1]
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+
+        def weight(name, shape, in_ax, out_ax):
+            return self.param(name, _part(init, "expert", in_ax, out_ax),
+                              shape).astype(self.dtype)
+
+        wi = weight("wi", (self.held, d_model, self.d_ff), "embed", "mlp")
+        wg = weight("wg", (self.held, d_model, self.d_ff), "embed", "mlp")
+        wo = weight("wo", (self.held, self.d_ff, d_model), "mlp", "embed")
+        h = nn.silu(grouped_matmul(rows, wg, tile_expert)) * \
+            grouped_matmul(rows, wi, tile_expert)
+        return grouped_matmul(h, wo, tile_expert)
+
+
+class _SharedExpert(nn.Module):
+    """``sigmoid(x w_s) * SwiGLU(x)``: the expert every token passes."""
+    d_ff: int
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        def dense(features, name, names):
+            return nn.Dense(features, use_bias=False, dtype=self.dtype,
+                            kernel_init=_part(nn.initializers.lecun_normal(),
+                                              *names), name=name)
+        h = nn.silu(dense(self.d_ff, "wg", ("embed", "mlp"))(x)) * \
+            dense(self.d_ff, "wi", ("embed", "mlp"))(x)
+        y = dense(x.shape[-1], "wo", ("mlp", "embed"))(h)
+        return jax.nn.sigmoid(dense(1, "gate", ("embed", None))(x)) * y
+
+
+class HeldExperts(nn.Module):
+    """One chip's share of an expert layer under expert parallelism.
+
+    The router scores all ``router_width`` experts of the model (float32
+    matmul and softmax), takes the ``top_k`` largest and divides their
+    probabilities by their sum.  This chip holds experts ``first ...
+    first + held``: it keeps the assignments that name one of them, and
+    computes ``sum_e p_e * W_down,e(silu(W_gate,e x) * W_up,e x)`` over
+    those alone.  What the absent experts would add is absent (no
+    all-to-all, nothing stands in for the other chips).  A shared expert
+    of width ``shared_d_ff``, if any, is computed whole and added.
+
+    **The buffer.**  The kept assignments are sorted by expert into a
+    buffer of ``R`` rows, ``R`` a function of shapes alone
+    (ops/grouped_matmul.py:``held_buffer_rows``: a stated multiple of the
+    assignments expected here under even routing, at most every choice of
+    every token, in whole row tiles; every expert's group starts on a tile
+    and holds at least one).  Rows are gathered, multiplied by their
+    expert's weights (every tile of the ``R`` rows is computed, whatever
+    was routed), and each token sums its rows weighted by ``p``
+    (``rows_of``, ``weighted_rows_sum``: gathers both ways).  So no
+    operation's cost follows the routing.  An assignment that does not fit
+    is never dropped in silence: the layer sows ``overflow_rows`` (and
+    ``live_rows``, the rows the aligned groups need) into the ``moe_stats``
+    collection, and the train step makes an overflowing step's loss
+    non-finite.  At the stated multiple of the Qwen3-Next share the buffer
+    holds every assignment the shapes allow and none can overflow.
+
+    No balance loss: the layer sows no ``aux_loss``.
+    """
+    router_width: int
+    first: int
+    held: int
+    top_k: int
+    d_ff: int
+    shared_d_ff: int = 0
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        if not (0 <= self.first
+                and self.first + self.held <= self.router_width
+                and 1 <= self.top_k <= self.router_width):
+            raise ValueError(
+                f"experts {self.first}..{self.first + self.held} of "
+                f"{self.router_width}, {self.top_k} a token")
+        b, s, d_model = x.shape
+        tokens, k, held = b * s, self.top_k, self.held
+        xf = x.reshape(tokens, d_model)
+        logits = nn.Dense(self.router_width, use_bias=False,
+                          dtype=jnp.float32,
+                          kernel_init=_part(nn.initializers.lecun_normal(),
+                                            "embed", "expert"),
+                          name="router")(xf.astype(jnp.float32))
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, idx = checkpoint_name(jax.lax.top_k(probs, k),
+                                     remat_plan.MOE_PLAN)    # [tokens, k]
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+
+        n_rows, expected = held_buffer_rows(tokens, k, held,
+                                            self.router_width)
+        step_name = remat_plan.traced_step_name()
+        if step_name is not None:
+            record_expert_buffer(step_name, n_rows, ROW_TILE, expected)
+        with jax.named_scope("moe_dispatch"):
+            local = idx.reshape(-1) - self.first             # [tokens * k]
+            local = jnp.where((local >= 0) & (local < held), local, held)
+            counts = jnp.sum(local[:, None] == jnp.arange(held)[None, :],
+                             axis=0, dtype=jnp.int32)        # [held]
+            tiles = jnp.maximum(1, -(-counts // ROW_TILE))
+            tile_end = jnp.cumsum(tiles)
+            group_start = (tile_end - tiles) * ROW_TILE      # buffer row
+            sorted_start = jnp.cumsum(counts) - counts       # in ``order``
+            order = checkpoint_name(jnp.argsort(local, stable=True),
+                                    remat_plan.MOE_PLAN)
+            tile_expert = jnp.minimum(
+                jnp.sum(jnp.arange(n_rows // ROW_TILE)[:, None]
+                        >= tile_end[None, :], axis=1), held - 1)
+            row_expert = jnp.repeat(tile_expert, ROW_TILE)   # [R]
+            offset = jnp.arange(n_rows) - group_start[row_expert]
+            live = (offset >= 0) & (offset < counts[row_expert])
+            # each row's assignment (tokens * k: none) ...
+            row_assign = jnp.where(live, order[jnp.where(
+                live, sorted_start[row_expert] + offset, 0)], tokens * k)
+            # ... and each assignment's row (n_rows: not held here, or
+            # behind the buffer's end)
+            rank = checkpoint_name(jnp.argsort(order), remat_plan.MOE_PLAN)
+            mine = jnp.minimum(local, held - 1)
+            row = group_start[mine] + rank - sorted_start[mine]
+            assign_row = jnp.where((local < held) & (row < n_rows), row,
+                                   n_rows).reshape(tokens, k)
+            fits = jnp.clip(n_rows - group_start, 0, counts)
+            self.sow("moe_stats", "overflow_rows",
+                     jnp.sum(counts - fits))
+            self.sow("moe_stats", "live_rows", tile_end[-1] * ROW_TILE)
+            rows = rows_of(xf, row_assign, assign_row)       # [R, d]
+        y = _RoutedExperts(held, self.d_ff, self.dtype,
+                           name="experts")(rows, tile_expert)
+        with jax.named_scope("moe_dispatch"):
+            out = weighted_rows_sum(y, gates, row_assign,
+                                    assign_row).astype(self.dtype)
+        if self.shared_d_ff:
+            out = out + _SharedExpert(self.shared_d_ff, self.dtype,
+                                      name="shared")(xf)
+        return out.reshape(b, s, d_model)
+
+
+class GdnSpec(NamedTuple):
+    """``GatedDeltaNet``'s sizes."""
+    key_heads: int
+    value_heads: int
+    key_dim: int
+    value_dim: int
+    conv_width: int
+
+
+class HeldSpec(NamedTuple):
+    """``HeldExperts``' sizes."""
+    router_width: int
+    first: int
+    held: int
+    top_k: int
+    d_ff: int
+    shared_d_ff: int
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockSpec:
+    """What a block is beyond the dense one (``TransformerLM``'s keywords,
+    gathered so that ``Block`` stays one module): the token mixer's kind
+    and sizes, the norms' centring, and the held experts' layer."""
+    kind: str = "full"                 # 'full' | 'linear'
+    n_kv_heads: int = 0
+    rope_dims: int = 0
+    qk_norm: bool = False
+    attn_gate: bool = False
+    norm_zero_centered: bool = False
+    gdn: Any = None                    # a GdnSpec on a linear layer
+    held: Any = None                   # a HeldSpec where experts are held
+
+
 class Block(nn.Module):
     n_heads: int
     head_dim: int
@@ -876,9 +1247,12 @@ class Block(nn.Module):
     quantize: Any = False         # weight-only matmuls (serve):
     #                               True/'int8' -> int8, 'w8f' -> fp8
     paged_kernel: bool = False    # Pallas paged attend (kernel round 2)
+    spec: Any = None              # a BlockSpec; None: the dense block
 
     @nn.compact
     def __call__(self, x, cos, sin, decode: bool = False):
+        if self.spec is not None:
+            return self._hybrid(x, cos, sin, decode)
         h = RMSNorm(dtype=self.dtype, name="ln_attn")(x)
         x = x + Attention(self.n_heads, self.head_dim, self.attn_impl,
                           self.dtype, quantize=self.quantize,
@@ -897,12 +1271,48 @@ class Block(nn.Module):
                            quantize=self.quantize, name="mlp")(h)
         return x
 
+    def _hybrid(self, x, cos, sin, decode):
+        """``h = x + Mixer(norm(x)); y = h + FFN(norm(h))`` with the mixer
+        and the expert layer the spec names."""
+        spec = self.spec
+
+        def norm(name):
+            return RMSNorm(dtype=self.dtype,
+                           zero_centered=spec.norm_zero_centered, name=name)
+
+        h = norm("ln_attn")(x)
+        if spec.kind == "linear":
+            if decode:
+                raise NotImplementedError(
+                    "a model with linear-attention layers trains only: "
+                    "decoding needs the delta rule's recurrent state and "
+                    "the conv's tail in the cache, and a step that "
+                    "advances them (GatedDeltaNet has neither)")
+            x = x + GatedDeltaNet(**spec.gdn._asdict(), dtype=self.dtype,
+                                  name="gdn")(h)
+        elif spec.kind == "full":
+            x = x + Attention(
+                self.n_heads, self.head_dim, self.attn_impl, self.dtype,
+                quantize=self.quantize, n_kv_heads=spec.n_kv_heads,
+                rope_dims=spec.rope_dims, qk_norm=spec.qk_norm,
+                gate=spec.attn_gate,
+                norm_zero_centered=spec.norm_zero_centered,
+                name="attn")(h, cos, sin, decode=decode)
+        else:
+            raise ValueError(f"unknown layer kind {spec.kind!r}")
+        h = norm("ln_mlp")(x)
+        if spec.held:
+            return x + HeldExperts(**spec.held._asdict(), dtype=self.dtype,
+                                   name="moe")(h)
+        return x + SwiGLU(self.d_ff, self.dtype, name="mlp")(h)
+
 
 @functools.cache
-def _remat_block(rung: int):
+def _remat_block(rung: int, linear: bool = False, held: bool = False):
     """``Block`` under ``jax.checkpoint`` with the policy of ``rung``
     (models/remat_plan.py); rung 0 is no policy, the whole forward again."""
-    return nn.remat(Block, static_argnums=(), policy=remat_plan.policy(rung))
+    return nn.remat(Block, static_argnums=(),
+                    policy=remat_plan.policy(rung, linear, held))
 
 
 class TransformerLM(nn.Module):
@@ -936,10 +1346,73 @@ class TransformerLM(nn.Module):
     # (dtdl_tpu/ops/paged_attention.py).  The serving engine resolves
     # its 'auto' flag to this bool at construction.
     paged_kernel: bool = False
+    # --- hybrid architectures (all at their defaults: the model above, the
+    # same parameter paths and the same compiled programs) -----------------
+    # per-layer token mixer, 'full' (softmax attention) or 'linear' (Gated
+    # DeltaNet); () = every layer 'full'
+    layer_kinds: tuple = ()
+    n_kv_heads: int = 0           # K/V heads; 0 = n_heads
+    attn_head_dim: int = 0        # stated head size; 0 = d_model // n_heads
+    rope_dims: int = 0            # rotated dims of a head; 0 = all of them
+    rope_theta: float = 10000.0
+    qk_norm: bool = False         # RMSNorm over the head on q and k
+    attn_gate: bool = False       # sigmoid output gate from a doubled q
+    norm_zero_centered: bool = False   # x_hat * (1 + w), w around 0
+    gdn_key_heads: int = 0        # linear attention: key heads,
+    gdn_value_heads: int = 0      # value heads,
+    gdn_key_dim: int = 0          # their head sizes,
+    gdn_value_dim: int = 0
+    gdn_conv: int = 4             # and the causal conv's width
+    # moe_dispatch='held' (one chip's share under expert parallelism,
+    # HeldExperts): n_experts are held here, ids moe_first_expert onward, of
+    # the moe_router_width the router scores; moe_top_k a token; expert width
+    # moe_d_ff (0 = d_ff), a shared expert of moe_shared_d_ff (0 = none)
+    moe_router_width: int = 0
+    moe_first_expert: int = 0
+    moe_d_ff: int = 0
+    moe_shared_d_ff: int = 0
+    tie_embeddings: bool = True   # False: a head table of its own, 'head'
 
     @property
     def head_dim(self):
-        return self.d_model // self.n_heads
+        return self.attn_head_dim or self.d_model // self.n_heads
+
+    @property
+    def hybrid(self) -> bool:
+        """Whether any block departs from the dense one (BlockSpec)."""
+        return bool(self.layer_kinds or self.n_kv_heads or self.rope_dims
+                    or self.qk_norm or self.attn_gate
+                    or self.norm_zero_centered
+                    or self.moe_dispatch == "held")
+
+    def block_specs(self, is_moe):
+        """A :class:`BlockSpec` a layer, or None a layer for the dense
+        model."""
+        if not self.hybrid:
+            return [None] * self.n_layers
+        kinds = self.layer_kinds or ("full",) * self.n_layers
+        if len(kinds) != self.n_layers:
+            raise ValueError(f"{len(kinds)} layer kinds for "
+                             f"{self.n_layers} layers")
+        held = None
+        if self.moe_dispatch == "held":
+            held = HeldSpec(
+                router_width=self.moe_router_width,
+                first=self.moe_first_expert, held=self.n_experts,
+                top_k=self.moe_top_k, d_ff=self.moe_d_ff or self.d_ff,
+                shared_d_ff=self.moe_shared_d_ff)
+        elif self.n_experts:
+            raise ValueError("hybrid blocks route through "
+                             "moe_dispatch='held' alone")
+        gdn = GdnSpec(self.gdn_key_heads, self.gdn_value_heads,
+                      self.gdn_key_dim, self.gdn_value_dim, self.gdn_conv)
+        return [BlockSpec(kind=kind, n_kv_heads=self.n_kv_heads,
+                          rope_dims=self.rope_dims, qk_norm=self.qk_norm,
+                          attn_gate=self.attn_gate,
+                          norm_zero_centered=self.norm_zero_centered,
+                          gdn=gdn if kind == "linear" else None,
+                          held=held if moe else None)
+                for kind, moe in zip(kinds, is_moe)]
 
     def cache_shapes(self, batch_size: int, per_slot_index: bool = False,
                      kv_dtype=None):
@@ -1057,20 +1530,40 @@ class TransformerLM(nn.Module):
                             self.paged_cache_shapes(n_slots, n_pages,
                                                     page_size, kv_dtype))
 
-    def _checkpoint_plan(self, tokens_shape, is_moe, return_hidden):
+    def _checkpoint_plan(self, tokens_shape, is_moe, return_hidden,
+                         specs=None):
         """What each rematerialized block keeps (models/remat_plan.py), from
         the traced token shape and this model's widths; recorded in the
         compile account beside the step that traces it."""
         batch, seq = tokens_shape
         itemsize = jnp.dtype(self.dtype).itemsize
-        costs = [remat_plan.residual_bytes(
-            batch, seq, self.d_model, self.n_heads,
-            0 if moe else self.d_ff, itemsize) for moe in is_moe]
-        held = remat_plan.model_held_bytes(
-            batch, seq, self.d_model, self.d_ff, self.n_layers,
-            0 if return_hidden else self.vocab_size,
-            remat_plan.tree_bytes(self.variables.get("params", {})),
-            itemsize)
+        param_bytes = remat_plan.tree_bytes(self.variables.get("params", {}))
+        vocab = 0 if return_hidden else self.vocab_size
+        if specs is None or specs[0] is None:
+            costs = [remat_plan.residual_bytes(
+                batch, seq, self.d_model, self.n_heads,
+                0 if moe else self.d_ff, itemsize) for moe in is_moe]
+            held = remat_plan.model_held_bytes(
+                batch, seq, self.d_model, self.d_ff, self.n_layers, vocab,
+                param_bytes, itemsize)
+        else:
+            # each block's own bytes: a linear block may keep the delta
+            # rule's T, a full one the attention names at its own head width
+            attn_width = self.n_heads * self.head_dim
+            costs = [remat_plan.residual_bytes(
+                batch, seq, self.d_model, self.n_heads, 0, itemsize,
+                attn_width=attn_width) if spec.kind == "full"
+                else remat_plan.gdn_residual_bytes(
+                    batch, seq, self.d_model, spec.gdn, itemsize)
+                for spec in specs]
+            live = max(remat_plan.hybrid_block_live_bytes(
+                batch, seq, self.d_model, itemsize,
+                attn_width=attn_width if spec.kind == "full" else 0,
+                gdn=spec.gdn, held=spec.held,
+                d_ff=0 if spec.held else self.d_ff) for spec in specs)
+            held = remat_plan.model_held_bytes(
+                batch, seq, self.d_model, 0, self.n_layers, vocab,
+                param_bytes, itemsize, block_live_bytes=live)
         plan = remat_plan.plan_checkpoints(costs, held)
         if plan.fun_name is not None:
             record_remat_plan(plan)
@@ -1094,24 +1587,43 @@ class TransformerLM(nn.Module):
             "embed", _part(nn.initializers.normal(stddev=0.02),
                            "vocab", "embed"),
             (self.vocab_size, self.d_model))
+        if decode and (self.hybrid or not self.tie_embeddings):
+            raise NotImplementedError(
+                "decode=True on a hybrid model (linear-attention layers, "
+                "grouped-query or gated attention, held experts, an untied "
+                "head): it trains only; serving it needs a recurrent state "
+                "beside the K/V pages and K/V heads in the cached attention")
         # the model's own two ops outside any flax submodule carry a scope
         # of their own (obs/trace.py:DEVICE_SCOPES), or a device trace can
         # tell them from the blocks by operand names alone
         with jax.named_scope("embed"):
             x = jnp.take(emb, tokens, axis=0).astype(self.dtype)
-        cos, sin = rope_frequencies(self.head_dim, self.max_seq)
+        if self.hybrid:
+            # the rotation runs outside the kernels, over the rotated dims
+            # and the traced positions alone
+            if tokens.shape[1] > self.max_seq:
+                raise ValueError(f"{tokens.shape[1]} positions exceed "
+                                 f"max_seq={self.max_seq}")
+            cos, sin = rope_frequencies(self.rope_dims or self.head_dim,
+                                        tokens.shape[1], self.rope_theta)
+        else:
+            cos, sin = rope_frequencies(self.head_dim, self.max_seq)
 
         # remat is a training-time memory/FLOPs trade; under decode it
         # would also trace the `decode` flag into a tracer (remat treats
         # every call arg as dynamic) — plain blocks for decode
         is_moe = [self.n_experts > 0 and (i + 1) % self.moe_every == 0
                   for i in range(self.n_layers)]
+        specs = self.block_specs(is_moe)
         rungs = None
         if self.remat and not decode:
             rungs = self._checkpoint_plan(tokens.shape, is_moe,
-                                          return_hidden).rungs
+                                          return_hidden, specs).rungs
         for i, moe in enumerate(is_moe):
-            block_cls = Block if rungs is None else _remat_block(rungs[i])
+            block_cls = Block if rungs is None else (
+                _remat_block(rungs[i]) if specs[i] is None
+                else _remat_block(rungs[i], specs[i].kind == "linear",
+                                  bool(specs[i].held)))
             block = block_cls(
                 self.n_heads, self.head_dim, self.d_ff,
                 n_experts=self.n_experts if moe else 0,
@@ -1122,13 +1634,22 @@ class TransformerLM(nn.Module):
                 moe_group_size=self.moe_group_size,
                 quantize=self.quantize,
                 paged_kernel=self.paged_kernel,
+                **({} if specs[i] is None else {"spec": specs[i]}),
                 name=f"block_{i}")
             # only pass the flag when set: a kwarg through nn.remat is
             # traced, and Attention branches on it in Python
             x = block(x, cos, sin, decode=True) if decode \
                 else block(x, cos, sin)
 
-        x = RMSNorm(dtype=self.dtype, name="ln_f")(x)
+        x = RMSNorm(dtype=self.dtype,
+                    zero_centered=self.norm_zero_centered, name="ln_f")(x)
+        if not self.tie_embeddings:
+            # a table of its own; declared before ``return_hidden`` returns
+            # so that the parameter tree does not depend on the caller
+            emb = self.param(
+                "head", _part(nn.initializers.normal(stddev=0.02),
+                              "vocab", "embed"),
+                (self.vocab_size, self.d_model))
         if return_hidden:
             return x
         with jax.named_scope("head"):

@@ -75,6 +75,12 @@ def lm_forward_flops(cfg, batch: int, seq: int) -> float:
     MoE layers count ACTIVATED expert compute (top_k x the dense MLP);
     router/dispatch/capacity overhead is deliberately not credited.
     """
+    if getattr(cfg, "hybrid", False):
+        raise ValueError(
+            "the dense formula does not hold for a hybrid model (linear-"
+            "attention layers, grouped-query gated attention, held experts "
+            "with a shared one): no FLOP count for it here; the benchmark "
+            "counts one (benchmarks/families/qwen3_next.py:train_flops)")
     t = seq
     qkvo = 4 * 2 * batch * t * cfg.d_model * (cfg.n_heads * cfg.head_dim)
     attn = 2 * 2 * batch * cfg.n_heads * t * t * cfg.head_dim * 0.5
@@ -105,6 +111,9 @@ def lm_decode_flops(cfg, batch: int, context: int) -> float:
     against the cache.  The per-token serving MFU numerator (decode is
     HBM-bound, so this fraction is honest about how far below peak the
     phase must sit — SCALING.md "Serving latency model")."""
+    if getattr(cfg, "hybrid", False):
+        raise ValueError("a hybrid model (linear-attention layers, held "
+                         "experts) does not decode: no FLOP count for it")
     qkvo = 4 * 2 * batch * cfg.d_model * (cfg.n_heads * cfg.head_dim)
     attn = 2 * 2 * batch * cfg.n_heads * context * cfg.head_dim
     mlp = 3 * 2 * batch * cfg.d_model * cfg.d_ff

@@ -170,8 +170,13 @@ EVENT_CATALOG = frozenset({
 # submodule); the rest in train/step.py.  With ``vocab_chunk_size > 0`` the
 # head matmul runs inside the chunked loss, tile by tile, and so under
 # ``loss``.
+# ``gdn`` wraps the gated delta rule itself (ops/gated_delta.py: the batched
+# chunk-local matmuls and the scan over chunks), ``moe_dispatch`` the held
+# experts' routing plan, gather and weighted scatter-add
+# (models/transformer.py:HeldExperts), ``gate`` the attention's sigmoid
+# output gate (under ``attn``: the component ``attn_gate``).
 DEVICE_SCOPES = frozenset({"embed", "head", "loss", "grad_sync", "update",
-                           "guard"})
+                           "guard", "gdn", "moe_dispatch", "gate"})
 
 # flax module scopes of models/transformer.py (flax puts them there; this
 # repo only names the modules) -> component
@@ -180,6 +185,14 @@ MODULE_SCOPES = {
     "ln_attn": "norm", "ln_mlp": "norm", "ln_f": "norm",
 }
 _ATTN_PROJECTIONS = frozenset({"q", "k", "v", "out"})
+# the sub-modules of the hybrid blocks, by the module they sit in: a Gated
+# DeltaNet layer (flax scope ``gdn``, the same word as the delta rule's own
+# scope inside it) and the held experts' layer (``moe``)
+_GDN_MODULES = {"in_qkvz": "gdn_proj", "in_ba": "gdn_proj",
+                "out": "gdn_proj", "conv": "gdn_conv", "norm": "gdn_other"}
+_MOE_MODULES = {"router": "moe_router", "shared": "moe_shared",
+                "experts": "moe_experts"}
+_ATTN_OTHER = {"gate": "attn_gate", "q_norm": "norm", "k_norm": "norm"}
 
 # pallas_call ``name=`` -> component.  On the chip the kernel's HLO
 # instruction takes this name (``%flash_fwd.<n> = ... custom-call(...)
@@ -188,6 +201,7 @@ _ATTN_PROJECTIONS = frozenset({"q", "k", "v", "out"})
 KERNEL_NAMES = {
     "flash_fwd": "flash", "flash_bwd_dq": "flash", "flash_bwd_dkv": "flash",
     "paged_attn": "paged_attn",
+    "moe_gmm": "moe_gmm", "moe_tgmm": "moe_gmm",
 }
 
 # names of the traced step functions of train/step.py: ``jit(<name>)`` in
@@ -209,8 +223,12 @@ def device_component(name_stack: str):
 
     ``component`` is a member of :data:`DEVICE_SCOPES`, a value of
     :data:`MODULE_SCOPES` or :data:`KERNEL_NAMES`, ``attn_proj`` for
-    ``attn/{q,k,v,out}``, or None where no catalogued scope is on the
-    stack.  ``pass`` is ``update`` for what follows the backward pass
+    ``attn/{q,k,v,out}``, ``attn_gate`` for ``attn/gate``; under a Gated
+    DeltaNet module ``gdn_proj`` (``gdn/{in_qkvz,in_ba,out}``), ``gdn_conv``,
+    ``gdn`` for the delta rule itself (``gdn/gdn``) and ``gdn_other`` for
+    the rest; under the held experts' ``moe`` the kernels' ``moe_gmm``,
+    ``moe_dispatch``, ``moe_router``, ``moe_shared``, ``moe_experts``; or
+    None where no catalogued scope is on the stack.  ``pass`` is ``update`` for what follows the backward pass
     (``grad_sync``, ``update``, ``guard``); else ``recompute`` under
     ``rematted_computation``, ``backward`` under a ``transpose(...)``,
     ``forward`` otherwise.  jax wraps a scope entered inside a transformed
@@ -222,20 +240,38 @@ def device_component(name_stack: str):
         while (m := _WRAPPED.match(element)):
             transposed = transposed or m.group(1) == "transpose"
             element = m.group(2)
-        names.append(element)
+        # flax names a module's method other than ``__call__`` as a scope
+        # of its own (``attn/attn._grouped_attend/q``): not a component
+        if not (names and element.startswith(names[-1] + ".")):
+            names.append(element)
     component = None
     for i, name in enumerate(names):
         component = (name if name in DEVICE_SCOPES else
                      MODULE_SCOPES.get(name) or KERNEL_NAMES.get(name))
         if component is None:
             continue
+        rest = names[i + 1:]
+        kernels = [KERNEL_NAMES[n] for n in rest if n in KERNEL_NAMES]
         if name == "attn":
-            rest = names[i + 1:]
-            kernels = [KERNEL_NAMES[n] for n in rest if n in KERNEL_NAMES]
             if kernels:
                 component = kernels[0]
             elif rest and rest[0] in _ATTN_PROJECTIONS:
                 component = "attn_proj"
+            elif rest and rest[0] in _ATTN_OTHER:
+                component = _ATTN_OTHER[rest[0]]
+        elif name == "gdn":
+            # the module's scope; the delta rule's own ``gdn`` inside it
+            # is the component ``gdn``
+            inner = rest[0] if rest else None
+            component = ("gdn" if inner == "gdn"
+                         else _GDN_MODULES.get(inner, "gdn_other"))
+        elif name == "moe":
+            if kernels:
+                component = kernels[0]
+            elif "moe_dispatch" in rest:
+                component = "moe_dispatch"
+            elif rest and rest[0] in _MOE_MODULES:
+                component = _MOE_MODULES[rest[0]]
         break
     if component in _AFTER_BACKWARD:
         return component, "update"

@@ -30,7 +30,10 @@ The account is one a process because jax's listeners are.
 (``models/remat_plan.py``): :func:`record_remat_plan` appends,
 :func:`remat_plans` returns them, and :func:`compile_totals` carries the
 newest as ``remat_blocks_by_rung``, ``remat_kept_bytes``,
-``remat_budget_bytes`` and ``remat_estimate_bytes``.
+``remat_budget_bytes`` and ``remat_estimate_bytes``.  A held-experts layer
+(``models/transformer.py:HeldExperts``) adds the shape of its row buffer
+the same way: :func:`record_expert_buffer`, :func:`expert_buffers`, and
+``moe_buffer_rows``, ``moe_row_tile``, ``moe_expected_rows`` in the totals.
 """
 
 from __future__ import annotations
@@ -68,6 +71,7 @@ class CompileRow(NamedTuple):
 
 _ROWS: list[CompileRow] = []
 _PLANS: list = []       # models.remat_plan.RematPlan, one a traced step
+_EXPERT_BUFFERS: list = []      # one a held-experts layer a traced step
 _listening = False
 
 
@@ -99,6 +103,21 @@ def enable_compile_cache() -> str:
 def record_remat_plan(plan) -> None:
     """Keep the checkpoint plan a train step was just traced with."""
     _PLANS.append(plan)
+
+
+def record_expert_buffer(fun_name: str, rows: int, row_tile: int,
+                         expected_rows: float) -> None:
+    """Keep the shape of the held experts' buffer a train step was just
+    traced with (models/transformer.py:HeldExperts): its rows, the row
+    tile, and the assignments expected under even routing."""
+    _EXPERT_BUFFERS.append({"fun_name": fun_name, "rows": rows,
+                            "row_tile": row_tile,
+                            "expected_rows": expected_rows})
+
+
+def expert_buffers() -> list:
+    """The expert buffers so far, oldest first (a copy)."""
+    return list(_EXPERT_BUFFERS)
 
 
 def remat_plans() -> list:
@@ -143,4 +162,9 @@ def compile_totals() -> dict:
         totals["remat_kept_bytes"] = plan.kept_bytes
         totals["remat_budget_bytes"] = plan.budget_bytes
         totals["remat_estimate_bytes"] = plan.estimate_bytes
+    if _EXPERT_BUFFERS:
+        newest = _EXPERT_BUFFERS[-1]
+        totals["moe_buffer_rows"] = newest["rows"]
+        totals["moe_row_tile"] = newest["row_tile"]
+        totals["moe_expected_rows"] = newest["expected_rows"]
     return totals

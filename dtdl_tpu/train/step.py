@@ -164,6 +164,12 @@ def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
     sow is silently dropped and capacity routing collapses onto few
     experts.  Reported as the ``moe_aux_loss`` metric; 0 disables.
 
+    A model whose expert layers hold one chip's share (``HeldExperts``)
+    adds the metrics ``moe_overflow_rows`` (assignments that did not fit
+    their buffers; such a step's loss is made non-finite) and
+    ``moe_live_rows`` (the most buffer rows a layer needed).  A model with
+    an untied head refuses ``vocab_chunk_size > 0``.
+
     ``guard`` folds the resil anomaly check into the program, exactly as
     in :func:`make_train_step`.
     """
@@ -197,6 +203,37 @@ def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
             aux = sum(leaves) / len(leaves)
             return moe_aux_weight * aux, aux
 
+        def held_experts(loss, variables):
+            """``(loss, stats)`` of a model whose expert layers hold a share
+            (models/transformer.py:HeldExperts sows ``moe_stats``): the
+            assignments that did not fit their buffers, summed over the
+            layers, and the most rows any layer's aligned groups needed.
+            An overflow is an error, never a silent drop: it makes the
+            step's loss non-finite."""
+            stats = variables.get("moe_stats", {})
+            if not stats:       # static at trace time: no such layer
+                return loss, None
+            flat = jax.tree_util.tree_flatten_with_path(stats)[0]
+
+            def of(name):
+                return [x for path, x in flat
+                        if any(getattr(k, "key", None) == name
+                               for k in path)]
+            overflow = sum(of("overflow_rows"))
+            loss = jnp.where(overflow > 0, jnp.nan, loss)
+            return loss, {
+                "moe_overflow_rows": overflow.astype(jnp.float32),
+                "moe_live_rows": jnp.max(jnp.stack(
+                    of("live_rows"))).astype(jnp.float32)}
+
+        if vocab_chunk_size and "head" in state.params:
+            raise ValueError(
+                "vocab_chunk_size > 0 reads the head from params['embed'], "
+                "the tied table; this model has a head table of its own "
+                "(tie_embeddings=False): a chunked loss over params['head'] "
+                "is missing, use vocab_chunk_size=0")
+        collections = ["aux_loss", "moe_stats"]
+
         if vocab_chunk_size:
             from dtdl_tpu.ops.cross_entropy import chunked_lm_loss
 
@@ -204,7 +241,7 @@ def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
                 h, muts = state.apply_fn({"params": params}, inputs,
                                          train=True, rngs=rngs,
                                          return_hidden=True,
-                                         mutable=["aux_loss"])
+                                         mutable=collections)
                 b, s, d = h.shape
                 emb = params["embed"]
                 if hasattr(emb, "unbox"):   # flax logical-partitioning box
@@ -218,12 +255,13 @@ def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
                 term, aux = aux_term(muts)
                 if term is not None:
                     loss = loss + term
-                return loss, (correct * scale, aux)
+                loss, held = held_experts(loss, muts)
+                return loss, (correct * scale, aux, held)
         else:
             def compute_loss(params):
                 logits, muts = state.apply_fn({"params": params}, inputs,
                                               train=True, rngs=rngs,
-                                              mutable=["aux_loss"])
+                                              mutable=collections)
                 with jax.named_scope("loss"):
                     logits = logits.astype(jnp.float32)
                     lse = jax.nn.logsumexp(logits, axis=-1)
@@ -236,7 +274,8 @@ def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
                 term, aux = aux_term(muts)
                 if term is not None:
                     loss = loss + term
-                return loss, (correct, aux)
+                loss, held = held_experts(loss, muts)
+                return loss, (correct, aux, held)
 
         # what a remat=True model may keep of its forward pass is planned
         # against what this step holds beside it (models/remat_plan.py)
@@ -248,7 +287,7 @@ def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
         limit = (remat_plan.device_bytes_limit()
                  if strategy.traces_one_device else None)
         with remat_plan.step_memory("lm_train_step", held, limit):
-            (loss, (acc, aux)), grads = jax.value_and_grad(
+            (loss, (acc, aux, held)), grads = jax.value_and_grad(
                 compute_loss, has_aux=True)(strategy.localize(state.params))
         with jax.named_scope("grad_sync"):
             grads = strategy.grad_sync(grads)
@@ -257,6 +296,8 @@ def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
         metrics = {"loss": loss, "accuracy": acc}
         if aux is not None:
             metrics["moe_aux_loss"] = aux
+        if held is not None:
+            metrics.update(held)
         metrics = strategy.metric_sync(metrics)
         if guard is not None:
             with jax.named_scope("guard"):

@@ -28,9 +28,9 @@ from jax.extend import core as jex_core
 from dtdl_tpu.models import remat_plan
 from dtdl_tpu.models.transformer import TransformerLM
 from dtdl_tpu.obs import Observer
-from dtdl_tpu.obs.trace import (_ATTN_PROJECTIONS, DEVICE_SCOPES,
-                                KERNEL_NAMES, MODULE_SCOPES, STEP_NAMES,
-                                device_component)
+from dtdl_tpu.obs.trace import (_ATTN_OTHER, _ATTN_PROJECTIONS, _GDN_MODULES,
+                                _MOE_MODULES, DEVICE_SCOPES, KERNEL_NAMES,
+                                MODULE_SCOPES, STEP_NAMES, device_component)
 from dtdl_tpu.parallel import DataParallel, SingleDevice
 from dtdl_tpu.runtime import compile_cache
 from dtdl_tpu.runtime.mesh import DATA_AXIS
@@ -207,6 +207,8 @@ def test_jitted_steps_carry_their_own_names(make, name, parallel,
 # ---------------------------------------------------------------------------
 
 _BWD = "jit(lm_train_step)/transpose(jvp(TransformerLM))/"
+_FWD = "jit(lm_train_step)/jvp(TransformerLM)/"
+_REMAT = _BWD + "jvp(TransformerLM)/checkpoint/rematted_computation/"
 
 STACKS = [
     ("jit(lm_train_step)/jvp(TransformerLM)/embed/jit(_take)/gather",
@@ -245,7 +247,29 @@ STACKS = [
     ("jit(decode)/block_0/attn/paged_attn/pallas_call", "paged_attn",
      "forward"),
     ("jit(lm_train_step)/jvp(TransformerLM)/block_1/moe/router/dot_general",
-     "moe", "forward"),
+     "moe_router", "forward"),
+    ("jit(lm_train_step)/jvp(TransformerLM)/block_1/moe/ebsd,edf->ebsf/"
+     "dot_general", "moe", "forward"),
+    # the hybrid blocks (PR 29): flax's method scopes are passed over
+    (_FWD + "block_0/block_0._hybrid/gdn/gdn/while/body/dot_general", "gdn",
+     "forward"),
+    (_REMAT + "block_0/block_0._hybrid/gdn/in_qkvz/dot_general", "gdn_proj",
+     "recompute"),
+    (_BWD + "block_2/block_2._hybrid/gdn/conv/mul", "gdn_conv", "backward"),
+    (_FWD + "block_2/block_2._hybrid/gdn/norm/mul", "gdn_other", "forward"),
+    (_FWD + "block_3/block_3._hybrid/attn/attn._grouped_attend/q/"
+     "dot_general", "attn_proj", "forward"),
+    (_FWD + "block_3/block_3._hybrid/attn/attn._grouped_attend/gate/mul",
+     "attn_gate", "forward"),
+    (_BWD + "block_3/block_3._hybrid/attn/attn._grouped_attend/flash_bwd_dq/"
+     "pallas_call", "flash", "backward"),
+    (_BWD + "block_1/block_1._hybrid/moe/experts/moe_tgmm/pallas_call",
+     "moe_gmm", "backward"),
+    (_REMAT + "block_1/block_1._hybrid/moe/moe_dispatch/gather",
+     "moe_dispatch", "recompute"),
+    (_FWD + "block_1/block_1._hybrid/moe/shared/wi/dot_general",
+     "moe_shared", "forward"),
+    (_FWD + "block_1/block_1._hybrid/moe/top_k", "moe", "forward"),
     # the step's own scalar bookkeeping and the rope table carry no scope
     ("jit(lm_train_step)/div", None, "forward"),
     ("jit(lm_train_step)/jvp(TransformerLM)/cos", None, "forward"),
@@ -260,7 +284,9 @@ def test_device_component_maps_recorded_name_stacks(stack, component, phase):
     assert phase in ("forward", "recompute", "backward", "update")
     assert component is None or component in (
         set(DEVICE_SCOPES) | set(MODULE_SCOPES.values())
-        | set(KERNEL_NAMES.values()) | {"attn_proj"})
+        | set(KERNEL_NAMES.values()) | {"attn_proj"}
+        | set(_GDN_MODULES.values()) | set(_MOE_MODULES.values())
+        | set(_ATTN_OTHER.values()))
 
 
 def test_module_scopes_are_the_models_own_modules():
@@ -280,6 +306,72 @@ def test_module_scopes_are_the_models_own_modules():
     assert modules - {"embed"} == set(MODULE_SCOPES)
     assert {name for block in blocks
             for name in block["attn"]} == set(_ATTN_PROJECTIONS)
+
+
+def _hybrid_lm(**over):
+    kw = dict(vocab_size=64, d_model=16, n_layers=2, n_heads=2, d_ff=32,
+              max_seq=128, layer_kinds=("linear", "full"), n_kv_heads=1,
+              attn_head_dim=16, rope_dims=4, qk_norm=True, attn_gate=True,
+              norm_zero_centered=True, gdn_key_heads=1, gdn_value_heads=2,
+              gdn_key_dim=8, gdn_value_dim=8, n_experts=2, moe_every=1,
+              moe_dispatch="held", moe_router_width=8, moe_first_expert=2,
+              moe_top_k=2, moe_d_ff=8, moe_shared_d_ff=8,
+              tie_embeddings=False)
+    return TransformerLM(**dict(kw, **over))
+
+
+def test_hybrid_module_scopes_are_the_models_own_modules():
+    """The same audit for the hybrid blocks: a Gated DeltaNet layer's and
+    the held experts' sub-modules are the keys of their maps, and the gated
+    attention adds the two head norms."""
+    params = jax.eval_shape(_hybrid_lm().init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+    linear, full = params["block_0"], params["block_1"]
+    assert set(linear) == {"ln_attn", "gdn", "ln_mlp", "moe"}
+    assert set(full) == {"ln_attn", "attn", "ln_mlp", "moe"}
+    assert {n for n in linear["gdn"] if n not in ("A_log", "dt_bias")} \
+        == set(_GDN_MODULES)
+    assert set(linear["moe"]) == set(_MOE_MODULES)
+    assert set(full["attn"]) == set(_ATTN_PROJECTIONS) | (
+        set(_ATTN_OTHER) - {"gate"})
+    assert {"head", "embed", "ln_f"} == {k for k in params
+                                         if not k.startswith("block_")}
+    assert "head" in DEVICE_SCOPES and "gate" in DEVICE_SCOPES
+
+
+def test_lowered_hybrid_step_leaves_no_matmul_or_kernel_unscoped():
+    """In the lowered train step of a hybrid model every ``dot_general``
+    and every op of a grouped-matmul kernel lies under a component, and
+    each new component shows the passes it should (rung 0 on the CPU:
+    forward, recompute and backward)."""
+    model = _hybrid_lm(attn_impl="flash", remat=True, dtype=jnp.bfloat16)
+    tokens = jnp.zeros((2, 72), jnp.int32)      # two chunks of the rule
+    params = model.init(jax.random.PRNGKey(0), tokens[:, :-1])["params"]
+    state = TrainState.create(apply_fn=model.apply, params=params,
+                              tx=optax.adamw(3e-4))
+    lowered = make_lm_train_step(SingleDevice()).lower(state,
+                                                       {"tokens": tokens})
+    seen = {}
+    for op, stack, from_optax in _op_stacks(lowered):
+        if stack.startswith("closed_call:"):
+            # jax lowers the delta rule's forward scan through a private
+            # function whose call site carries this in place of a name
+            # stack; XLA's inliner gives its ops the caller's op_name (the
+            # chip's trace attributes them: PERF.md section 5)
+            continue
+        component, phase = device_component(stack)
+        kernel = [k for k in stack.split("/") if k in KERNEL_NAMES]
+        if op == "stablehlo.dot_general" or kernel:
+            assert component is not None, (op, stack)
+        if kernel:
+            assert component == KERNEL_NAMES[kernel[0]], (op, stack)
+        if component:
+            seen.setdefault(component, set()).add(phase)
+    every = {"forward", "recompute", "backward"}
+    for component in ("gdn", "gdn_proj", "gdn_conv", "moe_gmm",
+                      "moe_dispatch", "moe_router", "moe_shared",
+                      "attn_gate", "attn_proj", "flash"):
+        assert seen[component] >= every, (component, seen.get(component))
 
 
 # ---------------------------------------------------------------------------
@@ -329,7 +421,8 @@ def test_paged_pallas_call_carries_its_name():
         jnp.zeros((b, h, 1, d), jnp.float32))
     assert _pallas_names(jaxpr.jaxpr) == ["paged_attn"]
     assert set(KERNEL_NAMES) == {"flash_fwd", "flash_bwd_dq",
-                                 "flash_bwd_dkv", "paged_attn"}
+                                 "flash_bwd_dkv", "paged_attn",
+                                 "moe_gmm", "moe_tgmm"}
 
 
 # ---------------------------------------------------------------------------
@@ -376,9 +469,9 @@ def test_compile_account_rows_totals_and_single_registration():
     assert len(compile_cache.compile_account()) == mid
 
     whole = compile_cache.compile_totals()
-    # (and the newest checkpoint plan, where a step of this process made
-    # one: tests/test_remat_plan.py)
-    assert ({k for k in whole if not k.startswith("remat_")}
+    # (and the newest checkpoint plan and experts' buffer, where a step of
+    # this process made one: tests/test_remat_plan.py, test_qwen3_next.py)
+    assert ({k for k in whole if not k.startswith(("remat_", "moe_"))}
             == set(compile_cache.ACCOUNT_EVENTS.values()))
     assert whole["compile_trace_s"] > 0 and whole["compile_backend_s"] > 0
     summary = Observer().summary()
@@ -405,6 +498,7 @@ def test_compile_totals_count_a_nested_trace_and_a_retrieval_once(
     assert compile_cache.covered_s([]) == 0.0
     monkeypatch.setattr(compile_cache, "_ROWS", [])
     monkeypatch.setattr(compile_cache, "_PLANS", [])
+    monkeypatch.setattr(compile_cache, "_EXPERT_BUFFERS", [])
     assert compile_cache.compile_totals() == {}
     monkeypatch.setattr(compile_cache, "_ROWS", rows)
     assert compile_cache.compile_totals() == {

@@ -579,7 +579,10 @@ def test_failover_e2e_exported_series_holds_invariant(engine, oracle,
                 exporter=exp,
                 slos=default_fleet_slos(ttft_p99_s=60.0,
                                         availability=0.5),
-                **kw(recover_after=50)) as router:
+                # the stall watchdog is not what this test is about, and at
+                # kw()'s 0.25 s a busy machine starves the healthy replica
+                # past it, which evicts the one replica the requests need
+                **kw(recover_after=50, watchdog_s=5.0)) as router:
         reqs = router.run([Request(list(p), N_NEW) for p in prompts])
     s = router.summary()
     for r, toks in zip(reqs, want):
