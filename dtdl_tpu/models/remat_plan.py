@@ -18,8 +18,9 @@ name            value                                        placed in
                 them (unrotated)
 ``attn_out``    the ``out`` projection's output              Attention
 ``mlp_up``      the ``wi`` and ``wg`` outputs                SwiGLU
-``gdn_t``       the delta rule's ``T``, 64 x 64 a chunk a     ops/gated_delta.py
-                value head (a linear-attention block)
+``gdn_loop``    what the delta rule's loop reads of a chunk  ops/gated_delta.py
+                (a linear-attention block)
+``gdn_in``      the ``in_qkvz`` projection's output          GatedDeltaNet
 ==============  ===========================================  =================
 
 **The ladder.**  :data:`RUNGS` orders them by the recomputation a kept byte
@@ -32,10 +33,12 @@ elementwise and fuses into ``wo``'s backward).  :func:`ladder` fills rung 1
 on every block, then rung 2, then rung 3, block 0 first, and stops at the
 first residual that does not fit the budget: so the first *k* blocks may
 stand one rung above the rest.  MoE blocks carry the attention names only.
-A linear-attention block (Gated DeltaNet) has one rung of its own, ``gdn_t``
-(the ten small matmuls that invert ``I + A`` go; measured 4.4 ms a layer a
-step on a v5e, PERF.md section 6, PR 29), filled in the ladder's first pass
-beside the other blocks' ``flash_out``.
+A linear-attention block (Gated DeltaNet) has two rungs of its own:
+``gdn_loop`` (the rule's chunk-local stage is not run again: the
+``gdn_chunk_fwd`` kernel, or the batched matmuls of the ``jax.numpy`` path),
+filled in the ladder's first pass beside the other blocks' ``flash_out``,
+then ``gdn_in`` (the ``in_qkvz`` projection goes).  The rule's ``T`` has no
+name any more: the kernels never write it to HBM (PR 30).
 
 **The budget** is computed, never set: the device's
 ``memory_stats()["bytes_limit"]`` (:func:`device_bytes_limit`) less
@@ -61,7 +64,7 @@ from typing import NamedTuple, Sequence
 import jax
 
 from dtdl_tpu.ops.attention import FLASH_OUT, FLASH_QKV
-from dtdl_tpu.ops.gated_delta import GDN_LOOP, GDN_T
+from dtdl_tpu.ops.gated_delta import GDN_LOOP, stage_plan
 from dtdl_tpu.ops.grouped_matmul import held_buffer_rows
 
 ATTN_OUT = "attn_out"
@@ -83,16 +86,16 @@ MARGIN = 1 / 16
 
 
 # a linear-attention block's own ladder, by the recomputation a kept byte
-# removes (measured on a v5e, PERF.md section 6, PR 29: 66, 14 and 11 ms/GB)
-_LINEAR_RUNG_NAMES = ((), (GDN_T,), (GDN_LOOP,), (GDN_IN,))
+# removes (measured on a v5e, PERF.md section 6, PR 29: 14 and 11 ms/GB)
+_LINEAR_RUNG_NAMES = ((), (GDN_LOOP,), (GDN_IN,))
 
 
 def saved_names(rung: int, linear: bool = False,
                 held: bool = False) -> tuple[str, ...]:
     """The checkpoint names a block at ``rung`` keeps.  A linear-attention
-    block has rungs of its own: the delta rule's ``T`` (ten small matmuls a
-    chunk go), what its loop reads (the chunk-local part goes), the
-    ``in_qkvz`` output (the projection goes).  A block with held experts
+    block has two rungs of its own: what the delta rule's loop reads (the
+    chunk-local stage goes), the ``in_qkvz`` output (the projection goes);
+    it has no third.  A block with held experts
     always keeps ``moe_plan``: the top-k and the sort of the assignments are
     a megabyte to keep and milliseconds to run again."""
     names = _LINEAR_RUNG_NAMES if linear else _RUNG_NAMES
@@ -110,17 +113,19 @@ def policy(rung: int, linear: bool = False, held: bool = False):
 
 
 def gdn_residual_bytes(batch: int, seq: int, d_model: int, gdn,
-                       itemsize: int, chunk: int = 64) -> tuple[int, int, int]:
+                       itemsize: int) -> tuple[int, int, int]:
     """Bytes a linear-attention block (``gdn``: models/transformer.py's
-    ``GdnSpec``) keeps at its rungs 1, 2 and 3, each beyond the rung below:
-    ``T`` in float32; the loop's five inputs in the compute dtype; the
-    ``in_qkvz`` output."""
+    ``GdnSpec``) keeps at its rungs 1 and 2, each beyond the rung below:
+    the loop's five inputs in the compute dtype, at the chunk the rule
+    takes for these head sizes; the ``in_qkvz`` output.  It has no rung 3
+    (0: :func:`ladder` passes it over)."""
     hk, hv, dk, dv = (gdn.key_heads, gdn.value_heads, gdn.key_dim,
                       gdn.value_dim)
+    _, chunk = stage_plan(dk, dv)
     padded = -(-seq // chunk) * chunk * batch
-    return (padded * hv * chunk * 4,
-            padded * hv * (3 * dk + dv + chunk) * itemsize,
-            batch * seq * (2 * hk * dk + 2 * hv * dv) * itemsize)
+    return (padded * hv * (3 * dk + dv + chunk) * itemsize,
+            batch * seq * (2 * hk * dk + 2 * hv * dv) * itemsize,
+            0)
 
 
 def residual_bytes(batch: int, seq: int, d_model: int, n_heads: int,
@@ -207,8 +212,7 @@ def model_held_bytes(batch: int, seq: int, d_model: int, d_ff: int,
 
 def hybrid_block_live_bytes(batch: int, seq: int, d_model: int,
                             itemsize: int, attn_width: int = 0,
-                            gdn=None, held=None, d_ff: int = 0,
-                            chunk: int = 64) -> int:
+                            gdn=None, held=None, d_ff: int = 0) -> int:
     """One hybrid block's live set (models/transformer.py:BlockSpec) while
     it is recomputed and differentiated.
 
@@ -218,10 +222,11 @@ def hybrid_block_live_bytes(batch: int, seq: int, d_model: int,
     ``GdnSpec``): the ``in_qkvz`` projection, the conv's input and output,
     and the delta rule's values as
     ops/gated_delta.py holds them (q and k in float32 at the key heads; v
-    and the loop's five inputs in the compute dtype; the decay, ``A`` and
-    ``T`` in float32, ``chunk x chunk`` a chunk a value head; ``o`` in
-    float32; the state at every chunk), and half as much again for the
-    cotangents that are live at once.  Held experts (``held``, a
+    and the loop's five inputs in the compute dtype; ``o`` in float32; the
+    state at every chunk; and only where the shapes fall to the
+    ``jax.numpy`` stage the decay, ``A`` and ``T`` in float32, ``chunk x
+    chunk`` a chunk a value head: the kernels hold those in VMEM), and half
+    as much again for the cotangents that are live at once.  Held experts (``held``, a
     ``HeldSpec``): the ``R``-row buffers (rows, gate, up, their product,
     the output), each with its cotangent, and the three weights' float32
     gradients, which a grouped matmul writes whole.  A dense MLP: six
@@ -235,13 +240,15 @@ def hybrid_block_live_bytes(batch: int, seq: int, d_model: int,
         hk, hv, dk, dv = (gdn.key_heads, gdn.value_heads, gdn.key_dim,
                           gdn.value_dim)
         conv = 2 * hk * dk + hv * dv
+        path, chunk = stage_plan(dk, dv)
         states = -(-seq // chunk) * batch * hv * dk * dv * 4
         values = (t * (conv + hv * dv) * itemsize       # in_qkvz
                   + 2 * t * conv * itemsize             # the conv, in and out
                   + 2 * t * hk * dk * 4                 # q, k
                   + t * hv * (dv + 3 * dk + dv + chunk) * itemsize
-                  + 3 * t * hv * chunk * 4              # decay, A, T
                   + t * hv * dv * 4 + states)           # o, the states
+        if path == "jnp":
+            values += 3 * t * hv * chunk * 4            # decay, A, T
         live += values + values // 2
     if held:
         rows, _ = held_buffer_rows(t, held.top_k, held.held,

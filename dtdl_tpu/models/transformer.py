@@ -39,7 +39,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from dtdl_tpu.models import remat_plan
 from dtdl_tpu.ops.attention import flash_attention, mha_reference
-from dtdl_tpu.ops.gated_delta import gated_delta_rule
+from dtdl_tpu.ops.gated_delta import gated_delta_rule, stage_plan
 from dtdl_tpu.ops.grouped_matmul import (
     ROW_TILE, grouped_matmul, held_buffer_rows, rows_of, weighted_rows_sum)
 from dtdl_tpu.ops.paged_attention import paged_attention
@@ -47,6 +47,7 @@ from dtdl_tpu.ops.rope import apply_rope, rope_frequencies
 from dtdl_tpu.quant import (QuantDenseGeneral, canon_kv_dtype, kv_quantize,
                             kv_scale_dtype, weight_dtypes)
 from dtdl_tpu.runtime.compile_cache import (record_expert_buffer,
+                                            record_gdn_path,
                                             record_remat_plan)
 
 Dtype = Any
@@ -1037,6 +1038,10 @@ class GatedDeltaNet(nn.Module):
         beta = jax.nn.sigmoid(ba[..., :r].reshape(b, s, hv))
         g = -jnp.exp(a_log) * jax.nn.softplus(
             ba[..., r:].reshape(b, s, hv) + dt_bias)
+        step_name = remat_plan.traced_step_name()
+        if step_name is not None:
+            record_gdn_path(step_name, *stage_plan(dk, dv),
+                            shapes=(b, s, hk, hv, dk, dv))
         o = gated_delta_rule(                            # [B, S, Hv, Dv] f32
             q, k, v, g, beta,
             operand_dtype=None if self.dtype == jnp.float32 else self.dtype)
@@ -1547,8 +1552,9 @@ class TransformerLM(nn.Module):
                 batch, seq, self.d_model, self.d_ff, self.n_layers, vocab,
                 param_bytes, itemsize)
         else:
-            # each block's own bytes: a linear block may keep the delta
-            # rule's T, a full one the attention names at its own head width
+            # each block's own bytes: a linear block may keep what the delta
+            # rule's loop reads, a full one the attention names at its own
+            # head width
             attn_width = self.n_heads * self.head_dim
             costs = [remat_plan.residual_bytes(
                 batch, seq, self.d_model, self.n_heads, 0, itemsize,

@@ -170,8 +170,8 @@ EVENT_CATALOG = frozenset({
 # submodule); the rest in train/step.py.  With ``vocab_chunk_size > 0`` the
 # head matmul runs inside the chunked loss, tile by tile, and so under
 # ``loss``.
-# ``gdn`` wraps the gated delta rule itself (ops/gated_delta.py: the batched
-# chunk-local matmuls and the scan over chunks), ``moe_dispatch`` the held
+# ``gdn`` wraps the gated delta rule itself (ops/gated_delta.py: the
+# chunk-local stage, kernels or batched matmuls, and the scan over chunks), ``moe_dispatch`` the held
 # experts' routing plan, gather and weighted scatter-add
 # (models/transformer.py:HeldExperts), ``gate`` the attention's sigmoid
 # output gate (under ``attn``: the component ``attn_gate``).
@@ -202,6 +202,7 @@ KERNEL_NAMES = {
     "flash_fwd": "flash", "flash_bwd_dq": "flash", "flash_bwd_dkv": "flash",
     "paged_attn": "paged_attn",
     "moe_gmm": "moe_gmm", "moe_tgmm": "moe_gmm",
+    "gdn_chunk_fwd": "gdn", "gdn_chunk_bwd": "gdn",
 }
 
 # names of the traced step functions of train/step.py: ``jit(<name>)`` in

@@ -34,6 +34,10 @@ newest as ``remat_blocks_by_rung``, ``remat_kept_bytes``,
 (``models/transformer.py:HeldExperts``) adds the shape of its row buffer
 the same way: :func:`record_expert_buffer`, :func:`expert_buffers`, and
 ``moe_buffer_rows``, ``moe_row_tile``, ``moe_expected_rows`` in the totals.
+A linear-attention layer (``models/transformer.py:GatedDeltaNet``) adds which
+implementation of the delta rule's chunk-local stage its shapes chose
+(``ops/gated_delta.py:stage_plan``): :func:`record_gdn_path`,
+:func:`gdn_paths`, and ``gdn_kernel_calls``, ``gdn_jnp_calls`` in the totals.
 """
 
 from __future__ import annotations
@@ -72,6 +76,7 @@ class CompileRow(NamedTuple):
 _ROWS: list[CompileRow] = []
 _PLANS: list = []       # models.remat_plan.RematPlan, one a traced step
 _EXPERT_BUFFERS: list = []      # one a held-experts layer a traced step
+_GDN_PATHS: list = []           # one a call of the delta rule a traced step
 _listening = False
 
 
@@ -113,6 +118,21 @@ def record_expert_buffer(fun_name: str, rows: int, row_tile: int,
     _EXPERT_BUFFERS.append({"fun_name": fun_name, "rows": rows,
                             "row_tile": row_tile,
                             "expected_rows": expected_rows})
+
+
+def record_gdn_path(fun_name: str, path: str, chunk: int,
+                    shapes: tuple) -> None:
+    """Keep which implementation of the gated delta rule's chunk-local
+    stage a train step was just traced with (ops/gated_delta.py): ``path``
+    is ``"kernel"`` or ``"jnp"``, ``shapes`` the call's ``(rows, positions,
+    key heads, value heads, key dim, value dim)``, ``chunk`` its length."""
+    _GDN_PATHS.append({"fun_name": fun_name, "path": path,
+                       "shapes": tuple(shapes), "chunk": chunk})
+
+
+def gdn_paths() -> list:
+    """The delta rule's calls so far, oldest first (a copy)."""
+    return list(_GDN_PATHS)
 
 
 def expert_buffers() -> list:
@@ -167,4 +187,7 @@ def compile_totals() -> dict:
         totals["moe_buffer_rows"] = newest["rows"]
         totals["moe_row_tile"] = newest["row_tile"]
         totals["moe_expected_rows"] = newest["expected_rows"]
+    for path in sorted({r["path"] for r in _GDN_PATHS}):
+        totals[f"gdn_{path}_calls"] = sum(
+            r["path"] == path for r in _GDN_PATHS)
     return totals

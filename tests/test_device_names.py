@@ -339,12 +339,15 @@ def test_hybrid_module_scopes_are_the_models_own_modules():
     assert "head" in DEVICE_SCOPES and "gate" in DEVICE_SCOPES
 
 
-def test_lowered_hybrid_step_leaves_no_matmul_or_kernel_unscoped():
+@pytest.mark.parametrize("gdn_dim", [8, 128], ids=["jnp", "gdn_kernels"])
+def test_lowered_hybrid_step_leaves_no_matmul_or_kernel_unscoped(gdn_dim):
     """In the lowered train step of a hybrid model every ``dot_general``
-    and every op of a grouped-matmul kernel lies under a component, and
+    and every op of a grouped-matmul kernel, and at head sizes of 128 of
+    the delta rule's kernels, lies under a component, and
     each new component shows the passes it should (rung 0 on the CPU:
     forward, recompute and backward)."""
-    model = _hybrid_lm(attn_impl="flash", remat=True, dtype=jnp.bfloat16)
+    model = _hybrid_lm(attn_impl="flash", remat=True, dtype=jnp.bfloat16,
+                       gdn_key_dim=gdn_dim, gdn_value_dim=gdn_dim)
     tokens = jnp.zeros((2, 72), jnp.int32)      # two chunks of the rule
     params = model.init(jax.random.PRNGKey(0), tokens[:, :-1])["params"]
     state = TrainState.create(apply_fn=model.apply, params=params,
@@ -422,7 +425,66 @@ def test_paged_pallas_call_carries_its_name():
     assert _pallas_names(jaxpr.jaxpr) == ["paged_attn"]
     assert set(KERNEL_NAMES) == {"flash_fwd", "flash_bwd_dq",
                                  "flash_bwd_dkv", "paged_attn",
-                                 "moe_gmm", "moe_tgmm"}
+                                 "moe_gmm", "moe_tgmm",
+                                 "gdn_chunk_fwd", "gdn_chunk_bwd"}
+
+
+def test_gdn_pallas_calls_carry_their_names():
+    from dtdl_tpu.ops.gated_delta import gated_delta_rule
+
+    q = jnp.zeros((1, 70, 1, 128), jnp.float32)
+    v = jnp.zeros((1, 70, 2, 128), jnp.float32)
+    g = jnp.zeros((1, 70, 2), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: gated_delta_rule(*a).sum(), argnums=(0, 1, 2, 3, 4)))(
+        q, q, v, g, g)
+    assert _pallas_names(jaxpr.jaxpr) == ["gdn_chunk_fwd", "gdn_chunk_bwd"]
+    assert KERNEL_NAMES["gdn_chunk_fwd"] == KERNEL_NAMES["gdn_chunk_bwd"] \
+        == "gdn"
+
+
+def _mosaic_calls(model, row_tokens, monkeypatch):
+    """``{kernel name: calls}`` of the ``tpu_custom_call`` instructions in
+    a model's train step lowered for a TPU (no chip, nothing compiled:
+    jax lowers for a platform that is not attached)."""
+    import collections
+    import re
+
+    import dtdl_tpu.ops.attention as attention
+    monkeypatch.setattr(attention, "_use_interpret", lambda: False)
+    tokens = jax.ShapeDtypeStruct((2, row_tokens), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((2, row_tokens - 1), jnp.int32))
+    state = jax.eval_shape(
+        lambda p: TrainState.create(apply_fn=model.apply, params=p,
+                                    tx=optax.adamw(3e-4)), params["params"])
+    text = make_lm_train_step(SingleDevice()).trace(
+        state, {"tokens": tokens}).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert text.count("@tpu_custom_call") == len(
+        re.findall(r'kernel_name = "\w+"', text))
+    return collections.Counter(re.findall(r'kernel_name = "(\w+)"', text))
+
+
+def test_the_lowered_tpu_step_holds_gdn_kernels_at_kernel_sized_heads_alone(
+        monkeypatch):
+    """The Qwen3-Next-shaped step at head sizes of 128 holds the rule's
+    Mosaic calls under their names (not once a layer a pass: the calls are
+    jitted on their own, and a lowering is shared); at head sizes of 8 the
+    same model holds none, nor does the dense one."""
+    common = dict(attn_impl="flash", remat=True, dtype=jnp.bfloat16)
+    taken = _mosaic_calls(_hybrid_lm(gdn_key_dim=128, gdn_value_dim=128,
+                                     **common), 73, monkeypatch)
+    assert taken["gdn_chunk_fwd"] >= 1 and taken["gdn_chunk_bwd"] >= 1
+    assert KERNEL_NAMES.keys() >= taken.keys() >= {
+        "flash_fwd", "moe_gmm", "moe_tgmm"}
+    small = _mosaic_calls(_hybrid_lm(**common), 73, monkeypatch)
+    dense = _mosaic_calls(
+        TransformerLM(vocab_size=64, d_model=16, n_layers=1, n_heads=2,
+                      d_ff=32, max_seq=128, **common), 73, monkeypatch)
+    for other in (small, dense):
+        assert other["flash_fwd"] >= 1
+        assert not [name for name in other if name.startswith("gdn_")]
 
 
 # ---------------------------------------------------------------------------
@@ -469,9 +531,9 @@ def test_compile_account_rows_totals_and_single_registration():
     assert len(compile_cache.compile_account()) == mid
 
     whole = compile_cache.compile_totals()
-    # (and the newest checkpoint plan and experts' buffer, where a step of
+    # (and the newest checkpoint plan, experts' buffer and delta-rule paths, where a step of
     # this process made one: tests/test_remat_plan.py, test_qwen3_next.py)
-    assert ({k for k in whole if not k.startswith(("remat_", "moe_"))}
+    assert ({k for k in whole if not k.startswith(("remat_", "moe_", "gdn_"))}
             == set(compile_cache.ACCOUNT_EVENTS.values()))
     assert whole["compile_trace_s"] > 0 and whole["compile_backend_s"] > 0
     summary = Observer().summary()
@@ -499,6 +561,7 @@ def test_compile_totals_count_a_nested_trace_and_a_retrieval_once(
     monkeypatch.setattr(compile_cache, "_ROWS", [])
     monkeypatch.setattr(compile_cache, "_PLANS", [])
     monkeypatch.setattr(compile_cache, "_EXPERT_BUFFERS", [])
+    monkeypatch.setattr(compile_cache, "_GDN_PATHS", [])
     assert compile_cache.compile_totals() == {}
     monkeypatch.setattr(compile_cache, "_ROWS", rows)
     assert compile_cache.compile_totals() == {

@@ -464,7 +464,10 @@ def test_hedged_failover_single_correlated_timeline(engine, oracle):
     obs = Observer(trace=True)
     with Router(engine, n_replicas=2, plan=plan, auto_restart=False,
                 observer=obs, hedge_after_s=0.0,
-                **kw(recover_after=50)) as router:
+                # as in the exported-series scenario below: at kw()'s 0.25 s
+                # a busy machine starves the healthy replica past the stall
+                # watchdog, which evicts the one replica the hedges need
+                **kw(recover_after=50, watchdog_s=5.0)) as router:
         reqs = router.run([Request(list(p), N_NEW) for p in prompts])
         s = router.summary()
     for r, toks in zip(reqs, want):

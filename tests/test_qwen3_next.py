@@ -377,9 +377,10 @@ def test_goodput_refuses_the_dense_formula_for_a_hybrid_model(count):
 
 def test_the_plan_reckons_each_blocks_own_bytes(monkeypatch):
     """With a limit the one full-attention block may keep the attention
-    names at its own head width, a linear block the delta rule's ``T``, what
-    its loop reads and the ``in_qkvz`` output; the estimate grows
-    with the projection widths and the experts' buffer, not with ``d_ff``."""
+    names at its own head width, a linear block what the delta rule's loop
+    reads and the ``in_qkvz`` output (the rule's ``T`` has no name since the
+    kernels hold it in VMEM); the estimate grows with the projection widths
+    and the experts' buffer, not with ``d_ff``."""
     from dtdl_tpu.runtime import compile_cache
     monkeypatch.setattr(remat_plan, "device_bytes_limit", lambda: 10 ** 9)
     model, params, _, _ = _model_and_params(CFG, dtype=jnp.bfloat16)
@@ -387,20 +388,20 @@ def test_the_plan_reckons_each_blocks_own_bytes(monkeypatch):
     step.lower(_tiny_state(model, params),
                {"tokens": jnp.zeros((2, ROW), jnp.int32)})
     plan = compile_cache.remat_plans()[-1]
-    assert plan.rungs == (3, 3, 3, 2)       # everything a block can keep
+    assert plan.rungs == (2, 2, 2, 2)       # everything a block can keep
     t, width = 2 * (ROW - 1), 4 * 16
     linear = remat_plan.gdn_residual_bytes(2, ROW - 1, 32,
                                            GdnSpec(2, 4, 8, 8, 4), 2)
-    assert linear == (2 * 128 * 4 * 64 * 4,            # T: two chunks a row
-                      2 * 128 * 4 * (3 * 8 + 8 + 64) * 2,
-                      t * (2 * 2 * 8 + 2 * 4 * 8) * 2)
+    assert linear == (2 * 128 * 4 * (3 * 8 + 8 + 64) * 2,  # two chunks a row
+                      t * (2 * 2 * 8 + 2 * 4 * 8) * 2, 0)
     full = remat_plan.residual_bytes(2, ROW - 1, 32, 4, 0, 2,
                                      attn_width=width)
     assert full == (t * width * 2 + 2 * 4 * (ROW - 1) * 4,
                     (3 * width + 32) * t * 2, 0)
     assert plan.kept_bytes == 3 * sum(linear) + sum(full)
     assert remat_plan.saved_names(1, linear=True, held=True) == (
-        "gdn_t", "moe_plan")
+        "gdn_loop", "moe_plan")
+    assert remat_plan.saved_names(2, linear=True) == ("gdn_loop", "gdn_in")
     assert remat_plan.saved_names(0, held=True) == ("moe_plan",)
     assert remat_plan.policy(0) is None
     buffer = compile_cache.expert_buffers()[-1]
