@@ -48,7 +48,7 @@ MIN_DEVICES = {"train_step": 1, "megatron_step": 8, "serve_decode": 1,
 
 def runnable_programs(names=PROGRAMS) -> tuple[list, list]:
     """Split ``names`` into (runnable, skipped) for THIS process's
-    device count — bench.py / scripts/audit.py run outside the test
+    device count — scripts/audit.py runs outside the test
     harness's forced 8-device CPU platform, where the megatron
     geometry cannot build; skipping it loudly beats an error row."""
     n = jax.device_count()

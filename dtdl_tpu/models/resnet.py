@@ -51,7 +51,7 @@ class SpaceToDepthStem(nn.Module):
     The standard stem convolves a 3-channel 224x224 image with a 7x7 stride-2
     kernel — on the TPU that contraction (7*7*3 = 147) runs the MXU at ~4%
     utilisation and the f32 image is the single largest tensor the step reads
-    (measured: 7.1 ms of a 101 ms ResNet-50 step, see RESNET50_ROOFLINE.md).
+    (no cell times a CNN: its share of the step is not measured).
     Rewriting it over a 2x2 space-to-depth view of the image — input
     [N,224,224,3] -> [N,112,112,12], kernel [7,7,3,64] zero-padded to 8x8 and
     regrouped to [4,4,12,64], stride 1 — computes the *identical* function
@@ -94,8 +94,8 @@ class ResNet(nn.Module):
     renames the stem parameter path (``SpaceToDepthStem_0/kernel`` vs
     ``Conv_0/kernel``), so flipping it silently breaks restore of any
     snapshot taken with the other setting.  The default keeps the canonical
-    checkpoint tree interchangeable with reference-format weight ports; the
-    bench path enables it explicitly for the HBM win."""
+    checkpoint tree interchangeable with reference-format weight ports;
+    ``examples/imagenet_resnet50.py`` enables it by flag."""
     stage_sizes: Sequence[int] = (3, 4, 6, 3)
     num_classes: int = 1000
     dtype: Any = jnp.float32

@@ -1769,12 +1769,12 @@ def _compiled_generate(model, b, s0, max_new_tokens, temperature):
 
 
 def transformer_lm(size: str = "tiny", **overrides) -> TransformerLM:
-    """Named configs; 'tiny' fits the CPU test mesh, 'base' the bench chip.
+    """Named configs; 'tiny' fits the CPU test mesh, 'base' one v5e chip.
 
     'small' and 'base' use **head_dim 128** (the MXU lane width): the Pallas
     flash kernel tiles [block, head_dim] blocks, so head_dim 32 wastes 3/4
-    of every matmul lane — measured 2.6x slower end-to-end on a v5e at seq
-    4096 (397k vs 1,037k tokens/s for the identical FLOP count).  Fewer,
+    of every matmul lane for the identical FLOP count (what that costs end
+    to end is not in the ledger: no cell runs a head size under 128).  Fewer,
     wider heads is the TPU-first layout.
 
     These are the *v2* geometries (the canonical names 'small-hd128' /
@@ -1792,8 +1792,8 @@ def transformer_lm(size: str = "tiny", **overrides) -> TransformerLM:
                       d_ff=704, max_seq=1024),
         "base": dict(vocab_size=32000, d_model=512, n_layers=8, n_heads=4,
                      d_ff=1408, max_seq=2048),
-        # 'large' cashes LM_ROOFLINE.md §5's conclusion that further MFU
-        # comes from model shape: d_model 1024 doubles every matmul's
+        # 'large' is 'base' at twice the width (its speed is not in the
+        # ledger; chip_smoke.py trains it): d_model 1024 doubles every matmul's
         # contraction depth vs 'base' (same head_dim-128 MXU layout), and
         # ~239M params at seq 4096 need the standard long-seq memory
         # discipline — remat'd blocks plus the vocab-chunked loss
@@ -1803,8 +1803,8 @@ def transformer_lm(size: str = "tiny", **overrides) -> TransformerLM:
                       n_heads=8, d_ff=2816, max_seq=2048, remat=True),
     }
     # routed-MoE variant of 'base': 8 experts every other block, GShard
-    # capacity dispatch (the bench's MoE throughput row — measured 1.48x
-    # the dense-dispatch step at identical routing math)
+    # capacity dispatch (the same routing math as the dense dispatch;
+    # its speed against that one is not measured in any cell)
     cfgs["base-moe8"] = dict(cfgs["base"], n_experts=8, moe_every=2,
                              moe_dispatch="routed")
     cfgs["small-hd128"] = cfgs["small"]

@@ -1,21 +1,19 @@
 """Goodput / MFU accounting: analytic FLOPs in, roofline fractions out.
 
-LM_ROOFLINE.md / RESNET50_ROOFLINE.md derived MFU by hand once per
-round; this module is that math as a library, fed per drained window so
-every loop can report ``mfu`` / ``tokens_per_sec`` / achieved-vs-
-roofline continuously instead of in one-off docs.  Three pieces:
+The MFU arithmetic as a library, fed per drained window so every loop
+can report ``mfu`` / ``tokens_per_sec`` / achieved-vs-roofline
+continuously.  Three pieces:
 
 * **analytic model FLOPs** — :func:`lm_train_flops` (TransformerLM from
-  its config; moved here from bench.py, which re-exports it) and
+  its config) and
   :func:`netspec_flops` (Caffe-style CNNs from their parsed LayerSpecs).
   Analytic counts are the honest MFU numerator on TPU: XLA's
   ``cost_analysis()`` cannot see inside Pallas custom-calls and misses
-  the flash-attention FLOPs entirely (LM_ROOFLINE.md §1).  The
+  the flash-attention FLOPs entirely.  The
   convention is matmul-only model FLOPs — causal attention at the
   computed half, backward at 2x forward, recompute never credited, and
   elementwise work (rope — fused into the kernels since round 13 —
-  norms, activations) never counted (:func:`lm_rope_hbm_bytes` carries
-  the BYTE side of the rope-fusion story instead).
+  norms, activations) never counted.
 * **chip peaks** — :func:`peak_flops_per_chip` (public bf16 figures by
   exact device_kind; None on CPU, an unknown accelerator raises).
 * :class:`GoodputMeter` — turns (steps, seconds) windows into the
@@ -98,9 +96,9 @@ def lm_train_flops(cfg, batch: int, seq: int) -> float:
 
     The train step predicts ``seq - 1`` next tokens, so the forward is
     counted over seq-1 positions; backward at the standard 2x forward
-    (the kernel's recompute overhead is NOT credited).  This is the
-    number bench.py's ``mfu`` uses (see LM_ROOFLINE.md §1 for the
-    measured gap vs XLA's cost_analysis).
+    (the kernel's recompute overhead is NOT credited).  The benchmark's
+    ``step_mfu`` uses the same count (``benchmarks/lib/flops.py``, pinned
+    equal to this one by ``benchmarks/tests/test_arithmetic.py``).
     """
     return 3.0 * lm_forward_flops(cfg, batch, seq - 1)
 
@@ -143,27 +141,6 @@ def lm_verify_flops(cfg, batch: int, context: int, k: int) -> float:
     already counts real tokens, never drafts.
     """
     return (k + 1) * lm_decode_flops(cfg, batch, context)
-
-
-def lm_rope_hbm_bytes(cfg, batch: int, seq: int,
-                      dtype_bytes: int = 2) -> float:
-    """HBM bytes per train step an UNFUSED rope implementation
-    round-trips — the traffic the fused-rope attention kernels
-    (ops/attention.py, round 13) eliminate.
-
-    Per layer, a standalone ``apply_rope`` reads and writes both
-    [B, H, S, D] Q and K tensors once in the forward, and the backward
-    inverse-rotates dQ/dK the same way: 2 phases × 2 tensors × (read +
-    write) = 8 × B·H·S·D·bytes per layer.  Fused, the rotation runs on
-    tiles already in VMEM and only the [S, D]-shaped table rows move —
-    ~1/(2·B·H) of this, counted as zero here.  NOTE the analytic FLOP
-    numerator (:func:`lm_forward_flops`) is matmul-only by convention
-    and never counted rope's elementwise work, so fusing rope changes
-    measured step TIME, not the model-FLOP accounting — mfu rises
-    because the denominator seconds shrink, with no numerator edit.
-    """
-    qk = batch * cfg.n_heads * cfg.head_dim * seq * dtype_bytes
-    return cfg.n_layers * 8.0 * qk
 
 
 # ---------------------------------------------------------------------------
@@ -266,9 +243,9 @@ class GoodputMeter:
     only).  When ``flops_per_step`` covers a step sharded across several
     local devices, pass ``peak_flops=peak_flops_per_chip() * n_devices``
     explicitly — the auto single-chip default would inflate mfu by the
-    device count (bench.py avoids this by using XLA's per-device
-    partitioned FLOP count).  A
-    ``roofline_mfu`` target (e.g. the 0.46 measured in LM_ROOFLINE.md)
+    device count.  A
+    ``roofline_mfu`` target (the best ``step_mfu`` the ledger holds for
+    the configuration, as a fraction)
     adds ``vs_roofline`` — the achieved fraction of what this chip has
     *demonstrated*, which is the regression signal ``mfu`` alone (a
     fraction of an unreachable dense peak) is too noisy to give.

@@ -1,7 +1,7 @@
 """Observer: the one handle every loop takes for the obs subsystem.
 
 Call sites (train_epoch / Trainer / fit / Estimator / Solver /
-serve.Scheduler / bench.py) add ~3 lines each:
+serve.Scheduler) add ~3 lines each:
 
     obs = observer or NULL_OBSERVER            # default: all no-ops
     step = obs.watch(step, "train_step")       # recompile sentinel
@@ -16,8 +16,8 @@ by the sync-counting test in tests/test_obs.py).
 
 The default :data:`NULL_OBSERVER` short-circuits every method (shared
 nullcontext spans, identity watch, ``{}`` windows), so a loop wired for
-observability costs nothing when it is off — bench.py's observability
-row keeps the on-vs-off overhead receipt (<2% steps/sec).
+observability adds no sync when it is on and no work when it is off;
+what it costs in steps/sec is not measured on the chip.
 """
 
 from __future__ import annotations

@@ -3,7 +3,7 @@
 A recompile on TPU is a multi-second stall that the async dispatch
 pipeline hides until the drain — the loop just gets mysteriously slow.
 The tests already police this by hand (``_cache_size()`` asserts in
-tests/test_serve.py, the compile-count receipts in bench.py); this
+tests/test_serve.py, ``engine.compile_stats()``); this
 module is that pattern made a reusable runtime guard: wrap any jitted
 callable with :meth:`RecompileSentinel.watch` and every call compares
 the function's jit-cache size before/after.  Growth past the expected

@@ -159,7 +159,7 @@ class SLO:
         out[pre + "target"] = self.target
         # state transitions and crossing counters advance UNCONDITIONALLY
         # — an evaluator without an observer still keeps honest books
-        # (summary() is the bench/monitor rollup); the observer only
+        # (summary() is the monitor's rollup); the observer only
         # decides whether the crossing also lands on a trace
         prev_ok = self.ok
         self.ok = ok
@@ -243,7 +243,7 @@ class SLOEvaluator:
 
     def summary(self) -> dict:
         """Flat rollup: per-SLO last verdict + fleet-wide crossing
-        counts (the ``slo_*`` bench summary fields)."""
+        counts (the ``slo_*`` summary fields)."""
         out = {"slo_breach_events": sum(s.breaches for s in self.slos),
                "slo_burn_crossings": sum(s.burn_crossings
                                          for s in self.slos)}

@@ -34,9 +34,9 @@ Design (the standard TPU flash decomposition):
   double-buffers the next iteration's K/V tiles against the current tile's
   matmuls instead of stalling the MXU at the top of each k step.
 * block shapes come from a small **static autotune table** keyed on
-  (head_dim, seq bucket, causal) — see :data:`_BLOCK_TABLE` — derived from
-  the in-repo v5e block sweep (LM_ROOFLINE.md §2: 12%→25% kernel-efficiency
-  swings on block shape alone).  Explicit ``block_q``/``block_k`` args
+  (head_dim, seq bucket, causal) — see :data:`_BLOCK_TABLE` — taken from
+  a v5e block sweep whose record is gone (:func:`_build_block_table` says
+  what is known of it today).  Explicit ``block_q``/``block_k`` args
   still override (the tests' fixed geometries).
 
 On the CPU platform (the 8-virtual-device test mesh, SURVEY §4) the same
@@ -186,13 +186,13 @@ _SEQ_BUCKETS = (8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192,
 def _build_block_table():
     """(head_dim, seq_bucket, causal) -> (block_q, block_k).
 
-    Derived from the in-repo v5e sweep (LM_ROOFLINE.md §2, re-run round
-    13): at head_dim 128 / seq 4096 the 1024×1024 tile is the measured
-    knee (25.4% kernel efficiency vs 12-19% for smaller tiles; 2048-row
-    blocks fail to compile on VMEM), at head_dim 64 the same shape keeps
-    a smaller edge, and below ~4k the grid/DMA overhead of small tiles
-    dominates so a block spanning the whole sequence wins (the round-4
-    "128×128 loses to XLA dense below seq 4k" finding).  Every entry is
+    The table came from a v5e sweep at S = 4,096 on the kernels of PR 8;
+    that sweep's record is gone.  What stands today: the benchmark's
+    cells read ``flash_roofline`` 27.9-44.6 % with it (ledger, PR 30:
+    PERF.md §5), 1024×1024 above 512 positions and one block spanning
+    the whole sequence at or below it (2048-row blocks: never retried).
+    A retune is judged by the cells' ``flash_roofline`` and
+    ``flash.*_ms`` (ROADMAP.md S6), not by a sweep.  Every entry is
     EXPLICIT so the preset-config receipt test can pin that no model
     geometry silently falls back; per-geometry retunes edit this table,
     never call sites.
@@ -738,8 +738,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
     interpreter (tests).
 
     ``block_q``/``block_k`` default to the static autotune table
-    (:func:`resolve_blocks`, keyed on head_dim / seq bucket / causal —
-    LM_ROOFLINE.md §2's sweep; explicit args override).  VMEM per grid
+    (:func:`resolve_blocks`, keyed on head_dim / seq bucket / causal;
+    :func:`_build_block_table`; explicit args override).  VMEM per grid
     step ~= bq·bk·4 (score tile) + bq·d·4 (acc) + (bq+bk)·d·8 (rope
     tables): ~6.5 MB at 1024/1024/d=128 with rope.
 

@@ -10,8 +10,8 @@ round-6 attend gathers the ENTIRE logical view first::
 
 which materializes ``B * n_ptab * page_size`` K/V rows in scratch HBM
 every decode step even though a slot at position ``pos`` only occupies
-``ceil((pos+1)/page_size)`` pages — the measured ~15% paged-decode tax
-(bench.py --paged, PR 6 known-remaining).  This kernel walks the page
+``ceil((pos+1)/page_size)`` pages (PR 6's known-remaining; its cost is
+not measured on the chip).  This kernel walks the page
 table INSIDE the attention loop instead:
 
 * grid ``(B, H, n_ptab)`` with the page step innermost (sequential);
@@ -37,8 +37,8 @@ table INSIDE the attention loop instead:
 * online softmax in VMEM scratch (m, l, acc — same recurrence as
   ops/attention.py:_fwd_kernel) finalizes once per (b, h).
 
-Bytes argument (LM_ROOFLINE.md §9): per decode step the gather path
-moves ``2 * B * n_ptab * page * H * D`` payload bytes pool->scratch
+Bytes argument (SCALING.md "Kernel round 2"): per decode step the gather
+path moves ``2 * B * n_ptab * page * H * D`` payload bytes pool->scratch
 PLUS the same again scratch->compute; this kernel moves
 ``2 * B * ceil((pos+1)/page) * page * H * D`` pool->VMEM once.  For the
 production long-context shape (n_ptab >> live pages) that is the whole

@@ -57,7 +57,7 @@ VALID_KINDS = ("local", "device", "dist_sync", "dist_device_sync", "dist_async")
 # must keep working while the data-plane world is broken (that is its
 # whole job).  :class:`HostKVStore` is that surface: one logical store
 # per training cluster, consulted by every worker's host loop.  Tests
-# and the bench drill host workers as threads sharing one store — the
+# and the example drills host workers as threads sharing one store — the
 # PR 9 CPU-testable construction (fleet replicas share one engine); a
 # real deployment backs the same five-verb protocol (set / get / wait /
 # add / delete, plus store-side age stamps and the generation counter)

@@ -48,7 +48,7 @@ Aggregation is host-mediated (workers push gradient trees into the
 store, pull the rank-ordered sum — the MXNet ``dist_sync`` idiom the
 KVStore module documents), which is precisely what makes shrink
 possible: no XLA collective holds a ticket for the ghost.  Tests and
-the bench drill host workers as threads sharing one store and one JAX
+the example drills host workers as threads, sharing one store and one JAX
 runtime — the PR 9 CPU-testable construction — with every failure edge
 injected deterministically through :func:`~dtdl_tpu.resil.faults.
 peer_site`.  Every event on the failure path is named and cataloged
@@ -139,7 +139,7 @@ class ElasticConfig:
     lesson).  ``join_grace_s`` is how long a forming rendezvous stays
     open after its last joiner — it must cover the spread of the
     survivors' abort times; ``heartbeat_s <= 0`` disables the liveness
-    layer (bench baseline)."""
+    layer."""
 
     heartbeat_s: float = 0.05
     watchdog_s: float = 0.3
@@ -369,7 +369,7 @@ class StepWatchdog:
 
 class ElasticWorker:
     """One logical training process of the elastic world (thread-hosted
-    in tests/bench — the PR 9 construction — one per host in a real
+    in tests and drills — the PR 9 construction — one per host in a real
     deployment).  Drives the full machine: heartbeat lease up, join the
     world, loop deadline-guarded steps, and on :class:`PeerLostError`
     abort → re-rendezvous → restore the last committed snapshot →
@@ -411,7 +411,7 @@ class ElasticWorker:
         self.done = False
         self.stopped_t: Optional[float] = None
         # host-side drill telemetry: (event, monotonic t, info) — the
-        # bench row reads detect/re-form/first-step latencies from here
+        # drills read detect/re-form/first-step latencies from here
         self.events: list = []
         # opt-in (audit_samples=True): (generation, step) -> the shard
         # indices THIS worker actually fed its grad step — the raw
@@ -517,7 +517,7 @@ class ElasticWorker:
 
 def run_workers(workers, timeout_s: float = 60.0):
     """Host the workers on threads and join them — the CPU-testable
-    world driver tests and the bench drill share.  A worker that fails
+    world driver tests and the example drill share.  A worker that fails
     to finish within ``timeout_s`` fails the run by name (the harness
     must never itself hang on a hang)."""
     threads = [threading.Thread(target=w.run, daemon=True,

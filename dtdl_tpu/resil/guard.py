@@ -161,7 +161,7 @@ class StepGuard:
                 f"transient; last bad step: {self.last_bad}")
 
     def summary(self) -> dict:
-        """Run-level counters for reports/bench rows."""
+        """Run-level counters for reports."""
         return {"guard_steps": self.n_steps, "guard_bad_steps": self.n_bad,
                 "guard_rollbacks": self.n_rollbacks}
 

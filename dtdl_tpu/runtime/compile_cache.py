@@ -11,7 +11,8 @@ path is part of what makes a later process find the entries again.
 
 Entry points call :func:`enable_compile_cache` once, before the first
 compile (``examples/common.py:bootstrap``, ``chip_smoke.py``,
-``bench.py``); nothing else in the repo sets a cache directory.
+``benchmarks/runners/train.py``); nothing else in the repo sets a cache
+directory.
 
 **The compile account.**  The same call starts the account of what set-up
 costs: jax reports every trace, lowering, backend compile and cache

@@ -62,7 +62,7 @@ class ReplicaHealth:
     close the circuit back to HEALTHY.
 
     ``transitions`` records every edge as ``(t, from, to, reason)`` —
-    the receipt the eviction-latency bench and the never-dispatch-to-
+    the receipt the eviction-latency drills and the never-dispatch-to-
     DRAINING tests read.
     """
 

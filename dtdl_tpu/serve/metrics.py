@@ -15,8 +15,8 @@ with an honest clock:
   observed it.  With ``harvest_lag=k`` these run up to k steps late;
   ``Scheduler.drain`` settles them exactly at boundaries.
 * **Throughput** (prefill/decode tokens per second) — wall-clock between
-  the first dispatch and the last harvest, the same fetch-ends-the-
-  timed-region rule bench.py uses.
+  the first dispatch and the last harvest: a fetch ends the timed
+  region, as in ``benchmarks/runners/train.py``.
 
 Tail percentiles (TTFT / per-token latency p50/p95/p99) come from
 streaming log-bucketed histograms (:class:`dtdl_tpu.obs.hist.
@@ -133,7 +133,7 @@ class ServeMetrics:
         # 19): chunk counts/tokens are the chunked path's ledger;
         # decode_steps_delayed_by_prefill is the PRE-change counter —
         # each whole-prompt (blocking) prefill charges the number of
-        # in-flight decode slots it stalled, so the before/after bench
+        # in-flight decode slots it stalled, so a before/after run
         # can show the interference the chunked path removes;
         # kv_handoff_* meter the page-granular prefill→decode migration
         # (pages moved, seconds spent in the extract sync / inject

@@ -126,7 +126,7 @@ class PageAllocator:
         self._page_hash: dict[int, int] = {}    # page -> chain hash
         # refcount-0 cached pages, least-recently-released first
         self._lru: "OrderedDict[int, None]" = OrderedDict()
-        # counters for ServeMetrics / bench receipts
+        # counters for ServeMetrics
         self.prefix_hit_pages = 0
         self.prefix_miss_pages = 0
         self.evictions = 0
@@ -523,7 +523,7 @@ class HostPageStore:
             disk.on_drop = on_drop
         self._entries: "OrderedDict[int, tuple]" = OrderedDict()
         self._bytes = 0
-        # counters for ServeMetrics / bench receipts
+        # counters for ServeMetrics
         self.spilled_pages = 0
         self.spilled_bytes = 0
         self.host_hits = 0

@@ -1026,7 +1026,7 @@ class Scheduler:
                 # whole-prompt prefill: one blocking compiled call —
                 # every in-flight decode waits a full prefill latency
                 # behind it (the interference the chunked path removes;
-                # the counter is the before/after bench receipt)
+                # the counter is the before/after receipt)
                 self.metrics.on_prefill_block(int(self._active.sum()))
                 # grammar: the prefill's bonus sample IS the request's
                 # first OUTPUT token, so it draws under the automaton's

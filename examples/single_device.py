@@ -6,8 +6,8 @@ Capability parity with reference pytorch/single_gpu.py:43-120: one device,
 manual epoch/step loop, per-batch loss/acc/batch-time logging every 20 steps,
 optional final state_dict save.  Differences by design: the step is one jitted
 XLA program, ``--seed`` actually seeds (the reference parses and drops it,
-single_gpu.py:32-33), and the device is whatever JAX exposes (TPU chip here,
-CPU elsewhere) instead of cuda:0.
+pytorch/single_gpu.py:32-33), and the device is whatever JAX exposes (TPU
+chip here, CPU elsewhere) instead of cuda:0.
 
     python examples/single_device.py --batch-size 64 --lr 0.1 --epochs 2
 """

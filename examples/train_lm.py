@@ -116,7 +116,7 @@ def main():
     per_host_bs = args.batch_size // nproc
     # flops_per_step covers the whole per-host step (sharded over all
     # local devices), so the peak must be per-host too — per-chip peak
-    # times local chips, matching bench.py's per-device convention
+    # times local chips (GoodputMeter's denominator convention)
     peak = peak_flops_per_chip()
     obs = Observer(trace_path=args.trace or None, sentinel="warn",
                    goodput=GoodputMeter(

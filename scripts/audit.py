@@ -16,7 +16,7 @@ The lint half is pure AST (sub-second) and is what
 tests/test_analysis_gate.py runs inside tier-1; ``--programs`` builds
 and compiles the real train/megatron/decode/verify programs (tens of
 seconds on CPU) — the same check the slow-marked
-tests/test_analysis_contracts.py and bench.py's ``audit`` row run.
+tests/test_analysis_contracts.py runs.
 """
 
 from __future__ import annotations

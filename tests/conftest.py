@@ -41,9 +41,9 @@ def devices():
 
 
 # ---------------------------------------------------------------------------
-# Budget discipline (round 16): tier-1 ran 768s of the 870s budget at
-# PR 9, so an unmarked compile-heavy test can push the whole suite past
-# timeout.  This check flags every test that ran slower than
+# Budget discipline (round 16): an unmarked compile-heavy test eats the
+# suite's time limit (1,470 s on six workers today, pytest.ini), so
+# this check flags every test that ran slower than
 # DTDL_BUDGET_SLOW_S (default 10s) WITHOUT a `slow` mark, as a loud
 # terminal section — new observability/serve tests get slow-marked
 # instead of silently eating the remaining headroom.  Set

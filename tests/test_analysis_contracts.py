@@ -3,9 +3,8 @@ audited against the checked-in census baseline.
 
 The whole module is slow-marked (it compiles the train step, the 4D
 megatron step, and the serve decode/verify pair — ~40s on CPU); the
-same audit runs un-marked through ``scripts/audit.py --programs`` and
-as the ``audit`` row of bench.py, so the contract is exercised on every
-bench/audit run even when tier-1 skips the compile cost.
+same audit runs by hand through ``scripts/audit.py --programs`` where
+tier-1 skips the compile cost.
 
 Contracts pinned here (the acceptance criteria of ISSUE 15):
 

@@ -275,7 +275,7 @@ def test_fused_rope_short_table_raises():
 def test_block_table_covers_presets():
     """The autotune-table receipt (ISSUE 8): every shipped model preset
     resolves to an EXPLICIT block-table entry — no silent fallback —
-    and so do the bench/roofline sweep geometries.  Unknown geometries
+    and so do the long-context geometries.  Unknown geometries
     fall back to the documented default unless strict."""
     from dtdl_tpu.models.transformer import transformer_lm
     from dtdl_tpu.ops.attention import (_BLOCK_DEFAULT, block_table_entry,

@@ -9,8 +9,8 @@
 3. **histogram** — streaming log-bucketed percentiles track numpy's
    within the bucket resolution, in fixed memory;
 4. **goodput** — the analytic LM FLOP count matches a hand-derived
-   number for the 'tiny' config within 1% (the LM_ROOFLINE.md
-   convention), and MFU follows from it;
+   number for the 'tiny' config within 1% (the matmul-only
+   convention of obs/goodput.py), and MFU follows from it;
 5. **integration** — `train_epoch` with the FULL observer enabled still
    performs at most one host sync per log window (the PR-1 contract,
    re-pinned with the tests/test_async_metrics.py sync-counting
