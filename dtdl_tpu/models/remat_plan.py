@@ -229,7 +229,11 @@ def hybrid_block_live_bytes(batch: int, seq: int, d_model: int,
     as much again for the cotangents that are live at once.  Held experts (``held``, a
     ``HeldSpec``): the ``R``-row buffers (rows, gate, up, their product,
     the output), each with its cotangent, and the three weights' float32
-    gradients, which a grouped matmul writes whole.  A dense MLP: six
+    gradients, which a grouped matmul writes whole.  ``R`` is the **full**
+    buffer's rows (``held_buffer_rows``), not the first buffer's that a
+    layer computes where its routing fits: the full branch can run, both
+    branches are in the one executable, and the compiler allocates for the
+    larger.  A dense MLP: six
     ``d_ff`` values.  How the step's estimate stands against the v5e
     compiler's total for the Qwen3-Next cell is in PERF.md section 4."""
     t = batch * seq

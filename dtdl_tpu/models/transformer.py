@@ -41,7 +41,8 @@ from dtdl_tpu.models import remat_plan
 from dtdl_tpu.ops.attention import flash_attention, mha_reference
 from dtdl_tpu.ops.gated_delta import gated_delta_rule, stage_plan
 from dtdl_tpu.ops.grouped_matmul import (
-    ROW_TILE, grouped_matmul, held_buffer_rows, rows_of, weighted_rows_sum)
+    ROW_TILE, first_buffer_rows, grouped_matmul, held_buffer_rows, rows_of,
+    weighted_rows_sum)
 from dtdl_tpu.ops.paged_attention import paged_attention
 from dtdl_tpu.ops.rope import apply_rope, rope_frequencies
 from dtdl_tpu.quant import (QuantDenseGeneral, canon_kv_dtype, kv_quantize,
@@ -1057,27 +1058,89 @@ class GatedDeltaNet(nn.Module):
 
 
 class _RoutedExperts(nn.Module):
-    """The held experts' weights and their grouped SwiGLU over the sorted
-    buffer (``HeldExperts`` plans the buffer)."""
+    """The held experts' weights in the compute dtype, ``(wi, wg, wo)``,
+    made once a layer (``HeldExperts`` plans the buffer and chooses its
+    size; :func:`_grouped_swiglu` multiplies)."""
     held: int
     d_ff: int
     dtype: Dtype = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, rows, tile_expert):
-        d_model = rows.shape[-1]
+    def __call__(self, d_model):
         init = nn.initializers.lecun_normal(batch_axis=(0,))
 
         def weight(name, shape, in_ax, out_ax):
             return self.param(name, _part(init, "expert", in_ax, out_ax),
                               shape).astype(self.dtype)
 
-        wi = weight("wi", (self.held, d_model, self.d_ff), "embed", "mlp")
-        wg = weight("wg", (self.held, d_model, self.d_ff), "embed", "mlp")
-        wo = weight("wo", (self.held, self.d_ff, d_model), "mlp", "embed")
+        return (weight("wi", (self.held, d_model, self.d_ff), "embed", "mlp"),
+                weight("wg", (self.held, d_model, self.d_ff), "embed", "mlp"),
+                weight("wo", (self.held, self.d_ff, d_model), "mlp", "embed"))
+
+
+def _grouped_swiglu(xf, gates, weights, plan, n_rows: int):
+    """[T, d] float32: the tokens ``xf`` through their held experts over the
+    first ``n_rows`` rows of the sorted buffer: the way in, the three grouped
+    matmuls with their SwiGLU, the way out weighted by ``gates``.  ``plan``
+    is ``(tile_expert, row_assign, assign_row)`` of the full buffer; a row
+    of ``assign_row`` behind ``n_rows`` reads zeros, as "no row" does."""
+    wi, wg, wo = weights
+    tile_expert, row_assign, assign_row = plan
+    tile_expert = tile_expert[:n_rows // ROW_TILE]
+    row_assign = row_assign[:n_rows]
+    with jax.named_scope("moe_dispatch"):
+        rows = rows_of(xf, row_assign, assign_row)           # [n_rows, d]
+    with jax.named_scope("experts"):
         h = nn.silu(grouped_matmul(rows, wg, tile_expert)) * \
             grouped_matmul(rows, wi, tile_expert)
-        return grouped_matmul(h, wo, tile_expert)
+        y = grouped_matmul(h, wo, tile_expert)
+    with jax.named_scope("moe_dispatch"):
+        return weighted_rows_sum(y, gates, row_assign, assign_row)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _first_or_full(sizes, fits, xf, gates, weights, plan):
+    """:func:`_grouped_swiglu` over ``sizes[0]`` rows where ``fits`` (a
+    scalar on the device), else over ``sizes[1]``: one ``lax.cond`` between
+    two programs of static shapes.  The backward pass is a second ``cond``
+    on the same scalar, each branch differentiating its own size from the
+    operands, which the two share: so what crosses from the forward pass to
+    the backward is the operands alone, and nothing of a branch's size (a
+    differentiated ``cond`` would keep the union of both branches'
+    residuals, the branch not taken writing zeros in the other's shapes)."""
+    return jax.lax.cond(fits, *(
+        functools.partial(_grouped_swiglu, n_rows=n) for n in sizes),
+        xf, gates, weights, plan)
+
+
+def _first_or_full_fwd(sizes, fits, xf, gates, weights, plan):
+    return (_first_or_full(sizes, fits, xf, gates, weights, plan),
+            (fits, xf, gates, weights, plan))
+
+
+def _first_or_full_bwd(sizes, res, d_out):
+    fits, *operands = res
+
+    def backward_over(n_rows):
+        def branch(xf, gates, weights, plan, d_out):
+            # the branch's forward, run again: under the scope jax gives a
+            # checkpoint's, so that a trace reads it as recomputation
+            with jax.named_scope("rematted_computation"):
+                _, pull = jax.vjp(
+                    lambda *a: _grouped_swiglu(*a, plan, n_rows),
+                    xf, gates, weights)
+            return pull(d_out)
+        return branch
+    # the barrier keeps what follows a gradient out of the branches: without
+    # it XLA moves AdamW's first elementwise steps on the weights' gradients
+    # into both, and each branch gives every gradient twice in float32 (13
+    # ms of a 313 ms step on a v5e: PERF.md section 6, PR 32)
+    return (None, *jax.lax.optimization_barrier(
+        jax.lax.cond(fits, *map(backward_over, sizes), *operands, d_out)),
+            None)
+
+
+_first_or_full.defvjp(_first_or_full_fwd, _first_or_full_bwd)
 
 
 class _SharedExpert(nn.Module):
@@ -1115,15 +1178,28 @@ class HeldExperts(nn.Module):
     assignments expected here under even routing, at most every choice of
     every token, in whole row tiles; every expert's group starts on a tile
     and holds at least one).  Rows are gathered, multiplied by their
-    expert's weights (every tile of the ``R`` rows is computed, whatever
-    was routed), and each token sums its rows weighted by ``p``
-    (``rows_of``, ``weighted_rows_sum``: gathers both ways).  So no
-    operation's cost follows the routing.  An assignment that does not fit
-    is never dropped in silence: the layer sows ``overflow_rows`` (and
-    ``live_rows``, the rows the aligned groups need) into the ``moe_stats``
+    expert's weights, and each token sums its rows weighted by ``p``
+    (``rows_of``, ``weighted_rows_sum``: gathers both ways).  **Two sizes
+    are computed, never one that follows the routing**: the first buffer
+    (``first_buffer_rows``: a smaller stated multiple of the expected
+    assignments, a prefix of the full one, since the sort, the groups'
+    starts and ``tile_expert`` are the same) where ``live_rows``, the rows
+    the aligned groups need, fits it, and the full ``R`` rows where it does
+    not: a ``lax.cond`` on the device between two programs of static
+    shapes, every tile of the chosen one computed.  The tiles the first
+    buffer leaves out held zeros only, so both give the same numbers.  The
+    backward pass chooses again and each branch differentiates its own size
+    from the operands, which the two share (tokens, gates, weights, the
+    plan), so nothing of a branch's own size crosses from one pass to the
+    other (``_first_or_full``).  Where the first buffer would be as
+    large as the full one there is one buffer and no choice.  The layer
+    sows ``full_buffer`` (1 where it took the full buffer of two, else 0),
+    and the train step counts the layers that did.  An assignment that does
+    not fit the full buffer is never dropped in silence: the layer sows
+    ``overflow_rows`` (and ``live_rows``) into the ``moe_stats``
     collection, and the train step makes an overflowing step's loss
-    non-finite.  At the stated multiple of the Qwen3-Next share the buffer
-    holds every assignment the shapes allow and none can overflow.
+    non-finite.  At the stated multiple of the Qwen3-Next share the full
+    buffer holds every assignment the shapes allow and none can overflow.
 
     No balance loss: the layer sows no ``aux_loss``.
     """
@@ -1156,11 +1232,13 @@ class HeldExperts(nn.Module):
                                      remat_plan.MOE_PLAN)    # [tokens, k]
         gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
 
-        n_rows, expected = held_buffer_rows(tokens, k, held,
-                                            self.router_width)
+        shapes = (tokens, k, held, self.router_width)
+        n_rows, expected = held_buffer_rows(*shapes)
+        first_rows = first_buffer_rows(*shapes)
         step_name = remat_plan.traced_step_name()
         if step_name is not None:
-            record_expert_buffer(step_name, n_rows, ROW_TILE, expected)
+            record_expert_buffer(step_name, n_rows, ROW_TILE, expected,
+                                 first_rows)
         with jax.named_scope("moe_dispatch"):
             local = idx.reshape(-1) - self.first             # [tokens * k]
             local = jnp.where((local >= 0) & (local < held), local, held)
@@ -1191,13 +1269,20 @@ class HeldExperts(nn.Module):
             fits = jnp.clip(n_rows - group_start, 0, counts)
             self.sow("moe_stats", "overflow_rows",
                      jnp.sum(counts - fits))
-            self.sow("moe_stats", "live_rows", tile_end[-1] * ROW_TILE)
-            rows = rows_of(xf, row_assign, assign_row)       # [R, d]
-        y = _RoutedExperts(held, self.d_ff, self.dtype,
-                           name="experts")(rows, tile_expert)
-        with jax.named_scope("moe_dispatch"):
-            out = weighted_rows_sum(y, gates, row_assign,
-                                    assign_row).astype(self.dtype)
+            live_rows = tile_end[-1] * ROW_TILE
+            self.sow("moe_stats", "live_rows", live_rows)
+        weights = _RoutedExperts(held, self.d_ff, self.dtype,
+                                 name="experts")(d_model)
+        operands = (xf, gates, weights, (tile_expert, row_assign, assign_row))
+        if first_rows < n_rows:
+            fits = live_rows <= first_rows
+            out = _first_or_full((first_rows, n_rows), fits, *operands)
+        else:
+            fits = True
+            out = _grouped_swiglu(*operands, n_rows)
+        self.sow("moe_stats", "full_buffer",
+                 jnp.logical_not(fits).astype(jnp.int32))
+        out = out.astype(self.dtype)
         if self.shared_d_ff:
             out = out + _SharedExpert(self.shared_d_ff, self.dtype,
                                       name="shared")(xf)

@@ -178,6 +178,13 @@ EVENT_CATALOG = frozenset({
 DEVICE_SCOPES = frozenset({"embed", "head", "loss", "grad_sync", "update",
                            "guard", "gdn", "moe_dispatch", "gate"})
 
+# scopes a function enters under a name that is another catalogue's, and
+# that map as there: the held experts' grouped SwiGLU runs outside its flax
+# module, inside the choice of buffer, and keeps the module's ``experts``
+# (:data:`_MOE_MODULES`); a backward branch of that choice runs its forward
+# again under the name jax gives a checkpoint's (``pass`` 'recompute')
+REENTERED_SCOPES = frozenset({"experts", "rematted_computation"})
+
 # flax module scopes of models/transformer.py (flax puts them there; this
 # repo only names the modules) -> component
 MODULE_SCOPES = {
@@ -236,11 +243,18 @@ def device_component(name_stack: str):
     function in the transform's name (``jvp(loss)``,
     ``transpose(jvp(loss))``); the wrappers are read for the pass and
     stripped for the name."""
-    names, transposed = [], False
+    names, transposed, rematted = [], False, False
     for element in name_stack.split("/"):
+        here = False
         while (m := _WRAPPED.match(element)):
-            transposed = transposed or m.group(1) == "transpose"
+            here = here or m.group(1) == "transpose"
             element = m.group(2)
+        transposed = transposed or here
+        # ``transpose(rematted_computation)`` is the backward pass of what
+        # was recomputed under a scope of that name (the held experts'
+        # choice of buffer, models/transformer.py:_first_or_full)
+        rematted = rematted or (element == "rematted_computation"
+                                and not here)
         # flax names a module's method other than ``__call__`` as a scope
         # of its own (``attn/attn._grouped_attend/q``): not a component
         if not (names and element.startswith(names[-1] + ".")):
@@ -271,12 +285,16 @@ def device_component(name_stack: str):
                 component = kernels[0]
             elif "moe_dispatch" in rest:
                 component = "moe_dispatch"
-            elif rest and rest[0] in _MOE_MODULES:
-                component = _MOE_MODULES[rest[0]]
+            else:
+                # the first sub-module named: the experts' own ops sit
+                # inside the choice of buffer (``moe/cond/branch_0_fun/
+                # experts/...``)
+                component = next((_MOE_MODULES[n] for n in rest
+                                  if n in _MOE_MODULES), component)
         break
     if component in _AFTER_BACKWARD:
         return component, "update"
-    if "rematted_computation" in names:
+    if rematted:
         return component, "recompute"
     return component, "backward" if transposed else "forward"
 

@@ -18,16 +18,22 @@ Two Pallas kernels:
   consecutive tiles share it.  Every expert owns a tile, so every block
   of ``dw`` is written.
 
-**Cost is a function of shapes alone**: the grid is ``R / ROW_TILE`` steps
-whatever was routed, every tile is computed (the rows behind the last live
-one are zeros and give zeros), and the one ``pl.when`` (zeroing ``dw[e]``
-at an expert's first tile) fires once an expert a call.  The design follows
-megablox (``jax.experimental.pallas.ops.tpu.megablox``: groups by scalar
-prefetch, a transposed kernel for the weights' gradient) without its
-dynamic grid; no code is taken from it.
+**A call's cost is a function of its shapes alone**: the grid is ``R /
+ROW_TILE`` steps whatever was routed, every tile of the rows it is given is
+computed (the rows behind the last live one are zeros and give zeros), and
+the one ``pl.when`` (zeroing ``dw[e]`` at an expert's first tile) fires once
+an expert a call.  The design follows megablox
+(``jax.experimental.pallas.ops.tpu.megablox``: groups by scalar prefetch, a
+transposed kernel for the weights' gradient) without its dynamic grid; no
+code is taken from it.  How many rows a call is given is the layer's to
+say, and it has two answers, both from shapes (:func:`held_buffer_rows`,
+:func:`first_buffer_rows`): the first buffer, a prefix of the full one that
+holds :data:`FIRST_MULTIPLE` times the expected rows, where the routing fits
+it, and the full buffer where it does not.  So a step's cost takes one of
+two values a layer, never a value that follows the seed.
 
-**The buffer's rows** (:func:`held_buffer_rows`) and **the way in and out of
-it** (:func:`rows_of`, :func:`weighted_rows_sum`) sit here too.  Both are
+**The buffers' rows** and **the way in and out of them** (:func:`rows_of`,
+:func:`weighted_rows_sum`) sit here too.  Both are
 gathers, and so are their gradients: an assignment has at most one row and a
 row at most one assignment, so the transpose of "row ``r`` reads token
 ``t``" is "token ``t`` sums its ``top_k`` rows", which needs no scatter
@@ -51,8 +57,8 @@ from dtdl_tpu.ops.attention import _sds, _vma_of
 
 ROW_TILE = 128      # the MXU's height; an expert's group is a multiple of it
 
-# The buffer holds this multiple of the assignments expected here under even
-# routing, and never more than every choice of every token.  16 is
+# The full buffer holds this multiple of the assignments expected here under
+# even routing, and never more than every choice of every token.  16 is
 # ``router_width / held`` of the Qwen3-Next share (512 / 32), where the two
 # meet: every assignment the shapes allow has a row, so no routing overflows.
 # Why not less: a share's router is trained by the held experts' terms alone
@@ -64,23 +70,47 @@ ROW_TILE = 128      # the MXU's height; an expert's group is a multiple of it
 # 3.13, 3.00, 2.90, ...; the peaks at steps 10 to 17, 1.1 to 2.5 after
 # them; PERF.md section 6, PR 29).  1.25 times the largest is 5.0, which a
 # log-normal through the twelve puts within reach of one run in eighty: a
-# run that overflows is a failed run, so the buffer takes what no seed can
-# pass.  8 would cost an estimated sixth less of the step at odds of one run
-# in 10^4.
+# run that overflows is a failed run, so the full buffer takes what no seed
+# can pass.
 ROWS_MULTIPLE = 16.0
+
+# The first buffer, the one a layer computes unless its routing does not fit
+# (models/transformer.py:HeldExperts picks a layer a step, on the device),
+# holds this multiple of the expected assignments.  A layer that does not
+# fit drops nothing: it takes the full buffer that step, at the full
+# buffer's cost.  8 is twice the largest reading of the probe above (4.03);
+# the log-normal through its twelve per-seed maxima (mean of logs 0.963,
+# deviation 0.289) puts a run of 48 steps past 8 once in 18,000, past 6 once
+# in 490, past 5 once in 86: a benchmark cell is run some twenty-five times
+# a check, and a run that meets the full buffer is a slower run, so the
+# multiple is the one that almost none meets.  In that cell 352 tiles
+# (45,056 rows) are computed where the full buffer has 672 (86,016).
+FIRST_MULTIPLE = 8.0
 
 
 def held_buffer_rows(tokens: int, top_k: int, held: int,
                      router_width: int) -> tuple[int, float]:
-    """``(R, expected)``: the rows of the held experts' buffer, from shapes
-    alone, and the assignments expected here under even routing.  ``R`` is
-    :data:`ROWS_MULTIPLE` times the expected ones, at most every choice of
-    every token, in whole row tiles, and one tile more an expert: a group
-    starts on a tile and holds at least one, which costs at most that."""
+    """``(R, expected)``: the rows of the held experts' full buffer, from
+    shapes alone, and the assignments expected here under even routing.
+    ``R`` is :data:`ROWS_MULTIPLE` times the expected ones, at most every
+    choice of every token, in whole row tiles, and one tile more an expert:
+    a group starts on a tile and holds at least one, which costs at most
+    that."""
     expected = tokens * top_k * held / router_width
     most = tokens * min(top_k, held)
     tiles = math.ceil(min(ROWS_MULTIPLE * expected, most) / ROW_TILE) + held
     return tiles * ROW_TILE, expected
+
+
+def first_buffer_rows(tokens: int, top_k: int, held: int,
+                      router_width: int) -> int:
+    """The rows of the first buffer, from shapes alone: :data:`FIRST_MULTIPLE`
+    times the expected assignments in whole row tiles and one tile more an
+    expert, and never more than the full buffer's (:func:`held_buffer_rows`):
+    where the two are equal there is one buffer."""
+    full, expected = held_buffer_rows(tokens, top_k, held, router_width)
+    tiles = math.ceil(FIRST_MULTIPLE * expected / ROW_TILE) + held
+    return min(tiles * ROW_TILE, full)
 
 
 def _gmm_kernel(tile_expert, x_ref, w_ref, o_ref, *, transpose_rhs):
@@ -95,11 +125,24 @@ def _gmm_kernel(tile_expert, x_ref, w_ref, o_ref, *, transpose_rhs):
 def moe_gmm(x, w, tile_expert, transpose_rhs: bool = False):
     """[R, N] = rows [R, K] times their tile's expert weight of ``w``
     [E, K, N] ([E, N, K] with ``transpose_rhs``)."""
-    rows, k = x.shape
-    n = w.shape[1] if transpose_rhs else w.shape[2]
+    rows = x.shape[0]
     if rows % ROW_TILE or tile_expert.shape != (rows // ROW_TILE,):
         raise ValueError(f"{rows} rows need {rows / ROW_TILE} tile ids, "
                          f"got {tile_expert.shape}")
+    return _gmm_call(x, w, tile_expert.astype(jnp.int32), transpose_rhs,
+                     _attention._use_interpret())
+
+
+# Under ``jax.jit`` so that a train step traces a kernel's body and lowers it
+# to Mosaic once a shape and not once a call: an expert layer calls each of
+# its six shapes in three passes, and both sizes of its buffer are in the
+# step (PERF.md section 6, PR 32; ops/gated_delta.py does the same).
+# ``interpret`` is an argument so that jit's cache tells the two lowerings of
+# one shape apart.
+@functools.partial(jax.jit, static_argnames=("transpose_rhs", "interpret"))
+def _gmm_call(x, w, tile_expert, transpose_rhs, interpret):
+    rows, k = x.shape
+    n = w.shape[1] if transpose_rhs else w.shape[2]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(rows // ROW_TILE,),
@@ -114,10 +157,10 @@ def moe_gmm(x, w, tile_expert, transpose_rhs: bool = False):
         name="moe_gmm",
         grid_spec=grid_spec,
         out_shape=_sds((rows, n), x.dtype, _vma_of(x, w)),
-        interpret=_attention._use_interpret(),
+        interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-    )(tile_expert.astype(jnp.int32), x, w)
+    )(tile_expert, x, w)
 
 
 def _tgmm_kernel(tile_expert, x_ref, dy_ref, o_ref):
@@ -137,6 +180,12 @@ def _tgmm_kernel(tile_expert, x_ref, dy_ref, o_ref):
 def moe_tgmm(x, dy, tile_expert, n_experts: int):
     """[E, K, N] float32: each expert's ``x^T @ dy`` over its own tiles.
     ``tile_expert`` must be non-decreasing and name every expert."""
+    return _tgmm_call(x, dy, tile_expert.astype(jnp.int32), n_experts,
+                      _attention._use_interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("n_experts", "interpret"))
+def _tgmm_call(x, dy, tile_expert, n_experts, interpret):
     rows, k = x.shape
     n = dy.shape[1]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -153,11 +202,11 @@ def moe_tgmm(x, dy, tile_expert, n_experts: int):
         name="moe_tgmm",
         grid_spec=grid_spec,
         out_shape=_sds((n_experts, k, n), jnp.float32, _vma_of(x, dy)),
-        interpret=_attention._use_interpret(),
+        interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=64 << 20),
-    )(tile_expert.astype(jnp.int32), x, dy)
+    )(tile_expert, x, dy)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=())

@@ -32,9 +32,10 @@ The account is one a process because jax's listeners are.
 :func:`remat_plans` returns them, and :func:`compile_totals` carries the
 newest as ``remat_blocks_by_rung``, ``remat_kept_bytes``,
 ``remat_budget_bytes`` and ``remat_estimate_bytes``.  A held-experts layer
-(``models/transformer.py:HeldExperts``) adds the shape of its row buffer
-the same way: :func:`record_expert_buffer`, :func:`expert_buffers`, and
-``moe_buffer_rows``, ``moe_row_tile``, ``moe_expected_rows`` in the totals.
+(``models/transformer.py:HeldExperts``) adds the shapes of its two row
+buffers the same way: :func:`record_expert_buffer`, :func:`expert_buffers`,
+and ``moe_buffer_rows``, ``moe_first_buffer_rows``, ``moe_row_tile``,
+``moe_expected_rows`` in the totals.
 A linear-attention layer (``models/transformer.py:GatedDeltaNet``) adds which
 implementation of the delta rule's chunk-local stage its shapes chose
 (``ops/gated_delta.py:stage_plan``): :func:`record_gdn_path`,
@@ -112,13 +113,16 @@ def record_remat_plan(plan) -> None:
 
 
 def record_expert_buffer(fun_name: str, rows: int, row_tile: int,
-                         expected_rows: float) -> None:
-    """Keep the shape of the held experts' buffer a train step was just
-    traced with (models/transformer.py:HeldExperts): its rows, the row
-    tile, and the assignments expected under even routing."""
+                         expected_rows: float, first_rows: int) -> None:
+    """Keep the shapes of the held experts' buffers a train step was just
+    traced with (models/transformer.py:HeldExperts): the full buffer's
+    rows, the row tile, the assignments expected under even routing, and
+    the rows of the first buffer, the one a layer computes where its
+    routing fits (equal to ``rows`` where there is one buffer)."""
     _EXPERT_BUFFERS.append({"fun_name": fun_name, "rows": rows,
                             "row_tile": row_tile,
-                            "expected_rows": expected_rows})
+                            "expected_rows": expected_rows,
+                            "first_rows": first_rows})
 
 
 def record_gdn_path(fun_name: str, path: str, chunk: int,
@@ -186,6 +190,7 @@ def compile_totals() -> dict:
     if _EXPERT_BUFFERS:
         newest = _EXPERT_BUFFERS[-1]
         totals["moe_buffer_rows"] = newest["rows"]
+        totals["moe_first_buffer_rows"] = newest["first_rows"]
         totals["moe_row_tile"] = newest["row_tile"]
         totals["moe_expected_rows"] = newest["expected_rows"]
     for path in sorted({r["path"] for r in _GDN_PATHS}):

@@ -166,9 +166,13 @@ def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
 
     A model whose expert layers hold one chip's share (``HeldExperts``)
     adds the metrics ``moe_overflow_rows`` (assignments that did not fit
-    their buffers; such a step's loss is made non-finite) and
-    ``moe_live_rows`` (the most buffer rows a layer needed).  A model with
-    an untied head refuses ``vocab_chunk_size > 0``.
+    their full buffers; such a step's loss is made non-finite),
+    ``moe_live_rows`` (the most buffer rows a layer needed) and
+    ``moe_full_buffer_layers`` (how many of the step's expert layers did
+    not fit their first buffer and computed the full one: the same numbers
+    at a higher cost, so a step that reads above 0 is a slower step, not a
+    wrong one).  A model with an untied head refuses ``vocab_chunk_size >
+    0``.
 
     ``guard`` folds the resil anomaly check into the program, exactly as
     in :func:`make_train_step`.
@@ -207,9 +211,10 @@ def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
             """``(loss, stats)`` of a model whose expert layers hold a share
             (models/transformer.py:HeldExperts sows ``moe_stats``): the
             assignments that did not fit their buffers, summed over the
-            layers, and the most rows any layer's aligned groups needed.
-            An overflow is an error, never a silent drop: it makes the
-            step's loss non-finite."""
+            layers, the most rows any layer's aligned groups needed, and
+            the number of layers that took the full buffer in place of the
+            first.  An overflow is an error, never a silent drop: it makes
+            the step's loss non-finite."""
             stats = variables.get("moe_stats", {})
             if not stats:       # static at trace time: no such layer
                 return loss, None
@@ -224,7 +229,9 @@ def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
             return loss, {
                 "moe_overflow_rows": overflow.astype(jnp.float32),
                 "moe_live_rows": jnp.max(jnp.stack(
-                    of("live_rows"))).astype(jnp.float32)}
+                    of("live_rows"))).astype(jnp.float32),
+                "moe_full_buffer_layers": sum(
+                    of("full_buffer")).astype(jnp.float32)}
 
         if vocab_chunk_size and "head" in state.params:
             raise ValueError(

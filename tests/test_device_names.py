@@ -270,6 +270,23 @@ STACKS = [
     (_FWD + "block_1/block_1._hybrid/moe/shared/wi/dot_general",
      "moe_shared", "forward"),
     (_FWD + "block_1/block_1._hybrid/moe/top_k", "moe", "forward"),
+    # the choice of the experts' buffer (PR 32): a branch's ops keep their
+    # scopes, and a backward branch's own forward reads as recomputation
+    (_FWD + "block_1/block_1._hybrid/moe/cond/branch_0_fun/experts/moe_gmm/"
+     "pallas_call", "moe_gmm", "forward"),
+    (_FWD + "block_1/block_1._hybrid/moe/cond/branch_1_fun/moe_dispatch/"
+     "jit(_take)/gather", "moe_dispatch", "forward"),
+    (_BWD + "jvp(TransformerLM)/checkpoint/block_1/block_1._hybrid/moe/cond/"
+     "branch_0_fun/rematted_computation/jvp(experts)/jit(silu)/mul",
+     "moe_experts", "recompute"),
+    (_BWD + "jvp(TransformerLM)/checkpoint/block_1/block_1._hybrid/moe/cond/"
+     "branch_0_fun/transpose(rematted_computation)/jvp(moe_dispatch)/"
+     "jit(_take)/gather", "moe_dispatch", "backward"),
+    (_BWD + "jvp(TransformerLM)/checkpoint/block_1/block_1._hybrid/moe/cond/"
+     "branch_1_fun/transpose(rematted_computation)/jvp(experts)/moe_tgmm/"
+     "pallas_call", "moe_gmm", "backward"),
+    (_BWD + "jvp(TransformerLM)/checkpoint/block_1/block_1._hybrid/moe/cond",
+     "moe", "backward"),
     # the step's own scalar bookkeeping and the rope table carry no scope
     ("jit(lm_train_step)/div", None, "forward"),
     ("jit(lm_train_step)/jvp(TransformerLM)/cos", None, "forward"),
@@ -339,15 +356,20 @@ def test_hybrid_module_scopes_are_the_models_own_modules():
     assert "head" in DEVICE_SCOPES and "gate" in DEVICE_SCOPES
 
 
-@pytest.mark.parametrize("gdn_dim", [8, 128], ids=["jnp", "gdn_kernels"])
-def test_lowered_hybrid_step_leaves_no_matmul_or_kernel_unscoped(gdn_dim):
+@pytest.mark.parametrize("gdn_dim, router_width", [
+    (8, 8), (128, 8), (8, 32)], ids=["jnp", "gdn_kernels", "two_buffers"])
+def test_lowered_hybrid_step_leaves_no_matmul_or_kernel_unscoped(
+        gdn_dim, router_width):
     """In the lowered train step of a hybrid model every ``dot_general``
     and every op of a grouped-matmul kernel, and at head sizes of 128 of
     the delta rule's kernels, lies under a component, and
     each new component shows the passes it should (rung 0 on the CPU:
-    forward, recompute and backward)."""
+    forward, recompute and backward).  At a router of 32 the experts'
+    first buffer is smaller than the full one (4 tiles for 5), so the
+    layer's kernels and gathers sit in the branches of its choice."""
     model = _hybrid_lm(attn_impl="flash", remat=True, dtype=jnp.bfloat16,
-                       gdn_key_dim=gdn_dim, gdn_value_dim=gdn_dim)
+                       gdn_key_dim=gdn_dim, gdn_value_dim=gdn_dim,
+                       moe_router_width=router_width)
     tokens = jnp.zeros((2, 72), jnp.int32)      # two chunks of the rule
     params = model.init(jax.random.PRNGKey(0), tokens[:, :-1])["params"]
     state = TrainState.create(apply_fn=model.apply, params=params,
@@ -370,6 +392,8 @@ def test_lowered_hybrid_step_leaves_no_matmul_or_kernel_unscoped(gdn_dim):
             assert component == KERNEL_NAMES[kernel[0]], (op, stack)
         if component:
             seen.setdefault(component, set()).add(phase)
+    assert any("/moe/cond/branch_1_fun/" in stack for _, stack, _ in
+               _op_stacks(lowered)) == (router_width == 32)
     every = {"forward", "recompute", "backward"}
     for component in ("gdn", "gdn_proj", "gdn_conv", "moe_gmm",
                       "moe_dispatch", "moe_router", "moe_shared",

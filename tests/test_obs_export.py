@@ -196,13 +196,15 @@ def test_event_catalog_audit_no_silent_drift():
 def test_device_scope_catalog_audit_no_silent_drift():
     """The same audit for the device-side names: every
     ``named_scope("...")`` literal under dtdl_tpu/ is a key of
-    DEVICE_SCOPES and every key has a scope in the source; every
+    DEVICE_SCOPES (or re-enters another catalogue's scope by name:
+    REENTERED_SCOPES) and every key has a scope in the source; every
     ``pallas_call`` carries a ``name=`` line and the names are
     KERNEL_NAMES' keys; the functions train/step.py hands to
     ``strategy.compile*`` are STEP_NAMES.  Device time and the compile
     account are read by these names, so a scope, a kernel or a step added
     without its catalogue entry would go unattributed in silence."""
-    from dtdl_tpu.obs.trace import DEVICE_SCOPES, KERNEL_NAMES, STEP_NAMES
+    from dtdl_tpu.obs.trace import (_MOE_MODULES, DEVICE_SCOPES, KERNEL_NAMES,
+                                    REENTERED_SCOPES, STEP_NAMES)
     pkg = pathlib.Path(dtdl_tpu.__file__).parent
     scope_pat = re.compile(r"named_scope\(\s*(f?)\"(\w[^\"]*)\"")
     name_pat = re.compile(r"^\s+name=\"(\w+)\",$", re.M)
@@ -219,6 +221,11 @@ def test_device_scope_catalog_audit_no_silent_drift():
                 f"{py.name}: {calls} pallas_call(s), {len(names)} name= "
                 f"lines")
             kernels.update(names)
+    # a scope re-entered by name is another catalogue's: a module's, or
+    # jax's own for recomputation
+    assert REENTERED_SCOPES <= scopes
+    assert REENTERED_SCOPES - {"rematted_computation"} <= set(_MOE_MODULES)
+    scopes -= REENTERED_SCOPES
     assert scopes == set(DEVICE_SCOPES), (
         f"uncataloged scopes: {sorted(scopes - set(DEVICE_SCOPES))}; "
         f"stale catalog entries: {sorted(set(DEVICE_SCOPES) - scopes)}")

@@ -3,8 +3,10 @@ grouped-query attention, one chip's share of an expert layer, an untied
 head) against the benchmark's plain reference of the same architecture
 (``benchmarks/families/qwen3_next.py``, which imports nothing of the
 program), on seeded weights at a small size; the share test; the overflow
-that is never a silent drop; the grouped-matmul kernels under the
-interpreter; and that the model's defaults are the dense model, bit for bit.
+that is never a silent drop; the two sizes of the experts' buffer, and that
+the choice between them changes no number; the grouped-matmul kernels under
+the interpreter; and that the model's defaults are the dense model, bit for
+bit.
 """
 
 import hashlib
@@ -31,9 +33,10 @@ from dtdl_tpu.models.transformer import (GdnSpec, HeldExperts,  # noqa: E402
                                          _SharedExpert)
 from dtdl_tpu.obs import goodput                              # noqa: E402
 from dtdl_tpu.ops import grouped_matmul as gm                 # noqa: E402
-from dtdl_tpu.ops.grouped_matmul import (ROW_TILE, grouped_matmul,  # noqa: E402
-                                         held_buffer_rows, moe_gmm, moe_tgmm,
-                                         rows_of, weighted_rows_sum)
+from dtdl_tpu.ops.grouped_matmul import (ROW_TILE, first_buffer_rows,  # noqa: E402
+                                         grouped_matmul, held_buffer_rows,
+                                         moe_gmm, moe_tgmm, rows_of,
+                                         weighted_rows_sum)
 from dtdl_tpu.parallel.strategy import SingleDevice           # noqa: E402
 from dtdl_tpu.train import make_lm_train_step                 # noqa: E402
 from dtdl_tpu.train.state import TrainState                   # noqa: E402
@@ -109,7 +112,7 @@ def test_program_equals_the_plain_reference_on_loss_and_every_gradient(kinds):
             lambda p, t: FAMILY.loss_and_grads(p, t, cfg, "f32"))(made, toks)
     assert float(loss) == pytest.approx(float(ref_loss), rel=1e-5)
     stats = muts["moe_stats"]
-    assert len(jax.tree.leaves(stats)) == 2 * len(kinds)
+    assert len(jax.tree.leaves(stats)) == 3 * len(kinds)
     assert all(int(layer["moe"]["overflow_rows"][0]) == 0
                for layer in stats.values())
     grads = dict(zip(paths, jax.tree.leaves(grads)))
@@ -204,22 +207,165 @@ def test_overflow_makes_the_loss_non_finite_and_counts(monkeypatch):
     assert not np.isfinite(metrics["loss"])
 
 
-@pytest.mark.parametrize("tokens_, top_k, held, width", [
-    (8190, 10, 32, 512), (276, 4, 2, 4), (80, 3, 4, 16)])
+def _primitives(jaxpr, out=None):
+    """The names of a jaxpr's primitives, those of its sub-jaxprs too."""
+    out = set() if out is None else out
+    for eqn in jaxpr.eqns:
+        out.add(eqn.primitive.name)
+        for value in eqn.params.values():
+            items = value if isinstance(value, (tuple, list)) else (value,)
+            for item in items:
+                inner = getattr(item, "jaxpr", item)
+                if hasattr(inner, "eqns"):
+                    _primitives(inner, out)
+    return out
+
+
+@pytest.mark.parametrize("tokens_, top_k, held, width, first, full", [
+    (8190, 10, 32, 512, 45056, 86016), (276, 4, 2, 4, None, 896),
+    (80, 3, 4, 16, None, 768), (276, 2, 4, 64, 896, 1152)])
 def test_the_buffer_holds_every_assignment_the_shapes_allow(tokens_, top_k,
-                                                            held, width):
-    """At the stated multiple no routing overflows: the rows are every
-    choice of every token in whole tiles and a tile an expert, which the
-    worst split of the assignments over the experts still fits."""
-    rows, expected = held_buffer_rows(tokens_, top_k, held, width)
+                                                            held, width,
+                                                            first, full):
+    """At the stated multiple no routing overflows: the full buffer's rows
+    are every choice of every token in whole tiles and a tile an expert,
+    which the worst split of the assignments over the experts still fits.
+    The first buffer (``first``; None: as large as the full one, so there is
+    one buffer) is 8 times the expected rows in whole tiles and a tile an
+    expert, and the traced layer chooses between two only where it has
+    two."""
+    shapes = (tokens_, top_k, held, width)
+    rows, expected = held_buffer_rows(*shapes)
     assert expected == tokens_ * top_k * held / width
     most = tokens_ * min(top_k, held)
     assert gm.ROWS_MULTIPLE * expected >= most
-    assert rows == (-(-most // ROW_TILE) + held) * ROW_TILE
+    assert rows == (-(-most // ROW_TILE) + held) * ROW_TILE == full
     # the worst case for alignment: every expert one row into a new tile
     counts = np.full(held, most // held)
     counts[:most % held] += 1
     assert sum(max(1, -(-c // ROW_TILE)) for c in counts) * ROW_TILE <= rows
+    assert first_buffer_rows(*shapes) == (first or full)
+    if first:
+        assert first < full and first == ROW_TILE * (
+            int(np.ceil(gm.FIRST_MULTIPLE * expected / ROW_TILE)) + held)
+    layer = HeldExperts(width, 0, held, top_k, 8, dtype=jnp.float32)
+    x = jnp.zeros((1, tokens_, 8))
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)["params"]
+    traced = _primitives(jax.make_jaxpr(lambda p: layer.apply(
+        {"params": p}, x, mutable=["moe_stats"]))(params).jaxpr)
+    assert "pallas_call" in traced
+    assert ("cond" in traced) == bool(first)
+
+
+# top 2 of 64 with 4 held, 4 x 69 tokens: 34.5 assignments expected, a full
+# buffer of 9 tiles and a first of 7; two full-attention layers
+_TWO_BUFFERS = dict(CFG, router_num_experts=64, num_experts=4,
+                    first_expert_held=8, num_experts_per_tok=2,
+                    num_hidden_layers=2, full_attention_interval=1)
+
+
+def _lean_to_the_held(params, bias):
+    """``params`` with the first feature of every embedding raised by ``2
+    bias`` and the first row of every router raised by ``bias`` in the
+    columns of the held experts 10 and 11, the last two: the residual
+    stream's first feature is then large in every token, and every token
+    chooses those two."""
+    def lean(path, leaf):
+        if _leaf_path(path).endswith("router/kernel"):
+            return leaf.at[0, 10:12].add(bias)
+        if _leaf_path(path) == "embed":
+            return leaf.at[:, 0].add(2 * bias)
+        return leaf
+    return jax.tree_util.tree_map_with_path(lean, params)
+
+
+@pytest.mark.parametrize("bias, full_layers", [(0.0, 0), (4.0, 2)],
+                         ids=["fits_the_first", "takes_the_full"])
+def test_the_choice_of_buffer_changes_no_number(monkeypatch, bias,
+                                                full_layers):
+    """Two buffers against one (the first multiple raised to the full one's,
+    which is the program before there was a choice), with every layer's
+    routing inside the first buffer or, the routers biased towards two of
+    the held experts, none: a train step's loss is the one-buffer program's
+    and the step counts the layers that took the full buffer; a layer's
+    output and every gradient are the one-buffer layer's.  Two programs that
+    XLA:CPU fuses differently agree to float32's rounding, not to the bit
+    (the shared expert's gate, which no buffer touches, differs in its last
+    bit between them); so the bits are compared where the buffer's size is
+    the only difference: the layer's own operands, taken from the one-buffer
+    layer, through the grouped SwiGLU at both sizes, value and every
+    cotangent.  A routing that fits gives the same bits; one that does not
+    would have lost rows to the first buffer, and took the full one."""
+    from dtdl_tpu.models import transformer
+    cfg = _TWO_BUFFERS
+    tokens_, d = 4 * (ROW - 1), cfg["hidden_size"]
+    shapes = (tokens_, 2, 4, 64)
+    first, full = 7 * ROW_TILE, 9 * ROW_TILE
+    model, params, _, _ = _model_and_params(cfg)
+    params = _lean_to_the_held(params, bias)
+    toks = jnp.asarray(tokens.batch_tokens(11, 0, 4, ROW, cfg["vocab_size"]))
+    layer = HeldExperts(64, 8, 4, 2, 24, 24, dtype=jnp.float32)
+    keys = jax.random.split(jax.random.PRNGKey(2), 3)
+    x = jax.random.normal(keys[0], (4, ROW - 1, d)).at[..., 0].add(2 * bias)
+    layer_params = _lean_to_the_held(jax.tree.map(
+        lambda p: jax.random.normal(keys[1], p.shape) / np.sqrt(p.shape[-2]),
+        nn.unbox(layer.init(keys[2], x)["params"])), bias)
+
+    def run():
+        _, metrics = make_lm_train_step(SingleDevice())(
+            _tiny_state(model, jax.tree.map(jnp.copy, params)),
+            {"tokens": toks})               # the step donates its state
+
+        def loss(p, x):
+            out, muts = layer.apply({"params": p}, x, mutable=["moe_stats"])
+            return jnp.sum(out * jnp.cos(out)), (out, muts["moe_stats"])
+        (_, (out, stats)), grads = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(layer_params, x)
+        return jax.device_get(metrics), out, stats, grads
+
+    assert first_buffer_rows(*shapes) == first
+    assert held_buffer_rows(*shapes)[0] == full
+    metrics, out, stats, grads = run()
+    monkeypatch.setattr(gm, "FIRST_MULTIPLE", gm.ROWS_MULTIPLE)
+    assert first_buffer_rows(*shapes) == full
+    metrics1, out1, stats1, grads1 = run()
+
+    assert metrics["moe_full_buffer_layers"] == full_layers
+    assert metrics1["moe_full_buffer_layers"] == 0
+    assert metrics["moe_live_rows"] == metrics1["moe_live_rows"]
+    assert (metrics["moe_live_rows"] > first) == bool(full_layers)
+    assert metrics["moe_overflow_rows"] == 0
+    assert int(stats["full_buffer"][0]) == bool(full_layers)
+    assert int(stats1["full_buffer"][0]) == 0
+    assert (int(stats["live_rows"][0]) > first) == bool(full_layers)
+
+    def same(a, b, what):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.linalg.norm(a - b) <= 1e-5 * np.linalg.norm(b) > 0, what
+
+    same(metrics["loss"], metrics1["loss"], "the step's loss")
+    same(out, out1, "the layer's output")
+    for (path, a), b in zip(
+            jax.tree_util.tree_flatten_with_path(grads)[0],
+            jax.tree.leaves(grads1)):
+        same(a, b, jax.tree_util.keystr(path))
+
+    # the layer's own operands through both sizes, to the bit
+    swiglu, calls = transformer._grouped_swiglu, []
+    monkeypatch.setattr(transformer, "_grouped_swiglu", lambda *a: (
+        calls.append(a), swiglu(*a))[1])
+    layer.apply({"params": layer_params}, x, mutable=["moe_stats"])
+    (xf, gates, weights, plan, n_rows), = calls
+    assert n_rows == full
+    got = {}
+    for n in (first, full):
+        y, pull = jax.vjp(jax.jit(lambda *a, n=n: swiglu(*a, plan, n)),
+                          xf, gates, weights)
+        got[n] = jax.tree.leaves((y, pull(jnp.cos(y))))
+    equal = [np.array_equal(np.asarray(a), np.asarray(b))
+             for a, b in zip(got[first], got[full])]
+    assert len(equal) == 6
+    assert all(equal) if not full_layers else not any(equal[:3])
 
 
 def test_the_way_in_and_out_of_the_buffer_equals_a_scatter_add():
@@ -407,8 +553,11 @@ def test_the_plan_reckons_each_blocks_own_bytes(monkeypatch):
     buffer = compile_cache.expert_buffers()[-1]
     rows, expected = held_buffer_rows(t, 3, 4, 16)
     assert buffer == {"fun_name": "lm_train_step", "rows": rows,
-                      "row_tile": ROW_TILE, "expected_rows": expected}
-    assert compile_cache.compile_totals()["moe_buffer_rows"] == rows
+                      "row_tile": ROW_TILE, "expected_rows": expected,
+                      "first_rows": first_buffer_rows(t, 3, 4, 16)}
+    totals = compile_cache.compile_totals()
+    assert totals["moe_buffer_rows"] == rows
+    assert totals["moe_first_buffer_rows"] == rows      # one buffer here
     live = remat_plan.hybrid_block_live_bytes
     assert live(2, 69, 32, 2, gdn=GdnSpec(2, 4, 8, 8, 4)) < \
         live(2, 69, 32, 2, gdn=GdnSpec(2, 8, 8, 8, 4))
