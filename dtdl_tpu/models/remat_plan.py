@@ -21,6 +21,8 @@ name            value                                        placed in
 ``gdn_loop``    what the delta rule's loop reads of a chunk  ops/gated_delta.py
                 (a linear-attention block)
 ``gdn_in``      the ``in_qkvz`` projection's output          GatedDeltaNet
+``kda_loop``    what the channel-wise rule's loop reads      ops/gated_delta.py
+``kda_in``      the ``in_q``, ``in_k``, ``in_v`` outputs     KimiDeltaAttention
 ==============  ===========================================  =================
 
 **The ladder.**  :data:`RUNGS` orders them by the recomputation a kept byte
@@ -38,7 +40,10 @@ A linear-attention block (Gated DeltaNet) has two rungs of its own:
 ``gdn_chunk_fwd`` kernel, or the batched matmuls of the ``jax.numpy`` path),
 filled in the ladder's first pass beside the other blocks' ``flash_out``,
 then ``gdn_in`` (the ``in_qkvz`` projection goes).  The rule's ``T`` has no
-name any more: the kernels never write it to HBM (PR 30).
+name any more: the kernels never write it to HBM (PR 30).  A Kimi Delta
+Attention block has the same two under its own names, ``kda_loop`` and
+``kda_in``; a latent-attention block keeps the attention names as a full
+block does, at its own widths (:func:`mla_residual_bytes`).
 
 **The budget** is computed, never set: the device's
 ``memory_stats()["bytes_limit"]`` (:func:`device_bytes_limit`) less
@@ -64,12 +69,13 @@ from typing import NamedTuple, Sequence
 import jax
 
 from dtdl_tpu.ops.attention import FLASH_OUT, FLASH_QKV
-from dtdl_tpu.ops.gated_delta import GDN_LOOP, stage_plan
+from dtdl_tpu.ops.gated_delta import GDN_LOOP, KDA_LOOP, stage_plan
 from dtdl_tpu.ops.grouped_matmul import held_buffer_rows
 
 ATTN_OUT = "attn_out"
 MLP_UP = "mlp_up"
 GDN_IN = "gdn_in"       # a linear-attention block's ``in_qkvz`` output
+KDA_IN = "kda_in"       # a Kimi Delta Attention block's q, k, v projections
 MOE_PLAN = "moe_plan"   # the held experts' choice and sort (a megabyte)
 
 RUNGS = ("recompute", "flash", "attn_proj", "mlp")
@@ -88,21 +94,24 @@ MARGIN = 1 / 16
 # a linear-attention block's own ladder, by the recomputation a kept byte
 # removes (measured on a v5e, PERF.md section 6, PR 29: 14 and 11 ms/GB)
 _LINEAR_RUNG_NAMES = ((), (GDN_LOOP,), (GDN_IN,))
+_KDA_RUNG_NAMES = ((), (KDA_LOOP,), (KDA_IN,))
 
 
-def saved_names(rung: int, linear: bool = False,
+def saved_names(rung: int, linear: bool | str = False,
                 held: bool = False) -> tuple[str, ...]:
     """The checkpoint names a block at ``rung`` keeps.  A linear-attention
-    block has two rungs of its own: what the delta rule's loop reads (the
-    chunk-local stage goes), the ``in_qkvz`` output (the projection goes);
-    it has no third.  A block with held experts
+    block (``linear``: True or ``"linear"`` for Gated DeltaNet, ``"kda"``
+    for Kimi Delta Attention) has two rungs of its own: what the delta
+    rule's loop reads (the chunk-local stage goes), the input projections'
+    output (they go); it has no third.  A block with held experts
     always keeps ``moe_plan``: the top-k and the sort of the assignments are
     a megabyte to keep and milliseconds to run again."""
-    names = _LINEAR_RUNG_NAMES if linear else _RUNG_NAMES
+    names = (_KDA_RUNG_NAMES if linear == "kda"
+             else _LINEAR_RUNG_NAMES if linear else _RUNG_NAMES)
     return sum(names[:rung + 1], ()) + ((MOE_PLAN,) if held else ())
 
 
-def policy(rung: int, linear: bool = False, held: bool = False):
+def policy(rung: int, linear: bool | str = False, held: bool = False):
     """The ``jax.checkpoint`` policy of a block at ``rung``; None where it
     keeps nothing (rung 0 of a dense block: the program ``remat=True``
     compiled before there was a plan)."""
@@ -126,6 +135,30 @@ def gdn_residual_bytes(batch: int, seq: int, d_model: int, gdn,
     return (padded * hv * (3 * dk + dv + chunk) * itemsize,
             batch * seq * (2 * hk * dk + 2 * hv * dv) * itemsize,
             0)
+
+
+def kda_residual_bytes(batch: int, seq: int, kda,
+                       itemsize: int) -> tuple[int, int, int]:
+    """:func:`gdn_residual_bytes` for a Kimi Delta Attention block (``kda``:
+    models/transformer.py's ``KdaSpec``): the loop's five inputs at the
+    channel-wise rule's chunk; the three projections' outputs."""
+    h, d = kda.heads, kda.head_dim
+    _, chunk = stage_plan(d, d, channelwise=True)
+    padded = -(-seq // chunk) * chunk * batch
+    return (padded * h * (4 * d + chunk) * itemsize,
+            batch * seq * 3 * h * d * itemsize, 0)
+
+
+def mla_residual_bytes(batch: int, seq: int, d_model: int, n_heads: int,
+                       mla, itemsize: int) -> tuple[int, int, int]:
+    """:func:`residual_bytes` for a latent-attention block (``mla``:
+    models/transformer.py's ``MlaSpec``): ``o`` at the value head and the
+    log-sum-exp; ``q``, ``k`` at the query/key head, ``v`` and the ``out``
+    projection's output; no third rung."""
+    t = batch * seq
+    qk, v = mla.nope_dim + mla.rope_dim, mla.v_dim
+    return (t * n_heads * v * itemsize + batch * n_heads * seq * 4,
+            t * (n_heads * (2 * qk + v) + d_model) * itemsize, 0)
 
 
 def residual_bytes(batch: int, seq: int, d_model: int, n_heads: int,
@@ -212,7 +245,8 @@ def model_held_bytes(batch: int, seq: int, d_model: int, d_ff: int,
 
 def hybrid_block_live_bytes(batch: int, seq: int, d_model: int,
                             itemsize: int, attn_width: int = 0,
-                            gdn=None, held=None, d_ff: int = 0) -> int:
+                            gdn=None, held=None, d_ff: int = 0,
+                            kda=None, mla=None) -> int:
     """One hybrid block's live set (models/transformer.py:BlockSpec) while
     it is recomputed and differentiated.
 
@@ -233,7 +267,13 @@ def hybrid_block_live_bytes(batch: int, seq: int, d_model: int,
     buffer's rows (``held_buffer_rows``), not the first buffer's that a
     layer computes where its routing fits: the full branch can run, both
     branches are in the one executable, and the compiler allocates for the
-    larger.  A dense MLP: six
+    larger.  A Kimi Delta Attention block (``kda``, a ``KdaSpec``): the
+    three projections, the conv's input and output, q, k and the decay
+    ``g`` in float32 a key channel, the gate, v and the loop's five inputs
+    in the compute dtype, ``o`` in float32 and the state at every chunk,
+    and half as much again.  A latent-attention block (``mla``: ``(heads,
+    MlaSpec)``): q, the assembled k, v, ``kv_b``'s output and ``o``, each
+    with its cotangent.  A dense MLP: six
     ``d_ff`` values.  How the step's estimate stands against the v5e
     compiler's total for the Qwen3-Next cell is in PERF.md section 4."""
     t = batch * seq
@@ -254,6 +294,24 @@ def hybrid_block_live_bytes(batch: int, seq: int, d_model: int,
         if path == "jnp":
             values += 3 * t * hv * chunk * 4            # decay, A, T
         live += values + values // 2
+    if kda:
+        h, d = kda.heads, kda.head_dim
+        path, chunk = stage_plan(d, d, channelwise=True)
+        states = -(-seq // chunk) * batch * h * d * d * 4
+        values = (3 * t * h * d * itemsize              # in_q, in_k, in_v
+                  + 2 * t * 3 * h * d * itemsize        # the conv, in and out
+                  + 3 * t * h * d * 4                   # q, k, g
+                  + t * h * d * itemsize                # the gate
+                  + t * h * (5 * d + chunk) * itemsize  # v, the loop's five
+                  + t * h * d * 4 + states)             # o, the states
+        if path == "jnp":
+            values += 3 * t * h * chunk * 4
+        live += values + values // 2
+    if mla:
+        heads, sizes = mla
+        qk = sizes.nope_dim + sizes.rope_dim
+        live += 2 * t * heads * (2 * qk + sizes.nope_dim
+                                 + 3 * sizes.v_dim) * itemsize
     if held:
         rows, _ = held_buffer_rows(t, held.top_k, held.held,
                                    held.router_width)

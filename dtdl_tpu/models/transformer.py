@@ -39,7 +39,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from dtdl_tpu.models import remat_plan
 from dtdl_tpu.ops.attention import flash_attention, mha_reference
-from dtdl_tpu.ops.gated_delta import gated_delta_rule, stage_plan
+from dtdl_tpu.ops.gated_delta import gated_delta_rule, kda_rule, stage_plan
 from dtdl_tpu.ops.grouped_matmul import (
     ROW_TILE, first_buffer_rows, grouped_matmul, held_buffer_rows, rows_of,
     weighted_rows_sum)
@@ -49,6 +49,7 @@ from dtdl_tpu.quant import (QuantDenseGeneral, canon_kv_dtype, kv_quantize,
                             kv_scale_dtype, weight_dtypes)
 from dtdl_tpu.runtime.compile_cache import (record_expert_buffer,
                                             record_gdn_path,
+                                            record_kda_path,
                                             record_remat_plan)
 
 Dtype = Any
@@ -1057,6 +1058,148 @@ class GatedDeltaNet(nn.Module):
             name="out")(o.astype(self.dtype))
 
 
+class KimiDeltaAttention(nn.Module):
+    """Kimi Delta Attention (arXiv:2510.26692): a delta rule whose decay is
+    a vector over the key channels.  ``in_q``, ``in_k``, ``in_v`` project to
+    ``heads x head_dim`` each, pass a causal depthwise conv (no bias) and
+    SiLU; q and k are L2-normalised over the head, q scaled by ``head_dim **
+    -0.5``; ``beta = sigmoid(in_b x)`` a head; ``g = -exp(A_log) *
+    softplus(f_b(f_a x) + dt_bias)`` a key channel in float32 (``A_log`` a
+    head, ``dt_bias`` a channel, the two-step projection through
+    ``gate_rank``).  The rule is ops/gated_delta.py:kda_rule; its output
+    passes an RMSNorm over the head, times ``sigmoid(g_b(g_a x))`` (``g_b``
+    with a bias), and ``out``.  Trains only, as :class:`GatedDeltaNet`."""
+    heads: int
+    head_dim: int
+    gate_rank: int
+    conv_width: int = 4
+    eps: float = 1e-6             # inside the q/k L2 normalisation
+    norm_eps: float = 1e-6
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, d_model = x.shape
+        h, d = self.heads, self.head_dim
+
+        def heads_of(name, dtype, bias=False):
+            return nn.DenseGeneral(
+                features=(h, d), axis=-1, use_bias=bias, dtype=dtype,
+                kernel_init=_part(nn.initializers.lecun_normal(),
+                                  "embed", "heads", "head_dim"),
+                name=name)
+
+        def dense(name, width, dtype):
+            return nn.Dense(width, use_bias=False, dtype=dtype,
+                            kernel_init=_part(nn.initializers.lecun_normal(),
+                                              "embed", None), name=name)
+
+        q, k, v = (checkpoint_name(heads_of(name, self.dtype)(x),
+                                   remat_plan.KDA_IN)
+                   for name in ("in_q", "in_k", "in_v"))
+        mixed = jnp.concatenate(
+            [t.reshape(b, s, h * d) for t in (q, k, v)], axis=-1)
+        mixed = nn.silu(DepthwiseConv(self.conv_width, self.dtype,
+                                      name="conv")(mixed))
+        q, k, v = (t.reshape(b, s, h, d) for t in jnp.split(mixed, 3, axis=-1))
+        q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+
+        def l2(t):
+            return t * jax.lax.rsqrt(
+                jnp.sum(t * t, axis=-1, keepdims=True) + self.eps)
+
+        q, k = l2(q) * d ** -0.5, l2(k)
+        x32 = x.astype(jnp.float32)
+        beta = jax.nn.sigmoid(dense("in_b", h, jnp.float32)(x32))
+        a_log = self.param("A_log", _part(nn.initializers.zeros, "heads"),
+                           (h,))
+        dt_bias = self.param(
+            "dt_bias", _part(nn.initializers.ones, "heads", "head_dim"),
+            (h, d))
+        low = dense("f_a", self.gate_rank, jnp.float32)(x32)
+        g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+            heads_of("f_b", jnp.float32)(low) + dt_bias)
+        step_name = remat_plan.traced_step_name()
+        if step_name is not None:
+            record_kda_path(step_name, *stage_plan(d, d, channelwise=True),
+                            shapes=(b, s, h, d, d))
+        o = kda_rule(                                    # [B, S, H, D] f32
+            q, k, v, g, beta,
+            operand_dtype=None if self.dtype == jnp.float32 else self.dtype)
+        o = RMSNorm(eps=self.norm_eps, dtype=jnp.float32,
+                    axis_name="head_dim", name="norm")(o)
+        gate = heads_of("g_b", self.dtype, bias=True)(
+            dense("g_a", self.gate_rank, self.dtype)(x))
+        o = o * jax.nn.sigmoid(gate.astype(jnp.float32))
+        return nn.DenseGeneral(
+            features=d_model, axis=(-2, -1), use_bias=False,
+            dtype=self.dtype,
+            kernel_init=_part(nn.initializers.lecun_normal(),
+                              "heads", "head_dim", "embed"),
+            name="out")(o.astype(self.dtype))
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention without a rotation (MLA, NoPE): ``q``
+    projects to ``heads x (nope_dim + rope_dim)``; ``kv_a`` to ``kv_rank +
+    rope_dim``, of which the first ``kv_rank`` pass an RMSNorm
+    (``kv_norm``) and ``kv_b`` to each head's ``nope_dim`` of key and
+    ``v_dim`` of value, and the last ``rope_dim`` are one key part shared by
+    all heads (not rotated); causal softmax attention at the query/key size
+    ``nope_dim + rope_dim`` with values of ``v_dim`` (the flash kernels take
+    the two sizes), then ``out``.  Trains only: in training there is no
+    cache, so what is latent about it is the shape."""
+    n_heads: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    kv_rank: int
+    norm_eps: float = 1e-6
+    attn_impl: str = "flash"
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, d_model = x.shape
+        h = self.n_heads
+
+        def heads_of(name, width):
+            return nn.DenseGeneral(
+                features=(h, width), axis=-1, use_bias=False,
+                dtype=self.dtype,
+                kernel_init=_part(nn.initializers.lecun_normal(),
+                                  "embed", "heads", "head_dim"),
+                name=name)
+
+        q = heads_of("q", self.nope_dim + self.rope_dim)(x)
+        latent = nn.Dense(self.kv_rank + self.rope_dim, use_bias=False,
+                          dtype=self.dtype,
+                          kernel_init=_part(nn.initializers.lecun_normal(),
+                                            "embed", None), name="kv_a")(x)
+        shared = latent[..., self.kv_rank:]              # [B, S, rope_dim]
+        kv = heads_of("kv_b", self.nope_dim + self.v_dim)(
+            RMSNorm(eps=self.norm_eps, dtype=self.dtype, axis_name=None,
+                    name="kv_norm")(latent[..., :self.kv_rank]))
+        k = jnp.concatenate(
+            [kv[..., :self.nope_dim],
+             jnp.broadcast_to(shared[:, :, None, :],
+                              (b, s, h, self.rope_dim))], axis=-1)
+        v = kv[..., self.nope_dim:]
+        # [B, S, H, D] -> [B, H, S, D]
+        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+        if self.attn_impl == "flash":
+            o = flash_attention(q, k, v, causal=True)
+        else:
+            o = mha_reference(q, k, v, causal=True).astype(self.dtype)
+        out = nn.DenseGeneral(
+            features=d_model, axis=(-2, -1), use_bias=False,
+            dtype=self.dtype,
+            kernel_init=_part(nn.initializers.lecun_normal(),
+                              "heads", "head_dim", "embed"),
+            name="out")(o.transpose(0, 2, 1, 3))
+        return checkpoint_name(out, remat_plan.ATTN_OUT)
+
+
 class _RoutedExperts(nn.Module):
     """The held experts' weights in the compute dtype, ``(wi, wg, wo)``,
     made once a layer (``HeldExperts`` plans the buffer and chooses its
@@ -1144,9 +1287,11 @@ _first_or_full.defvjp(_first_or_full_fwd, _first_or_full_bwd)
 
 
 class _SharedExpert(nn.Module):
-    """``sigmoid(x w_s) * SwiGLU(x)``: the expert every token passes."""
+    """``sigmoid(x w_s) * SwiGLU(x)``: the expert every token passes;
+    without ``gated`` the plain ``SwiGLU(x)``."""
     d_ff: int
     dtype: Dtype = jnp.bfloat16
+    gated: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -1157,6 +1302,8 @@ class _SharedExpert(nn.Module):
         h = nn.silu(dense(self.d_ff, "wg", ("embed", "mlp"))(x)) * \
             dense(self.d_ff, "wi", ("embed", "mlp"))(x)
         y = dense(x.shape[-1], "wo", ("mlp", "embed"))(h)
+        if not self.gated:
+            return y
         return jax.nn.sigmoid(dense(1, "gate", ("embed", None))(x)) * y
 
 
@@ -1164,8 +1311,9 @@ class HeldExperts(nn.Module):
     """One chip's share of an expert layer under expert parallelism.
 
     The router scores all ``router_width`` experts of the model (float32
-    matmul and softmax), takes the ``top_k`` largest and divides their
-    probabilities by their sum.  This chip holds experts ``first ...
+    matmul and softmax, or a sigmoid an expert: ``router_act``), takes the
+    ``top_k`` largest and divides their probabilities by their sum (times
+    ``routed_scale``).  This chip holds experts ``first ...
     first + held``: it keeps the assignments that name one of them, and
     computes ``sum_e p_e * W_down,e(silu(W_gate,e x) * W_up,e x)`` over
     those alone.  What the absent experts would add is absent (no
@@ -1209,10 +1357,19 @@ class HeldExperts(nn.Module):
     top_k: int
     d_ff: int
     shared_d_ff: int = 0
+    # the router's activation, 'softmax' over all experts or 'sigmoid' an
+    # expert; the chosen scores are divided by their sum either way, then
+    # times ``routed_scale``; ``shared_gate``: the shared expert's sigmoid
+    # gate (a model hyperparameter: some have none)
+    router_act: str = "softmax"
+    routed_scale: float = 1.0
+    shared_gate: bool = True
     dtype: Dtype = jnp.bfloat16
 
     @nn.compact
     def __call__(self, x):
+        if self.router_act not in ("softmax", "sigmoid"):
+            raise ValueError(f"router activation {self.router_act!r}")
         if not (0 <= self.first
                 and self.first + self.held <= self.router_width
                 and 1 <= self.top_k <= self.router_width):
@@ -1227,10 +1384,13 @@ class HeldExperts(nn.Module):
                           kernel_init=_part(nn.initializers.lecun_normal(),
                                             "embed", "expert"),
                           name="router")(xf.astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)
+        probs = jax.nn.softmax(logits, axis=-1) \
+            if self.router_act == "softmax" else jax.nn.sigmoid(logits)
         gates, idx = checkpoint_name(jax.lax.top_k(probs, k),
                                      remat_plan.MOE_PLAN)    # [tokens, k]
         gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        if self.routed_scale != 1.0:
+            gates = gates * self.routed_scale
 
         shapes = (tokens, k, held, self.router_width)
         n_rows, expected = held_buffer_rows(*shapes)
@@ -1285,6 +1445,7 @@ class HeldExperts(nn.Module):
         out = out.astype(self.dtype)
         if self.shared_d_ff:
             out = out + _SharedExpert(self.shared_d_ff, self.dtype,
+                                      gated=self.shared_gate,
                                       name="shared")(xf)
         return out.reshape(b, s, d_model)
 
@@ -1298,14 +1459,33 @@ class GdnSpec(NamedTuple):
     conv_width: int
 
 
+class KdaSpec(NamedTuple):
+    """``KimiDeltaAttention``'s sizes."""
+    heads: int
+    head_dim: int
+    gate_rank: int
+    conv_width: int
+
+
+class MlaSpec(NamedTuple):
+    """``LatentAttention``'s sizes."""
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    kv_rank: int
+
+
 class HeldSpec(NamedTuple):
-    """``HeldExperts``' sizes."""
+    """``HeldExperts``' sizes and the router's hyperparameters."""
     router_width: int
     first: int
     held: int
     top_k: int
     d_ff: int
     shared_d_ff: int
+    router_act: str = "softmax"
+    routed_scale: float = 1.0
+    shared_gate: bool = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1313,7 +1493,7 @@ class BlockSpec:
     """What a block is beyond the dense one (``TransformerLM``'s keywords,
     gathered so that ``Block`` stays one module): the token mixer's kind
     and sizes, the norms' centring, and the held experts' layer."""
-    kind: str = "full"                 # 'full' | 'linear'
+    kind: str = "full"                 # 'full' | 'linear' | 'kda' | 'mla'
     n_kv_heads: int = 0
     rope_dims: int = 0
     qk_norm: bool = False
@@ -1321,6 +1501,9 @@ class BlockSpec:
     norm_zero_centered: bool = False
     gdn: Any = None                    # a GdnSpec on a linear layer
     held: Any = None                   # a HeldSpec where experts are held
+    kda: Any = None                    # a KdaSpec on a 'kda' layer
+    mla: Any = None                    # an MlaSpec on an 'mla' layer
+    norm_eps: float = 1e-6             # of the block's norms
 
 
 class Block(nn.Module):
@@ -1367,19 +1550,28 @@ class Block(nn.Module):
         spec = self.spec
 
         def norm(name):
-            return RMSNorm(dtype=self.dtype,
+            return RMSNorm(eps=spec.norm_eps, dtype=self.dtype,
                            zero_centered=spec.norm_zero_centered, name=name)
 
         h = norm("ln_attn")(x)
+        if spec.kind in ("linear", "kda", "mla") and decode:
+            raise NotImplementedError(
+                "a model with linear-attention or latent-attention layers "
+                "trains only: decoding needs the delta rule's recurrent "
+                "state and the conv's tail in the cache (GatedDeltaNet and "
+                "KimiDeltaAttention have neither), or the latent row a "
+                "token (LatentAttention), and a step that advances them")
         if spec.kind == "linear":
-            if decode:
-                raise NotImplementedError(
-                    "a model with linear-attention layers trains only: "
-                    "decoding needs the delta rule's recurrent state and "
-                    "the conv's tail in the cache, and a step that "
-                    "advances them (GatedDeltaNet has neither)")
             x = x + GatedDeltaNet(**spec.gdn._asdict(), dtype=self.dtype,
                                   name="gdn")(h)
+        elif spec.kind == "kda":
+            x = x + KimiDeltaAttention(
+                **spec.kda._asdict(), norm_eps=spec.norm_eps,
+                dtype=self.dtype, name="kda")(h)
+        elif spec.kind == "mla":
+            x = x + LatentAttention(
+                self.n_heads, **spec.mla._asdict(), norm_eps=spec.norm_eps,
+                attn_impl=self.attn_impl, dtype=self.dtype, name="attn")(h)
         elif spec.kind == "full":
             x = x + Attention(
                 self.n_heads, self.head_dim, self.attn_impl, self.dtype,
@@ -1398,7 +1590,7 @@ class Block(nn.Module):
 
 
 @functools.cache
-def _remat_block(rung: int, linear: bool = False, held: bool = False):
+def _remat_block(rung: int, linear: bool | str = False, held: bool = False):
     """``Block`` under ``jax.checkpoint`` with the policy of ``rung``
     (models/remat_plan.py); rung 0 is no policy, the whole forward again."""
     return nn.remat(Block, static_argnums=(),
@@ -1438,8 +1630,9 @@ class TransformerLM(nn.Module):
     paged_kernel: bool = False
     # --- hybrid architectures (all at their defaults: the model above, the
     # same parameter paths and the same compiled programs) -----------------
-    # per-layer token mixer, 'full' (softmax attention) or 'linear' (Gated
-    # DeltaNet); () = every layer 'full'
+    # per-layer token mixer: 'full' (softmax attention), 'linear' (Gated
+    # DeltaNet), 'kda' (Kimi Delta Attention) or 'mla' (latent attention
+    # without a rotation); () = every layer 'full'
     layer_kinds: tuple = ()
     n_kv_heads: int = 0           # K/V heads; 0 = n_heads
     attn_head_dim: int = 0        # stated head size; 0 = d_model // n_heads
@@ -1453,6 +1646,15 @@ class TransformerLM(nn.Module):
     gdn_key_dim: int = 0          # their head sizes,
     gdn_value_dim: int = 0
     gdn_conv: int = 4             # and the causal conv's width
+    kda_heads: int = 0            # Kimi Delta Attention: heads, their size
+    kda_head_dim: int = 0         # (key and value alike), the rank of the
+    kda_gate_rank: int = 0        # decay's and the gate's two-step
+    kda_conv: int = 4             # projections, the causal conv's width
+    mla_nope_dim: int = 0         # latent attention: a head's key part of
+    mla_rope_dim: int = 0         # its own, the part all heads share (not
+    mla_v_dim: int = 0            # rotated), the value head, the latent's
+    mla_kv_rank: int = 0          # width
+    norm_eps: float = 1e-6        # of the blocks' and the final RMSNorm
     # moe_dispatch='held' (one chip's share under expert parallelism,
     # HeldExperts): n_experts are held here, ids moe_first_expert onward, of
     # the moe_router_width the router scores; moe_top_k a token; expert width
@@ -1461,6 +1663,14 @@ class TransformerLM(nn.Module):
     moe_first_expert: int = 0
     moe_d_ff: int = 0
     moe_shared_d_ff: int = 0
+    # 'held' alone: the router's activation ('softmax' | 'sigmoid'), what
+    # the chosen, renormalised scores are multiplied by, whether the shared
+    # expert has a sigmoid gate, and how many leading layers keep the dense
+    # SwiGLU of d_ff in place of experts
+    moe_router_act: str = "softmax"
+    moe_routed_scale: float = 1.0
+    moe_shared_gate: bool = True
+    first_dense_layers: int = 0
     tie_embeddings: bool = True   # False: a head table of its own, 'head'
 
     @property
@@ -1490,18 +1700,28 @@ class TransformerLM(nn.Module):
                 router_width=self.moe_router_width,
                 first=self.moe_first_expert, held=self.n_experts,
                 top_k=self.moe_top_k, d_ff=self.moe_d_ff or self.d_ff,
-                shared_d_ff=self.moe_shared_d_ff)
+                shared_d_ff=self.moe_shared_d_ff,
+                router_act=self.moe_router_act,
+                routed_scale=self.moe_routed_scale,
+                shared_gate=self.moe_shared_gate)
         elif self.n_experts:
             raise ValueError("hybrid blocks route through "
                              "moe_dispatch='held' alone")
         gdn = GdnSpec(self.gdn_key_heads, self.gdn_value_heads,
                       self.gdn_key_dim, self.gdn_value_dim, self.gdn_conv)
+        kda = KdaSpec(self.kda_heads, self.kda_head_dim, self.kda_gate_rank,
+                      self.kda_conv)
+        mla = MlaSpec(self.mla_nope_dim, self.mla_rope_dim, self.mla_v_dim,
+                      self.mla_kv_rank)
         return [BlockSpec(kind=kind, n_kv_heads=self.n_kv_heads,
                           rope_dims=self.rope_dims, qk_norm=self.qk_norm,
                           attn_gate=self.attn_gate,
                           norm_zero_centered=self.norm_zero_centered,
                           gdn=gdn if kind == "linear" else None,
-                          held=held if moe else None)
+                          held=held if moe else None,
+                          kda=kda if kind == "kda" else None,
+                          mla=mla if kind == "mla" else None,
+                          norm_eps=self.norm_eps)
                 for kind, moe in zip(kinds, is_moe)]
 
     def cache_shapes(self, batch_size: int, per_slot_index: bool = False,
@@ -1641,17 +1861,30 @@ class TransformerLM(nn.Module):
             # rule's loop reads, a full one the attention names at its own
             # head width
             attn_width = self.n_heads * self.head_dim
-            costs = [remat_plan.residual_bytes(
-                batch, seq, self.d_model, self.n_heads, 0, itemsize,
-                attn_width=attn_width) if spec.kind == "full"
-                else remat_plan.gdn_residual_bytes(
-                    batch, seq, self.d_model, spec.gdn, itemsize)
-                for spec in specs]
+
+            def cost(spec):
+                if spec.kind == "linear":
+                    return remat_plan.gdn_residual_bytes(
+                        batch, seq, self.d_model, spec.gdn, itemsize)
+                if spec.kind == "kda":
+                    return remat_plan.kda_residual_bytes(
+                        batch, seq, spec.kda, itemsize)
+                if spec.kind == "mla":
+                    return remat_plan.mla_residual_bytes(
+                        batch, seq, self.d_model, self.n_heads, spec.mla,
+                        itemsize)
+                return remat_plan.residual_bytes(
+                    batch, seq, self.d_model, self.n_heads, 0, itemsize,
+                    attn_width=attn_width)
+
+            costs = [cost(spec) for spec in specs]
             live = max(remat_plan.hybrid_block_live_bytes(
                 batch, seq, self.d_model, itemsize,
                 attn_width=attn_width if spec.kind == "full" else 0,
                 gdn=spec.gdn, held=spec.held,
-                d_ff=0 if spec.held else self.d_ff) for spec in specs)
+                d_ff=0 if spec.held else self.d_ff, kda=spec.kda,
+                mla=(self.n_heads, spec.mla) if spec.mla else None)
+                for spec in specs)
             held = remat_plan.model_held_bytes(
                 batch, seq, self.d_model, 0, self.n_layers, vocab,
                 param_bytes, itemsize, block_live_bytes=live)
@@ -1680,9 +1913,9 @@ class TransformerLM(nn.Module):
             (self.vocab_size, self.d_model))
         if decode and (self.hybrid or not self.tie_embeddings):
             raise NotImplementedError(
-                "decode=True on a hybrid model (linear-attention layers, "
-                "grouped-query or gated attention, held experts, an untied "
-                "head): it trains only; serving it needs a recurrent state "
+                "decode=True on a hybrid model (linear-attention or latent-"
+                "attention layers, grouped-query or gated attention, held "
+                "experts, an untied head): it trains only; serving it needs a recurrent state "
                 "beside the K/V pages and K/V heads in the cached attention")
         # the model's own two ops outside any flax submodule carry a scope
         # of their own (obs/trace.py:DEVICE_SCOPES), or a device trace can
@@ -1704,6 +1937,7 @@ class TransformerLM(nn.Module):
         # would also trace the `decode` flag into a tracer (remat treats
         # every call arg as dynamic) — plain blocks for decode
         is_moe = [self.n_experts > 0 and (i + 1) % self.moe_every == 0
+                  and i >= self.first_dense_layers
                   for i in range(self.n_layers)]
         specs = self.block_specs(is_moe)
         rungs = None
@@ -1713,8 +1947,10 @@ class TransformerLM(nn.Module):
         for i, moe in enumerate(is_moe):
             block_cls = Block if rungs is None else (
                 _remat_block(rungs[i]) if specs[i] is None
-                else _remat_block(rungs[i], specs[i].kind == "linear",
-                                  bool(specs[i].held)))
+                else _remat_block(
+                    rungs[i],
+                    specs[i].kind if specs[i].kind in ("linear", "kda")
+                    else False, bool(specs[i].held)))
             block = block_cls(
                 self.n_heads, self.head_dim, self.d_ff,
                 n_experts=self.n_experts if moe else 0,
@@ -1732,7 +1968,7 @@ class TransformerLM(nn.Module):
             x = block(x, cos, sin, decode=True) if decode \
                 else block(x, cos, sin)
 
-        x = RMSNorm(dtype=self.dtype,
+        x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
                     zero_centered=self.norm_zero_centered, name="ln_f")(x)
         if not self.tie_embeddings:
             # a table of its own; declared before ``return_hidden`` returns

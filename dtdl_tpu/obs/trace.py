@@ -171,12 +171,14 @@ EVENT_CATALOG = frozenset({
 # head matmul runs inside the chunked loss, tile by tile, and so under
 # ``loss``.
 # ``gdn`` wraps the gated delta rule itself (ops/gated_delta.py: the
-# chunk-local stage, kernels or batched matmuls, and the scan over chunks), ``moe_dispatch`` the held
+# chunk-local stage, kernels or batched matmuls, and the scan over chunks),
+# ``kda`` the rule whose decay is a vector over the key channels (the same
+# file's ``kda_rule``, the same two parts), ``moe_dispatch`` the held
 # experts' routing plan, gather and weighted scatter-add
 # (models/transformer.py:HeldExperts), ``gate`` the attention's sigmoid
 # output gate (under ``attn``: the component ``attn_gate``).
 DEVICE_SCOPES = frozenset({"embed", "head", "loss", "grad_sync", "update",
-                           "guard", "gdn", "moe_dispatch", "gate"})
+                           "guard", "gdn", "kda", "moe_dispatch", "gate"})
 
 # scopes a function enters under a name that is another catalogue's, and
 # that map as there: the held experts' grouped SwiGLU runs outside its flax
@@ -192,14 +194,24 @@ MODULE_SCOPES = {
     "ln_attn": "norm", "ln_mlp": "norm", "ln_f": "norm",
 }
 _ATTN_PROJECTIONS = frozenset({"q", "k", "v", "out"})
+# latent attention's (``kv_a``, ``kv_b``: its two steps to keys and values)
+_MLA_PROJECTIONS = frozenset({"q", "kv_a", "kv_b", "out"})
 # the sub-modules of the hybrid blocks, by the module they sit in: a Gated
 # DeltaNet layer (flax scope ``gdn``, the same word as the delta rule's own
 # scope inside it) and the held experts' layer (``moe``)
 _GDN_MODULES = {"in_qkvz": "gdn_proj", "in_ba": "gdn_proj",
                 "out": "gdn_proj", "conv": "gdn_conv", "norm": "gdn_other"}
+# a Kimi Delta Attention layer (flax scope ``kda``, and the rule's own
+# ``kda`` inside it, as above)
+_KDA_MODULES = dict(
+    {name: "kda_proj" for name in ("in_q", "in_k", "in_v", "in_b", "f_a",
+                                   "f_b", "g_a", "g_b", "out")},
+    conv="kda_conv", norm="kda_other")
+_RULE_MODULES = {"gdn": _GDN_MODULES, "kda": _KDA_MODULES}
 _MOE_MODULES = {"router": "moe_router", "shared": "moe_shared",
                 "experts": "moe_experts"}
-_ATTN_OTHER = {"gate": "attn_gate", "q_norm": "norm", "k_norm": "norm"}
+_ATTN_OTHER = {"gate": "attn_gate", "q_norm": "norm", "k_norm": "norm",
+               "kv_norm": "norm"}
 
 # pallas_call ``name=`` -> component.  On the chip the kernel's HLO
 # instruction takes this name (``%flash_fwd.<n> = ... custom-call(...)
@@ -210,6 +222,7 @@ KERNEL_NAMES = {
     "paged_attn": "paged_attn",
     "moe_gmm": "moe_gmm", "moe_tgmm": "moe_gmm",
     "gdn_chunk_fwd": "gdn", "gdn_chunk_bwd": "gdn",
+    "kda_chunk_fwd": "kda", "kda_chunk_bwd": "kda",
 }
 
 # names of the traced step functions of train/step.py: ``jit(<name>)`` in
@@ -231,10 +244,12 @@ def device_component(name_stack: str):
 
     ``component`` is a member of :data:`DEVICE_SCOPES`, a value of
     :data:`MODULE_SCOPES` or :data:`KERNEL_NAMES`, ``attn_proj`` for
-    ``attn/{q,k,v,out}``, ``attn_gate`` for ``attn/gate``; under a Gated
-    DeltaNet module ``gdn_proj`` (``gdn/{in_qkvz,in_ba,out}``), ``gdn_conv``,
-    ``gdn`` for the delta rule itself (``gdn/gdn``) and ``gdn_other`` for
-    the rest; under the held experts' ``moe`` the kernels' ``moe_gmm``,
+    ``attn/{q,k,v,out,kv_a,kv_b}``, ``attn_gate`` for ``attn/gate``; under
+    a Gated DeltaNet module ``gdn_proj`` (``gdn/{in_qkvz,in_ba,out}``),
+    ``gdn_conv``, ``gdn`` for the delta rule itself (``gdn/gdn``) and
+    ``gdn_other`` for the rest; under a Kimi Delta Attention module the same
+    four with ``kda`` (``kda_proj`` for its nine projections); under the
+    held experts' ``moe`` the kernels' ``moe_gmm``,
     ``moe_dispatch``, ``moe_router``, ``moe_shared``, ``moe_experts``; or
     None where no catalogued scope is on the stack.  ``pass`` is ``update`` for what follows the backward pass
     (``grad_sync``, ``update``, ``guard``); else ``recompute`` under
@@ -270,16 +285,16 @@ def device_component(name_stack: str):
         if name == "attn":
             if kernels:
                 component = kernels[0]
-            elif rest and rest[0] in _ATTN_PROJECTIONS:
+            elif rest and rest[0] in _ATTN_PROJECTIONS | _MLA_PROJECTIONS:
                 component = "attn_proj"
             elif rest and rest[0] in _ATTN_OTHER:
                 component = _ATTN_OTHER[rest[0]]
-        elif name == "gdn":
-            # the module's scope; the delta rule's own ``gdn`` inside it
-            # is the component ``gdn``
+        elif name in _RULE_MODULES:
+            # the module's scope; the delta rule's own scope of the same
+            # name inside it is the component ``gdn`` / ``kda``
             inner = rest[0] if rest else None
-            component = ("gdn" if inner == "gdn"
-                         else _GDN_MODULES.get(inner, "gdn_other"))
+            component = (name if inner == name else
+                         _RULE_MODULES[name].get(inner, name + "_other"))
         elif name == "moe":
             if kernels:
                 component = kernels[0]

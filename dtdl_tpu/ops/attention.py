@@ -198,7 +198,12 @@ def _build_block_table():
     never call sites.
     """
     table = {}
-    for hd in (16, 32, 64, 128):
+    # 192: latent attention's query/key head (128 + 64) beside a value head
+    # of 128.  On a v5e at 2 x 32 heads x 4,095 positions (PERF.md section 6,
+    # PR 33) 1024 x 1024 ran forward + backward in 17.0 ms, 512 x 1024 17.9,
+    # 1024 x 512 19.7, 512 x 512 19.2, and the head zero-padded to 256 17.8;
+    # 2048 x 1024 does not fit VMEM
+    for hd in (16, 32, 64, 128, 192):
         for causal in (False, True):
             for seq in _SEQ_BUCKETS:
                 table[(hd, seq, causal)] = ((seq, seq) if seq <= 512
@@ -364,7 +369,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
 
 def _fwd(q, k, v, tabs, scale, causal, block_q, block_k):
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[2]
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     rope = tabs is not None
@@ -376,7 +381,7 @@ def _fwd(q, k, v, tabs, scale, causal, block_q, block_k):
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_k, d), kmap),
-        pl.BlockSpec((1, block_k, d), kmap),
+        pl.BlockSpec((1, block_k, dv), kmap),
     ]
     operands = (q, k, v)
     if rope:
@@ -394,14 +399,14 @@ def _fwd(q, k, v, tabs, scale, causal, block_q, block_k):
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            _sds((bh, sq, d), q.dtype, _vma_of(q, k, v)),
+            _sds((bh, sq, dv), q.dtype, _vma_of(q, k, v)),
             _sds((bh, 1, sq), jnp.float32, _vma_of(q, k, v)),
         ],
-        scratch_shapes=(_scratch(block_q, d)
+        scratch_shapes=(_scratch(block_q, dv)
                         + ([_vmem((block_q, d), q.dtype)] if rope else [])),
         interpret=_use_interpret(),
         **_pallas_kwargs(),
@@ -566,7 +571,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 def _bwd(scale, causal, block_q, block_k, res, do_4d, tabs=None):
     q, k, v, o, lse = res
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[2]
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     rope = tabs is not None
@@ -579,8 +584,8 @@ def _bwd(scale, causal, block_q, block_k, res, do_4d, tabs=None):
     in_specs_dq = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_k, d), kmap),
-        pl.BlockSpec((1, block_k, d), kmap),
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        pl.BlockSpec((1, block_k, dv), kmap),
+        pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
     ]
@@ -621,8 +626,8 @@ def _bwd(scale, causal, block_q, block_k, res, do_4d, tabs=None):
     in_specs_dkv = [
         pl.BlockSpec((1, block_q, d), qmap),
         pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        pl.BlockSpec((1, block_q, d), qmap),
+        pl.BlockSpec((1, block_k, dv), lambda b, j, i: (b, j, 0)),
+        pl.BlockSpec((1, block_q, dv), qmap),
         pl.BlockSpec((1, 1, block_q), _lse_map),
         pl.BlockSpec((1, 1, block_q), _lse_map),
     ]
@@ -644,13 +649,13 @@ def _bwd(scale, causal, block_q, block_k, res, do_4d, tabs=None):
         in_specs=in_specs_dkv,
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
             _sds((bh, sk, d), k.dtype, _vma_of(q, k, v, do)),
-            _sds((bh, sk, d), v.dtype, _vma_of(q, k, v, do)),
+            _sds((bh, sk, dv), v.dtype, _vma_of(q, k, v, do)),
         ],
-        scratch_shapes=([_scratch(block_k, d)[2], _scratch(block_k, d)[2]]
+        scratch_shapes=([_scratch(block_k, d)[2], _scratch(block_k, dv)[2]]
                         + ([_vmem((block_k, d), k.dtype)] if rope else [])),
         interpret=_use_interpret(),
         **_pallas_kwargs(),
@@ -733,6 +738,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     rope=None, rope_positions=None):
     """Flash attention over [batch, heads, seq, head_dim] tensors.
 
+    ``v`` may have a head size of its own (latent attention: query/key 192,
+    value 128): scores run over the query/key size, the output, the
+    accumulator and ``dv`` at the value's.  ``scale`` defaults to the
+    query/key size's ``1 / sqrt``; the block table is keyed by that size.
+
     Differentiable (custom VJP, recompute-based backward); O(seq) memory.
     On the CPU platform the same kernel code runs under the Pallas
     interpreter (tests).
@@ -755,7 +765,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     case: positions 0..seq-1 for both).
     """
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if block_q is None or block_k is None:
@@ -766,7 +776,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     block_k = _legal_block(sk, block_k)
     qf = q.reshape(b * h, sq, d)
     kf = k.reshape(b * h, sk, d)
-    vf = v.reshape(b * h, sk, d)
+    vf = v.reshape(b * h, sk, dv)
     if rope is None:
         o = _flash(qf, kf, vf, scale, causal, block_q, block_k)
     else:
@@ -791,4 +801,4 @@ def flash_attention(q, k, v, *, causal: bool = True,
         kc, ks = _rope_rows(cos, sin, pos_k)
         o = _flash_rope(qf, kf, vf, qc, qs, kc, ks, scale, causal,
                         block_q, block_k)
-    return o.reshape(b, h, sq, d)
+    return o.reshape(b, h, sq, dv)

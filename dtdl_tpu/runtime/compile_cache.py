@@ -39,7 +39,10 @@ and ``moe_buffer_rows``, ``moe_first_buffer_rows``, ``moe_row_tile``,
 A linear-attention layer (``models/transformer.py:GatedDeltaNet``) adds which
 implementation of the delta rule's chunk-local stage its shapes chose
 (``ops/gated_delta.py:stage_plan``): :func:`record_gdn_path`,
-:func:`gdn_paths`, and ``gdn_kernel_calls``, ``gdn_jnp_calls`` in the totals.
+:func:`gdn_paths`, and ``gdn_kernel_calls``, ``gdn_jnp_calls`` in the totals;
+a Kimi Delta Attention layer the same for the channel-wise rule
+(:func:`record_kda_path`, :func:`kda_paths`, ``kda_kernel_calls``,
+``kda_jnp_calls``).
 """
 
 from __future__ import annotations
@@ -79,6 +82,7 @@ _ROWS: list[CompileRow] = []
 _PLANS: list = []       # models.remat_plan.RematPlan, one a traced step
 _EXPERT_BUFFERS: list = []      # one a held-experts layer a traced step
 _GDN_PATHS: list = []           # one a call of the delta rule a traced step
+_KDA_PATHS: list = []           # one a call of the channel-wise rule
 _listening = False
 
 
@@ -140,6 +144,20 @@ def gdn_paths() -> list:
     return list(_GDN_PATHS)
 
 
+def record_kda_path(fun_name: str, path: str, chunk: int,
+                    shapes: tuple) -> None:
+    """:func:`record_gdn_path` for the channel-wise rule
+    (ops/gated_delta.py:kda_rule): ``shapes`` is the call's ``(rows,
+    positions, heads, key dim, value dim)``."""
+    _KDA_PATHS.append({"fun_name": fun_name, "path": path,
+                       "shapes": tuple(shapes), "chunk": chunk})
+
+
+def kda_paths() -> list:
+    """The channel-wise rule's calls so far, oldest first (a copy)."""
+    return list(_KDA_PATHS)
+
+
 def expert_buffers() -> list:
     """The expert buffers so far, oldest first (a copy)."""
     return list(_EXPERT_BUFFERS)
@@ -193,7 +211,8 @@ def compile_totals() -> dict:
         totals["moe_first_buffer_rows"] = newest["first_rows"]
         totals["moe_row_tile"] = newest["row_tile"]
         totals["moe_expected_rows"] = newest["expected_rows"]
-    for path in sorted({r["path"] for r in _GDN_PATHS}):
-        totals[f"gdn_{path}_calls"] = sum(
-            r["path"] == path for r in _GDN_PATHS)
+    for rule, rows in (("gdn", _GDN_PATHS), ("kda", _KDA_PATHS)):
+        for path in sorted({r["path"] for r in rows}):
+            totals[f"{rule}_{path}_calls"] = sum(
+                r["path"] == path for r in rows)
     return totals

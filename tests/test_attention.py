@@ -57,6 +57,38 @@ def test_flash_grads_match_dense():
                                    atol=5e-5, rtol=1e-4)
 
 
+@pytest.mark.parametrize("dims, causal", [
+    ((192, 128), True), ((192, 128), False), ((24, 16), True),
+    ((16, 40), True)])
+def test_flash_takes_a_value_head_size_of_its_own(dims, causal):
+    """Latent attention's shapes: scores over the query/key size, the
+    output, the accumulator and ``dv`` at the value's, over a ragged
+    multi-block grid; values and all three gradients against the dense
+    reference, the scale the query/key size's."""
+    d, dv = dims
+    q, k = (_rand((1, 2, 300, d), s) for s in range(2))
+    v = _rand((1, 2, 300, dv), 2)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=128,
+                               block_k=128)
+
+    def dense(q, k, v):
+        return mha_reference(q, k, v, causal=causal)
+
+    with jax.default_matmul_precision("highest"):
+        out, ref = flash(q, k, v), dense(q, k, v)
+        g_flash = jax.grad(_sq_loss(flash), (0, 1, 2))(q, k, v)
+        g_ref = jax.grad(_sq_loss(dense), (0, 1, 2))(q, k, v)
+    assert out.shape == (1, 2, 300, dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=5e-6, rtol=1e-5)
+    for a, b in zip(g_flash, g_ref):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-4, rtol=1e-4)
+
+
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_cross_attention(causal):
     """q shorter than k/v; causal must be bottom-aligned like the oracle.
@@ -288,9 +320,10 @@ def test_block_table_covers_presets():
             assert entry is not None, (size, causal)
             assert resolve_blocks(cfg.head_dim, cfg.max_seq,
                                   causal=causal, strict=True) == entry
-    for d in (64, 128):
+    for d in (64, 128, 192):     # 192: latent attention's query/key head
         for s in (4096, 32768):
             assert block_table_entry(d, s, True) is not None
+    assert resolve_blocks(192, 4095, strict=True) == (1024, 1024)
     assert resolve_blocks(256, 999) == _BLOCK_DEFAULT
     with pytest.raises(ValueError, match="block-table"):
         resolve_blocks(256, 999, strict=True)

@@ -29,6 +29,7 @@ from dtdl_tpu.models import remat_plan
 from dtdl_tpu.models.transformer import TransformerLM
 from dtdl_tpu.obs import Observer
 from dtdl_tpu.obs.trace import (_ATTN_OTHER, _ATTN_PROJECTIONS, _GDN_MODULES,
+                                 _KDA_MODULES, _MLA_PROJECTIONS,
                                 _MOE_MODULES, DEVICE_SCOPES, KERNEL_NAMES,
                                 MODULE_SCOPES, STEP_NAMES, device_component)
 from dtdl_tpu.parallel import DataParallel, SingleDevice
@@ -287,6 +288,28 @@ STACKS = [
      "pallas_call", "moe_gmm", "backward"),
     (_BWD + "jvp(TransformerLM)/checkpoint/block_1/block_1._hybrid/moe/cond",
      "moe", "backward"),
+    # Kimi Delta Attention and latent attention (PR 33)
+    (_FWD + "block_0/block_0._hybrid/kda/kda/while/body/dot_general", "kda",
+     "forward"),
+    (_BWD + "block_0/block_0._hybrid/kda/kda/kda_chunk_bwd/pallas_call",
+     "kda", "backward"),
+    (_REMAT + "block_0/block_0._hybrid/kda/in_q/dot_general", "kda_proj",
+     "recompute"),
+    (_FWD + "block_1/block_1._hybrid/kda/f_b/dot_general", "kda_proj",
+     "forward"),
+    (_BWD + "block_1/block_1._hybrid/kda/g_a/dot_general", "kda_proj",
+     "backward"),
+    (_BWD + "block_2/block_2._hybrid/kda/conv/mul", "kda_conv", "backward"),
+    (_FWD + "block_2/block_2._hybrid/kda/norm/mul", "kda_other", "forward"),
+    (_FWD + "block_2/block_2._hybrid/kda/jit(softplus)/log1p", "kda_other",
+     "forward"),
+    (_FWD + "block_3/block_3._hybrid/attn/kv_a/dot_general", "attn_proj",
+     "forward"),
+    (_BWD + "block_3/block_3._hybrid/attn/kv_b/dot_general", "attn_proj",
+     "backward"),
+    (_FWD + "block_3/block_3._hybrid/attn/kv_norm/mul", "norm", "forward"),
+    (_FWD + "block_3/block_3._hybrid/attn/flash_fwd/pallas_call", "flash",
+     "forward"),
     # the step's own scalar bookkeeping and the rope table carry no scope
     ("jit(lm_train_step)/div", None, "forward"),
     ("jit(lm_train_step)/jvp(TransformerLM)/cos", None, "forward"),
@@ -303,6 +326,7 @@ def test_device_component_maps_recorded_name_stacks(stack, component, phase):
         set(DEVICE_SCOPES) | set(MODULE_SCOPES.values())
         | set(KERNEL_NAMES.values()) | {"attn_proj"}
         | set(_GDN_MODULES.values()) | set(_MOE_MODULES.values())
+        | set(_KDA_MODULES.values()) | {"kda_other"}
         | set(_ATTN_OTHER.values()))
 
 
@@ -350,10 +374,89 @@ def test_hybrid_module_scopes_are_the_models_own_modules():
         == set(_GDN_MODULES)
     assert set(linear["moe"]) == set(_MOE_MODULES)
     assert set(full["attn"]) == set(_ATTN_PROJECTIONS) | (
-        set(_ATTN_OTHER) - {"gate"})
+        set(_ATTN_OTHER) - {"gate", "kv_norm"})     # kv_norm: latent attention's
     assert {"head", "embed", "ln_f"} == {k for k in params
                                          if not k.startswith("block_")}
     assert "head" in DEVICE_SCOPES and "gate" in DEVICE_SCOPES
+
+
+def _passes_by_component(lowered):
+    """``{component: passes seen}`` of a lowered step, asserting on the way
+    that every ``dot_general`` and every op of a kernel lies under a
+    component, a kernel's under its own."""
+    seen = {}
+    for op, stack, _ in _op_stacks(lowered):
+        if stack.startswith("closed_call:"):
+            # jax lowers a delta rule's forward scan through a private
+            # function whose call site carries this in place of a name
+            # stack; XLA's inliner gives its ops the caller's op_name (the
+            # chip's trace attributes them: PERF.md section 5)
+            continue
+        component, phase = device_component(stack)
+        kernel = [k for k in stack.split("/") if k in KERNEL_NAMES]
+        if op == "stablehlo.dot_general" or kernel:
+            assert component is not None, (op, stack)
+        if kernel:
+            assert component == KERNEL_NAMES[kernel[0]], (op, stack)
+        if component:
+            seen.setdefault(component, set()).add(phase)
+    return seen
+
+
+def _kimi_lm(**over):
+    """A KDA layer with a dense FFN, a latent-attention layer with held
+    experts (sigmoid router, ungated shared expert)."""
+    kw = dict(vocab_size=64, d_model=16, n_layers=2, n_heads=2, d_ff=32,
+              max_seq=128, layer_kinds=("kda", "mla"), kda_heads=2,
+              kda_head_dim=8, kda_gate_rank=4, mla_nope_dim=8,
+              mla_rope_dim=4, mla_v_dim=8, mla_kv_rank=12, norm_eps=1e-5,
+              n_experts=2, moe_every=1, first_dense_layers=1,
+              moe_dispatch="held", moe_router_width=8, moe_first_expert=2,
+              moe_top_k=2, moe_d_ff=8, moe_shared_d_ff=8,
+              moe_router_act="sigmoid", moe_routed_scale=2.446,
+              moe_shared_gate=False, tie_embeddings=False)
+    return TransformerLM(**dict(kw, **over))
+
+
+def test_kimi_module_scopes_are_the_models_own_modules():
+    """The audit for the two mixers of PR 33: a Kimi Delta Attention
+    layer's sub-modules are the keys of its map, latent attention's are its
+    projections and the latent's norm, the leading dense layer is an
+    ``mlp``, and the ungated shared expert has no ``gate``."""
+    params = jax.eval_shape(_kimi_lm().init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+    kda, mla = params["block_0"], params["block_1"]
+    assert set(kda) == {"ln_attn", "kda", "ln_mlp", "mlp"}
+    assert set(mla) == {"ln_attn", "attn", "ln_mlp", "moe"}
+    assert {n for n in kda["kda"] if n not in ("A_log", "dt_bias")} \
+        == set(_KDA_MODULES)
+    assert set(mla["attn"]) == set(_MLA_PROJECTIONS) | {"kv_norm"}
+    assert _ATTN_OTHER["kv_norm"] == "norm"
+    assert set(mla["moe"]) == set(_MOE_MODULES)
+    assert set(mla["moe"]["shared"]) == {"wi", "wg", "wo"}
+    assert "kda" in DEVICE_SCOPES
+
+
+@pytest.mark.parametrize("dim", [8, 128], ids=["jnp", "kda_kernels"])
+def test_lowered_kimi_step_leaves_no_matmul_or_kernel_unscoped(dim):
+    """The same reading of the lowered train step for the Kimi blocks: every
+    ``dot_general`` and every op of a kernel (at head sizes of 128 the
+    channel-wise rule's own) lies under a component, and the new components
+    show forward, recompute and backward (rung 0 on the CPU)."""
+    model = _kimi_lm(attn_impl="flash", remat=True, dtype=jnp.bfloat16,
+                     kda_head_dim=dim)
+    tokens = jnp.zeros((2, 72), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens[:, :-1])["params"]
+    state = TrainState.create(apply_fn=model.apply, params=params,
+                              tx=optax.adamw(3e-4))
+    lowered = make_lm_train_step(SingleDevice()).lower(state,
+                                                       {"tokens": tokens})
+    seen = _passes_by_component(lowered)
+    every = {"forward", "recompute", "backward"}
+    for component in ("kda", "kda_proj", "kda_conv", "kda_other",
+                      "attn_proj", "flash", "mlp", "moe_gmm", "moe_router",
+                      "moe_shared"):
+        assert seen[component] >= every, (component, seen.get(component))
 
 
 @pytest.mark.parametrize("gdn_dim, router_width", [
@@ -376,22 +479,7 @@ def test_lowered_hybrid_step_leaves_no_matmul_or_kernel_unscoped(
                               tx=optax.adamw(3e-4))
     lowered = make_lm_train_step(SingleDevice()).lower(state,
                                                        {"tokens": tokens})
-    seen = {}
-    for op, stack, from_optax in _op_stacks(lowered):
-        if stack.startswith("closed_call:"):
-            # jax lowers the delta rule's forward scan through a private
-            # function whose call site carries this in place of a name
-            # stack; XLA's inliner gives its ops the caller's op_name (the
-            # chip's trace attributes them: PERF.md section 5)
-            continue
-        component, phase = device_component(stack)
-        kernel = [k for k in stack.split("/") if k in KERNEL_NAMES]
-        if op == "stablehlo.dot_general" or kernel:
-            assert component is not None, (op, stack)
-        if kernel:
-            assert component == KERNEL_NAMES[kernel[0]], (op, stack)
-        if component:
-            seen.setdefault(component, set()).add(phase)
+    seen = _passes_by_component(lowered)
     assert any("/moe/cond/branch_1_fun/" in stack for _, stack, _ in
                _op_stacks(lowered)) == (router_width == 32)
     every = {"forward", "recompute", "backward"}
@@ -450,7 +538,21 @@ def test_paged_pallas_call_carries_its_name():
     assert set(KERNEL_NAMES) == {"flash_fwd", "flash_bwd_dq",
                                  "flash_bwd_dkv", "paged_attn",
                                  "moe_gmm", "moe_tgmm",
-                                 "gdn_chunk_fwd", "gdn_chunk_bwd"}
+                                 "gdn_chunk_fwd", "gdn_chunk_bwd",
+                                 "kda_chunk_fwd", "kda_chunk_bwd"}
+
+
+def test_kda_pallas_calls_carry_their_names():
+    from dtdl_tpu.ops.gated_delta import kda_rule
+
+    q = jnp.zeros((1, 70, 2, 128), jnp.float32)
+    b = jnp.zeros((1, 70, 2), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v, g, b: kda_rule(q, k, v, g, b).sum(),
+        argnums=(0, 1, 2, 3, 4)))(q, q, q, q, b)
+    assert _pallas_names(jaxpr.jaxpr) == ["kda_chunk_fwd", "kda_chunk_bwd"]
+    assert KERNEL_NAMES["kda_chunk_fwd"] == KERNEL_NAMES["kda_chunk_bwd"] \
+        == "kda"
 
 
 def test_gdn_pallas_calls_carry_their_names():
@@ -511,6 +613,25 @@ def test_the_lowered_tpu_step_holds_gdn_kernels_at_kernel_sized_heads_alone(
         assert not [name for name in other if name.startswith("gdn_")]
 
 
+def test_the_lowered_tpu_step_holds_kda_kernels_at_kernel_sized_heads_alone(
+        monkeypatch):
+    """The Kimi-shaped step at a head size of 128 holds the channel-wise
+    rule's Mosaic calls under their names and the flash kernels of its
+    latent-attention layer; at a head size of 8 it holds none of the
+    rule's, and neither holds the scalar rule's."""
+    common = dict(attn_impl="flash", remat=True, dtype=jnp.bfloat16)
+    taken = _mosaic_calls(_kimi_lm(kda_head_dim=128, **common), 73,
+                          monkeypatch)
+    assert taken["kda_chunk_fwd"] >= 1 and taken["kda_chunk_bwd"] >= 1
+    assert KERNEL_NAMES.keys() >= taken.keys() >= {
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "moe_gmm", "moe_tgmm"}
+    small = _mosaic_calls(_kimi_lm(**common), 73, monkeypatch)
+    assert small["flash_fwd"] >= 1
+    for step in (taken, small):
+        assert not [name for name in step if name.startswith("gdn_")]
+    assert not [name for name in small if name.startswith("kda_")]
+
+
 # ---------------------------------------------------------------------------
 # the compile account
 # ---------------------------------------------------------------------------
@@ -557,7 +678,8 @@ def test_compile_account_rows_totals_and_single_registration():
     whole = compile_cache.compile_totals()
     # (and the newest checkpoint plan, experts' buffer and delta-rule paths, where a step of
     # this process made one: tests/test_remat_plan.py, test_qwen3_next.py)
-    assert ({k for k in whole if not k.startswith(("remat_", "moe_", "gdn_"))}
+    assert ({k for k in whole
+             if not k.startswith(("remat_", "moe_", "gdn_", "kda_"))}
             == set(compile_cache.ACCOUNT_EVENTS.values()))
     assert whole["compile_trace_s"] > 0 and whole["compile_backend_s"] > 0
     summary = Observer().summary()
@@ -586,6 +708,7 @@ def test_compile_totals_count_a_nested_trace_and_a_retrieval_once(
     monkeypatch.setattr(compile_cache, "_PLANS", [])
     monkeypatch.setattr(compile_cache, "_EXPERT_BUFFERS", [])
     monkeypatch.setattr(compile_cache, "_GDN_PATHS", [])
+    monkeypatch.setattr(compile_cache, "_KDA_PATHS", [])
     assert compile_cache.compile_totals() == {}
     monkeypatch.setattr(compile_cache, "_ROWS", rows)
     assert compile_cache.compile_totals() == {

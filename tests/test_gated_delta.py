@@ -230,3 +230,157 @@ def test_the_account_names_the_path_a_traced_step_took(dim, path, chunk):
         {"fun_name": "lm_train_step", "path": path, "chunk": chunk,
          "shapes": (2, 70, 1, 2, dim, dim)}]
     assert compile_cache.compile_totals()[f"gdn_{path}_calls"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# the channel-wise rule (Kimi Delta Attention): ``kda_rule``
+# ---------------------------------------------------------------------------
+
+KDA_JNP = dict(heads=2, dk=16, dv=24)
+KDA_KERNEL = dict(batch=1, heads=2, dk=128, dv=128)
+
+
+def _kda_inputs(length, decay, heads=2, dk=16, dv=24, seed=0, batch=2):
+    """As ``_inputs``, the log of the decay a key channel: around ``decay``
+    a token, each channel at a rate of its own."""
+    q, k, v, _, beta = _inputs(length, decay, heads, dk, dv, seed,
+                               batch=batch)
+    noise = jax.random.normal(jax.random.PRNGKey(seed + 100),
+                              (batch, length, heads, dk))
+    g = jnp.log(decay) * jax.nn.softplus(1.0 + noise) / 1.31
+    return q, k, v, g, beta
+
+
+def _takes_the_kda_kernels(fn, *args):
+    return "kda_chunk_fwd" in str(jax.make_jaxpr(fn)(*args))
+
+
+# decays from 0.999 to 0.3 a token, chunks 64 and 128 on the kernels
+@pytest.mark.parametrize("length, chunk, decay, shapes", [
+    (150, 64, 0.99, KDA_JNP), (37, 16, 0.999, KDA_JNP),
+    (130, 32, 0.3, KDA_JNP), (70, 8, 0.5, KDA_JNP),
+    (150, 64, 0.9, KDA_KERNEL), (200, None, 0.3, KDA_KERNEL),
+    (140, 128, 0.999, KDA_KERNEL), (130, 64, 0.3, KDA_KERNEL)],
+    ids=lambda x: ("kernel" if x is KDA_KERNEL else "jnp" if x is KDA_JNP
+                   else str(x)))
+def test_channelwise_rule_equals_the_recurrence_in_values_and_gradients(
+        length, chunk, decay, shapes):
+    """Chunked against token by token: the output and the gradients of all
+    five inputs (q, k, v, the decay a channel, beta), no value non-finite."""
+    args = _kda_inputs(length, decay, **shapes)
+
+    def rule(*a):
+        return gated_delta.kda_rule(*a, chunk=chunk)
+
+    assert _takes_the_kda_kernels(rule, *args) == (shapes is KDA_KERNEL)
+    with jax.default_matmul_precision("highest"):
+        got, want = rule(*args), gated_delta.kda_recurrence(*args)
+        grads = jax.grad(_scalar(rule), argnums=range(5))(*args)
+        wants = jax.grad(_scalar(gated_delta.kda_recurrence),
+                         argnums=range(5))(*args)
+    assert got.shape == want.shape == args[2].shape
+    assert bool(jnp.all(jnp.isfinite(got)))
+    top = float(jnp.max(jnp.abs(want)))
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-5 * top
+    for name, a, b in zip("q k v g beta".split(), grads, wants):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert float(jnp.max(jnp.abs(a - b))) \
+            < 5e-5 * float(jnp.max(jnp.abs(b))), name
+
+
+@pytest.mark.parametrize("shapes", [KDA_JNP, KDA_KERNEL],
+                         ids=["jnp", "kernel"])
+def test_with_equal_channels_the_channelwise_rule_is_the_scalar_one(shapes):
+    q, k, v, g, beta = _kda_inputs(150, 0.95, **shapes)
+    g = jnp.broadcast_to(g[..., :1], g.shape)
+    with jax.default_matmul_precision("highest"):
+        got = gated_delta.kda_rule(q, k, v, g, beta)
+        want = gated_delta_rule(q, k, v, g[..., 0], beta)
+    assert float(jnp.max(jnp.abs(got - want))) \
+        < 2e-5 * float(jnp.max(jnp.abs(want)))
+
+
+@pytest.mark.parametrize("decay", [0.999, 0.9, 0.3, 0.01])
+@pytest.mark.parametrize("chunk", [64, 128])
+def test_no_positive_exponent_is_taken_at_any_decay(decay, chunk,
+                                                    monkeypatch):
+    """The bound the operator's comment states: every argument of ``exp``
+    in a tile of the chunk-local stage is a difference ``Gamma_i - Gamma_j``
+    with ``j <= i`` and so at most 0 (up to the rounding of two float32
+    running sums), at decays down to 0.01 a token, where ``K e^-Gamma``
+    would overflow float32 within 20 positions."""
+    q, k, v, g, beta = (x[0, :, 0] for x in _kda_inputs(
+        chunk, decay, heads=1, dk=16, dv=16, batch=1))
+    largest = []
+
+    class Recording:
+        def __getattr__(self, name):
+            return getattr(jnp, name)
+
+        @staticmethod
+        def exp(x):
+            largest.append(float(jnp.max(x)))
+            return jnp.exp(x)
+
+    monkeypatch.setattr(gated_delta, "jnp", Recording())
+    outs = gated_delta._kda_tile(q, k, v, g, beta[:, None], None,
+                                 gated_delta._TileOps(False, False))
+    assert len(largest) == 7 + (chunk // 8).bit_length() - 1 + 2
+    assert max(largest) <= 1e-4 * abs(float(jnp.sum(g[:, 0])))
+    assert all(bool(jnp.all(jnp.isfinite(x))) for x in outs)
+
+
+def test_bf16_operands_keep_the_channelwise_rule_near_the_recurrence():
+    args = _kda_inputs(200, 0.98, **KDA_KERNEL)
+    want = gated_delta.kda_recurrence(*args)
+    for chunk in (64, 128):
+        got = gated_delta.kda_rule(*args, chunk=chunk,
+                                   operand_dtype=jnp.bfloat16)
+        assert float(jnp.max(jnp.abs(got - want))) \
+            < 3e-2 * float(jnp.max(jnp.abs(want))), chunk
+
+
+def test_the_channelwise_rule_takes_kernels_at_lane_sized_heads_alone():
+    assert stage_plan(128, 128, channelwise=True) == ("kernel", 128)
+    assert stage_plan(128, 128, 64, channelwise=True) == ("kernel", 64)
+    assert stage_plan(16, 24, channelwise=True) == ("jnp", 64)
+    assert stage_plan(128, 128, 32, channelwise=True) == ("jnp", 32)
+    fits = _kda_inputs(70, 0.9, **KDA_KERNEL)
+    text = str(jax.make_jaxpr(jax.grad(_scalar(gated_delta.kda_rule)))(*fits))
+    assert "kda_chunk_fwd" in text and "kda_chunk_bwd" in text
+    assert "gdn_chunk" not in text
+    with pytest.raises(ValueError, match="as many key heads"):
+        gated_delta.kda_rule(fits[0][:, :, :1], *fits[1:])
+    with pytest.raises(ValueError, match="power of two"):
+        gated_delta.kda_rule(*_kda_inputs(70, 0.9, **KDA_JNP), chunk=48)
+
+
+def test_a_checkpointed_block_keeps_what_the_channelwise_loop_reads():
+    """Under the ``kda_loop`` rung the chunk-local stage is not run again:
+    the backward pass holds one forward kernel call, not two."""
+    args = _kda_inputs(130, 0.9, **KDA_KERNEL)
+
+    def calls(rung):
+        fn = jax.checkpoint(gated_delta.kda_rule,
+                            policy=remat_plan.policy(rung, linear="kda"))
+        return str(jax.make_jaxpr(jax.grad(_scalar(fn)))(*args)).count(
+            "name=kda_chunk_fwd")
+
+    assert (calls(0), calls(1)) == (2, 1)
+
+
+@pytest.mark.parametrize("dim, path, chunk", [(8, "jnp", 64),
+                                              (128, "kernel", 128)])
+def test_the_account_names_the_path_a_kda_layer_took(dim, path, chunk):
+    from dtdl_tpu.models.transformer import KimiDeltaAttention
+    layer = KimiDeltaAttention(2, dim, 4, dtype=jnp.float32)
+    x = jax.ShapeDtypeStruct((2, 70, 32), jnp.float32)
+    before = len(compile_cache.kda_paths())
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+    assert len(compile_cache.kda_paths()) == before
+    with remat_plan.step_memory("lm_train_step", 0, None):
+        jax.eval_shape(layer.apply, params, x)
+    assert compile_cache.kda_paths()[before:] == [
+        {"fun_name": "lm_train_step", "path": path, "chunk": chunk,
+         "shapes": (2, 70, 2, dim, dim)}]
+    assert compile_cache.compile_totals()[f"kda_{path}_calls"] >= 1

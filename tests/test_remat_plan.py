@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pathlib
+
 import pytest
 
 from dtdl_tpu.models import remat_plan, transformer as transformer_mod
@@ -127,6 +129,30 @@ def test_ladder_order_is_flash_then_projections_then_mlp():
         attention.FLASH_OUT, attention.FLASH_QKV, remat_plan.ATTN_OUT,
         remat_plan.MLP_UP)
     assert remat_plan.policy(0) is None and callable(remat_plan.policy(2))
+
+
+@pytest.mark.parametrize("linear, names", [
+    (False, ("flash_out", "flash_qkv", "attn_out")),
+    (True, ("gdn_loop", "gdn_in")), ("linear", ("gdn_loop", "gdn_in")),
+    ("kda", ("kda_loop", "kda_in"))], ids=["full_or_mla", "gdn_bool",
+                                           "gdn", "kda"])
+def test_each_kind_of_block_has_a_ladder_of_its_own(linear, names):
+    """A full and a latent-attention block keep the attention names; the
+    two linear-attention kinds two rungs of their own, each under its own
+    names (the names are where the code puts them: the rule's file and the
+    layer)."""
+    from dtdl_tpu.ops import gated_delta
+    assert remat_plan.saved_names(2, linear=linear) == names
+    assert remat_plan.saved_names(1, linear=linear, held=True) == (
+        names[0], remat_plan.MOE_PLAN)
+    assert remat_plan.saved_names(0, linear=linear) == ()
+    assert (gated_delta.GDN_LOOP, remat_plan.GDN_IN, gated_delta.KDA_LOOP,
+            remat_plan.KDA_IN) == ("gdn_loop", "gdn_in", "kda_loop", "kda_in")
+    src = pathlib.Path(remat_plan.__file__).parents[1]
+    layer = (src / "models" / "transformer.py").read_text()
+    rule = (src / "ops" / "gated_delta.py").read_text()
+    assert "remat_plan.KDA_IN" in layer and "KDA_LOOP)" in rule
+    assert "remat_plan.GDN_IN" in layer and "GDN_LOOP)" in rule
 
 
 @pytest.mark.parametrize("step", [
