@@ -194,7 +194,12 @@ def train_phase(plan: Plan):
               f"(rung of each block {list(kept.rungs)}; budget "
               f"{kept.budget_bytes / 1e9:.3f} GB = limit {kept.limit_bytes} "
               f"less margin less an estimate of "
-              f"{kept.estimate_bytes / 1e9:.3f} GB under rung 0)")
+              f"{kept.estimate_bytes / 1e9:.3f} GB under rung 0 as the "
+              f"backward pass begins"
+              + ("" if kept.end_bytes is None else
+                 f"; {kept.end_bytes / 1e9:.3f} GB as it ends, "
+                 f"{kept.walk_bytes / 1e9:.3f} GB at the most between")
+              + ")")
     return model, state.params
 
 

@@ -48,15 +48,32 @@ block does, at its own widths (:func:`mla_residual_bytes`).
 **The budget** is computed, never set: the device's
 ``memory_stats()["bytes_limit"]`` (:func:`device_bytes_limit`) less
 :data:`MARGIN`, less an estimate from shapes of what the step holds under
-rung 0.  The step knows what lives outside the model
-(:func:`step_held_bytes`: the state's leaves, gradients, the loss's chunk)
-and says so through :func:`step_memory`; the model adds what it holds itself
+rung 0 **when the backward pass begins**: that is the instant at which every
+kept residual is live.  The step knows what lives outside the model
+(:func:`step_held_bytes`: the state's leaves, the loss's chunk) and says so
+through :func:`step_memory`; the model adds what it holds itself
 (:func:`model_held_bytes`: logits, the blocks' inputs, one block's live set)
-and plans (:func:`plan_checkpoints`).  Outside a
-:func:`step_memory` context, where the device reports no limit (the CPU) or
-where the traced shapes are not one device's (GSPMD), the plan is rung 0.
-Everything here is a function of shapes and of the device kind's limit, so
-every process of a job plans alike.
+and plans (:func:`plan_checkpoints`).
+
+**Two instants, not one sum.**  Where a collective or the guard reads the
+gradients together (DDP, ``StepGuard``) every parameter's float32 gradient
+is live **when the backward pass ends**, and by then every residual, the
+logits and the blocks' inputs are gone: the gradients take the place of what
+the backward pass has released, not room beside it.  So the step names a
+second instant (the state and all gradients; the model adds the parameters'
+copy and one block's live set), which no plan changes: if it alone passes
+limit less margin the plan is rung 0.  Between the two the step holds the
+residuals and inputs of the blocks not yet differentiated and the gradients
+of those already done; :func:`walk_bytes` computes that walk's peak from the
+per-block costs, the blocks' inputs and their parameters' bytes, and
+:func:`plan_checkpoints` takes residuals off the ladder's end until the
+peak fits too.  A step on one device, whose gradients never outlive their
+block, has the first instant alone, as it always had.
+
+Outside a :func:`step_memory` context, where the device reports no limit
+(the CPU) or where the traced shapes are not one device's (GSPMD), the plan
+is rung 0.  Everything here is a function of shapes and of the device kind's
+limit, so every process of a job plans alike.
 """
 
 from __future__ import annotations
@@ -201,30 +218,53 @@ def tree_bytes(tree) -> int:
                for x in jax.tree.leaves(tree) if hasattr(x, "dtype"))
 
 
+class StepHeld(NamedTuple):
+    """What a train step holds outside the model (:func:`step_held_bytes`)."""
+    start: int          # when the backward pass begins
+    end: int | None     # when it ends; None: no gradient outlives its block
+
+
 def step_held_bytes(state_bytes: int, param_leaf_bytes: Sequence[int],
                     grads_all_live: bool, tokens: int,
-                    vocab_chunk_size: int) -> int:
-    """What the train step holds outside the model under any plan: the
-    state; the gradients, all of them where a collective or the guard reads
-    them together (on one device XLA fuses each leaf's AdamW update into its
-    weight-gradient matmul and none outlives its block, PERF.md section 5);
-    and under the chunked loss (ops/cross_entropy.py) four ``[tokens,
-    chunk]`` f32 tiles (logits, probabilities, one-hot, cotangent) and the
-    table's f32 gradient accumulator (the largest leaf)."""
-    held = state_bytes
-    if grads_all_live:
-        held += sum(param_leaf_bytes)
+                    vocab_chunk_size: int) -> StepHeld:
+    """What the train step holds outside the model under any plan, at the
+    plan's two instants.
+
+    ``start``, when the backward pass begins and every kept residual is
+    live: the state, and under the chunked loss (ops/cross_entropy.py) four
+    ``[tokens, chunk]`` f32 tiles (logits, probabilities, one-hot,
+    cotangent) and the table's f32 gradient accumulator (the largest leaf).
+    No block's gradient exists yet, so this is what a one-device step holds
+    too.
+
+    ``end``, when it ends, only where a collective or the guard reads the
+    gradients together (``grads_all_live``): the state and every
+    parameter's gradient; the loss's tiles are long gone.  None on one
+    device without a guard, where XLA fuses each leaf's AdamW update into
+    its weight-gradient matmul and no gradient outlives its block (PERF.md
+    section 5).  Before PR 34 the gradients were added to the one sum the
+    plan had, as if they were live beside the residuals they replace."""
+    start = state_bytes
     if vocab_chunk_size:
-        held += (4 * tokens * vocab_chunk_size * 4
-                 + max(param_leaf_bytes, default=0))
-    return held
+        start += (4 * tokens * vocab_chunk_size * 4
+                  + max(param_leaf_bytes, default=0))
+    end = state_bytes + sum(param_leaf_bytes) if grads_all_live else None
+    return StepHeld(start, end)
+
+
+class ModelHeld(NamedTuple):
+    """What the model holds under rung 0 (:func:`model_held_bytes`)."""
+    start: int          # when the backward pass begins
+    end: int            # when it ends: the parameters' copy, one live set
+    block_input: int    # one block's input, freed as the block is done
 
 
 def model_held_bytes(batch: int, seq: int, d_model: int, d_ff: int,
                      n_layers: int, vocab_size: int, param_bytes: int,
                      itemsize: int,
-                     block_live_bytes: int | None = None) -> int:
-    """What the model's forward and backward hold under rung 0, from shapes:
+                     block_live_bytes: int | None = None) -> ModelHeld:
+    """What the model's forward and backward hold under rung 0, from shapes.
+    When the backward pass begins (``start``):
     the f32 logits and their cotangent in the compute dtype (``vocab_size``
     0 where the caller takes the hidden states instead), the parameters'
     copy in the compute dtype (made in the forward pass and read again in
@@ -233,14 +273,17 @@ def model_held_bytes(batch: int, seq: int, d_model: int, d_ff: int,
     recomputed and differentiated (``wi wg``, their product and the three
     cotangents; eight ``[tokens, d_model]`` values of the attention half),
     or ``block_live_bytes`` where the blocks are not the dense one
-    (:func:`hybrid_block_live_bytes`, the largest block's)."""
+    (:func:`hybrid_block_live_bytes`, the largest block's).  When it ends
+    (``end``) the logits and the blocks' inputs are gone: the parameters'
+    copy and the live set of the block differentiated last."""
     t = batch * seq
     if block_live_bytes is None:
         block_live_bytes = t * (6 * d_ff + 8 * d_model) * itemsize
-    return (t * vocab_size * (4 + itemsize)
-            + param_bytes * itemsize // 4
-            + n_layers * t * d_model * itemsize
-            + block_live_bytes)
+    block_input = t * d_model * itemsize
+    end = param_bytes * itemsize // 4 + block_live_bytes
+    return ModelHeld(
+        t * vocab_size * (4 + itemsize) + n_layers * block_input + end,
+        end, block_input)
 
 
 def hybrid_block_live_bytes(batch: int, seq: int, d_model: int,
@@ -325,8 +368,9 @@ def hybrid_block_live_bytes(batch: int, seq: int, d_model: int,
 class StepMemory(NamedTuple):
     """What a train step tells the model it traces (:func:`step_memory`)."""
     fun_name: str | None    # the step's name in the compile account
-    held: int               # step_held_bytes
+    held: int               # step_held_bytes().start
     limit: int | None       # the device's bytes_limit; None: not reported
+    end_held: int | None = None     # step_held_bytes().end
 
 
 class RematPlan(NamedTuple):
@@ -335,8 +379,14 @@ class RematPlan(NamedTuple):
     rungs: tuple[int, ...]      # rung of each block, block 0 first
     kept_bytes: int             # residuals the plan keeps, by shape
     budget_bytes: int           # limit less margin less the estimate; >= 0
-    estimate_bytes: int         # what the step holds under rung 0
+    estimate_bytes: int         # held under rung 0 as the backward begins
     limit_bytes: int | None
+    # where gradients are read together (None where not): what the step
+    # holds as the backward pass ends, whatever the plan; and the most it
+    # holds between the two instants under this plan (walk_bytes).  A plan
+    # that keeps less than the ladder buys for its budget was held to these.
+    end_bytes: int | None = None
+    walk_bytes: int | None = None
 
 
 # outside a train step: nobody to plan for, no limit known
@@ -362,24 +412,68 @@ def device_bytes_limit() -> int | None:
 
 
 @contextlib.contextmanager
-def step_memory(fun_name: str, held: int, limit: int | None):
+def step_memory(fun_name: str, held: int, limit: int | None,
+                end_held: int | None = None):
     """While the body traces, a ``remat=True`` model plans against ``limit``
-    with ``held`` bytes spoken for."""
-    token = _STEP.set(StepMemory(fun_name, held, limit))
+    with ``held`` bytes spoken for as the backward pass begins and, where
+    gradients are read together, ``end_held`` as it ends."""
+    token = _STEP.set(StepMemory(fun_name, held, limit, end_held))
     try:
         yield
     finally:
         _STEP.reset(token)
 
 
-def plan_checkpoints(costs: Sequence[Sequence[int]],
-                     model_held: int) -> RematPlan:
-    """The plan for blocks of ``costs`` in a model that holds ``model_held``
-    bytes under rung 0.  Rung 0 throughout outside :func:`step_memory` or
-    where the device reports no limit."""
+def walk_bytes(end: int, costs: Sequence[Sequence[int]],
+               rungs: Sequence[int], block_input: int,
+               block_grads: Sequence[int]) -> int:
+    """The most a step holds from the head's backward to the end of the
+    backward pass, where every gradient stays live to the end (``end``:
+    what it holds then).  The blocks are differentiated last to first; until
+    block ``i`` is, the step holds what the block keeps (``costs[i]`` up to
+    ``rungs[i]``) and its input in place of its parameters' gradient
+    (``block_grads[i]``).  So
+    before block ``k`` the step holds ``end`` plus the sum over the blocks
+    before ``k`` of ``kept + input - gradient``, and the peak is ``end``
+    plus the largest such prefix sum (the empty one is the end itself).
+    The gradients outside the blocks (the table's, the final norm's) count
+    from the head's backward on, and one block's live set throughout, as
+    ``end`` has them."""
+    peak = held = end
+    for cost, rung, grad in zip(costs, rungs, block_grads):
+        held += sum(cost[:rung]) + block_input - grad
+        peak = max(peak, held)
+    return peak
+
+
+def plan_checkpoints(costs: Sequence[Sequence[int]], model: ModelHeld,
+                     block_grads: Sequence[int]) -> RematPlan:
+    """The plan for blocks of ``costs`` in a model that holds ``model``
+    (:func:`model_held_bytes`) under rung 0 and whose blocks' parameters
+    take ``block_grads`` bytes each.  Rung 0 throughout outside
+    :func:`step_memory` or where the device reports no limit.
+
+    The budget is limit less margin less what is held as the backward pass
+    begins.  Where the step named an end (gradients read together) and that
+    alone passes limit less margin, the budget is 0; otherwise the walk
+    between the two instants is **computed** (:func:`walk_bytes`; the margin
+    is not asked to cover it) and, while its peak passes limit less margin,
+    the residual the ladder filled last is given back.  That ends: each
+    round keeps less, and at rung 0 a walk that still does not fit is an
+    estimate over the limit, as it always was."""
     step = _STEP.get()
-    estimate = step.held + model_held
-    budget = (0 if step.limit is None
-              else max(0, int(step.limit * (1 - MARGIN)) - estimate))
+    estimate = step.held + model.start
+    ceiling = 0 if step.limit is None else int(step.limit * (1 - MARGIN))
+    budget = max(0, ceiling - estimate)
+    end = walk = None
+    if step.end_held is not None:
+        end = step.end_held + model.end
+        if end > ceiling:
+            budget = 0
     rungs, kept = ladder(costs, budget)
-    return RematPlan(step.fun_name, rungs, kept, budget, estimate, step.limit)
+    if end is not None:
+        while (walk := walk_bytes(end, costs, rungs, model.block_input,
+                                  block_grads)) > ceiling and kept:
+            rungs, kept = ladder(costs, kept - 1)
+    return RematPlan(step.fun_name, rungs, kept, budget, estimate,
+                     step.limit, end, walk)

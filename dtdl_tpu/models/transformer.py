@@ -1847,7 +1847,11 @@ class TransformerLM(nn.Module):
         compile account beside the step that traces it."""
         batch, seq = tokens_shape
         itemsize = jnp.dtype(self.dtype).itemsize
-        param_bytes = remat_plan.tree_bytes(self.variables.get("params", {}))
+        params = self.variables.get("params", {})
+        param_bytes = remat_plan.tree_bytes(params)
+        # a block's gradient has its parameters' shapes and dtypes
+        block_grads = [remat_plan.tree_bytes(params.get(f"block_{i}", {}))
+                       for i in range(self.n_layers)]
         vocab = 0 if return_hidden else self.vocab_size
         if specs is None or specs[0] is None:
             costs = [remat_plan.residual_bytes(
@@ -1888,7 +1892,7 @@ class TransformerLM(nn.Module):
             held = remat_plan.model_held_bytes(
                 batch, seq, self.d_model, 0, self.n_layers, vocab,
                 param_bytes, itemsize, block_live_bytes=live)
-        plan = remat_plan.plan_checkpoints(costs, held)
+        plan = remat_plan.plan_checkpoints(costs, held, block_grads)
         if plan.fun_name is not None:
             record_remat_plan(plan)
         return plan

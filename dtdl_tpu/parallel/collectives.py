@@ -45,8 +45,13 @@ def grad_sync(grads, axis: str = DATA_AXIS):
     The TPU equivalent of DDP's bucketed NCCL allreduce (reference
     pytorch/distributed_data_parallel.py:74,132) and ChainerMN's
     multi-node-optimizer allreduce (reference chainer/train_mnist_multi.py:81-83).
-    XLA fuses/schedules these AllReduces against the backward pass, giving the
-    comm/compute overlap torch gets from grad hooks.
+    XLA schedules these AllReduces inside the backward pass, each right after
+    its gradient's fusion, but on a v5e as **synchronous** ``all-reduce``
+    instructions (no ``-start``/``-done`` pair): the core waits out each one,
+    so this is not the comm/compute overlap torch gets from grad hooks.  In
+    ``olmo1b-train-ddp4`` the 27 of them stand 45 ms a step exposed (PERF.md
+    section 7, which also says which compiler options changed nothing and
+    what a reduce-scatter + all-gather form compiles to).
     """
     return lax.pmean(grads, axis_name=axis)
 

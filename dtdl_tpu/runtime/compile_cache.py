@@ -31,7 +31,10 @@ The account is one a process because jax's listeners are.
 (``models/remat_plan.py``): :func:`record_remat_plan` appends,
 :func:`remat_plans` returns them, and :func:`compile_totals` carries the
 newest as ``remat_blocks_by_rung``, ``remat_kept_bytes``,
-``remat_budget_bytes`` and ``remat_estimate_bytes``.  A held-experts layer
+``remat_budget_bytes``, ``remat_estimate_bytes`` (held as the backward pass
+begins) and, where the step's gradients are read together,
+``remat_end_bytes`` and ``remat_walk_bytes`` (held as it ends, and the most
+between the two instants: the plan was held to all three).  A held-experts layer
 (``models/transformer.py:HeldExperts``) adds the shapes of its two row
 buffers the same way: :func:`record_expert_buffer`, :func:`expert_buffers`,
 and ``moe_buffer_rows``, ``moe_first_buffer_rows``, ``moe_row_tile``,
@@ -190,7 +193,7 @@ def compile_totals() -> dict:
     """The account's totals under the keys of :data:`ACCOUNT_EVENTS`: the
     seconds covered by each kind of duration, and the counts; and of the
     newest checkpoint plan the blocks at each rung (rung 0 first), the bytes
-    it keeps, its budget and the estimate it was chosen against.  ``{}``
+    it keeps, its budget and the estimates it was chosen against.  ``{}``
     while the account is empty."""
     totals = {}
     if _ROWS:
@@ -205,6 +208,9 @@ def compile_totals() -> dict:
         totals["remat_kept_bytes"] = plan.kept_bytes
         totals["remat_budget_bytes"] = plan.budget_bytes
         totals["remat_estimate_bytes"] = plan.estimate_bytes
+        if plan.end_bytes is not None:
+            totals["remat_end_bytes"] = plan.end_bytes
+            totals["remat_walk_bytes"] = plan.walk_bytes
     if _EXPERT_BUFFERS:
         newest = _EXPERT_BUFFERS[-1]
         totals["moe_buffer_rows"] = newest["rows"]

@@ -285,15 +285,18 @@ def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
                 return loss, (correct, aux, held)
 
         # what a remat=True model may keep of its forward pass is planned
-        # against what this step holds beside it (models/remat_plan.py)
-        held = remat_plan.step_held_bytes(
+        # against what this step holds beside it as the backward pass
+        # begins and, where a collective or the guard reads the gradients
+        # together, as it ends (models/remat_plan.py)
+        beside = remat_plan.step_held_bytes(
             remat_plan.tree_bytes(state),
             [remat_plan.tree_bytes(p) for p in jax.tree.leaves(state.params)],
             strategy.num_replicas > 1 or guard is not None,
             inputs.size, vocab_chunk_size)
         limit = (remat_plan.device_bytes_limit()
                  if strategy.traces_one_device else None)
-        with remat_plan.step_memory("lm_train_step", held, limit):
+        with remat_plan.step_memory("lm_train_step", beside.start, limit,
+                                    beside.end):
             (loss, (acc, aux, held)), grads = jax.value_and_grad(
                 compute_loss, has_aux=True)(strategy.localize(state.params))
         with jax.named_scope("grad_sync"):

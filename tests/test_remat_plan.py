@@ -156,21 +156,31 @@ def test_each_kind_of_block_has_a_ladder_of_its_own(linear, names):
 
 
 @pytest.mark.parametrize("step", [
-    None, ("lm_train_step", 0, None), ("lm_train_step", 10**12, V5E_LIMIT)],
-    ids=["no_step", "no_limit", "nothing_left"])
+    None, ("lm_train_step", 0, None), ("lm_train_step", 10**12, V5E_LIMIT),
+    # (iii) the end of the backward pass alone does not fit: the start
+    # would leave a budget that buys every rung, and the plan keeps nothing
+    ("lm_train_step", 0, V5E_LIMIT, V5E_LIMIT)],
+    ids=["no_step", "no_limit", "nothing_left", "end_alone_over"])
 def test_unknown_limit_or_no_step_or_no_room_is_rung_zero(step):
     costs = _cell_costs("olmo7b-train-b2s2048")
+    model = remat_plan.ModelHeld(123, 45, 6)
     if step is None:
-        plan = remat_plan.plan_checkpoints(costs, 123)
+        plan = remat_plan.plan_checkpoints(costs, model, [7, 7])
         assert plan.fun_name is None and plan.estimate_bytes == 123
     else:
         with remat_plan.step_memory(*step):
-            plan = remat_plan.plan_checkpoints(costs, 123)
+            plan = remat_plan.plan_checkpoints(costs, model, [7, 7])
         assert plan.fun_name == "lm_train_step"
         assert plan.estimate_bytes == step[1] + 123
     assert plan.rungs == (0, 0) and plan.kept_bytes == plan.budget_bytes == 0
+    if step is None or len(step) == 3:      # no gradient outlives its block
+        assert plan.end_bytes is None and plan.walk_bytes is None
+    else:
+        assert plan.end_bytes == plan.walk_bytes == V5E_LIMIT + 45
+        assert int(V5E_LIMIT * 15 / 16) - plan.estimate_bytes > sum(
+            map(sum, costs))
     # and the context is gone once its body is left
-    assert remat_plan.plan_checkpoints(costs, 0).fun_name is None
+    assert remat_plan.plan_checkpoints(costs, model, [7, 7]).fun_name is None
 
 
 def test_device_limit_is_none_here_and_whole_64_mib_on_a_chip(monkeypatch):
@@ -195,14 +205,24 @@ def test_device_limit_is_none_here_and_whole_64_mib_on_a_chip(monkeypatch):
 def test_what_the_step_holds_beside_the_model():
     leaves = [400, 100, 50]
     held = remat_plan.step_held_bytes
-    assert held(1650, leaves, False, 1000, 0) == 1650
-    assert held(1650, leaves, True, 1000, 0) == 1650 + 550
+    assert held(1650, leaves, False, 1000, 0) == (1650, None)
+    # gradients read together: none when the backward pass begins, all of
+    # them when it ends (they were added to the one sum before PR 34)
+    assert held(1650, leaves, True, 1000, 0) == (1650, 1650 + 550)
     # the chunked loss: four f32 [tokens, chunk] tiles and the table's grad
-    assert held(1650, leaves, False, 1000, 8) == 1650 + 4 * 1000 * 8 * 4 + 400
+    # as the backward pass begins; by its end the tiles are gone
+    assert held(1650, leaves, False, 1000, 8).start == (
+        1650 + 4 * 1000 * 8 * 4 + 400)
+    assert held(1650, leaves, True, 1000, 8) == (
+        1650 + 4 * 1000 * 8 * 4 + 400, 1650 + 550)
     dense = remat_plan.model_held_bytes(4, 100, 64, 256, 2, 1000, 4000, 2)
     hidden = remat_plan.model_held_bytes(4, 100, 64, 256, 2, 0, 4000, 2)
-    assert dense - hidden == 400 * 1000 * (4 + 2)      # logits + cotangent
-    assert hidden == 2000 + 2 * 400 * 64 * 2 + 400 * (6 * 256 + 8 * 64) * 2
+    assert dense.start - hidden.start == 400 * 1000 * (4 + 2)   # logits + cotangent
+    assert hidden.start == (2000 + 2 * 400 * 64 * 2
+                            + 400 * (6 * 256 + 8 * 64) * 2)
+    # at the end: the parameters' copy and one block's live set, no input
+    assert dense.end == hidden.end == 2000 + 400 * (6 * 256 + 8 * 64) * 2
+    assert dense.block_input == 400 * 64 * 2
     state = {"a": jax.ShapeDtypeStruct((3, 5), jnp.float32),
              "b": jax.ShapeDtypeStruct((7,), jnp.bfloat16), "n": 3}
     assert remat_plan.tree_bytes(state) == 60 + 14
@@ -229,12 +249,18 @@ def _traced_plan(monkeypatch, model, rows, row_tokens, limit, strategy=None,
 
 
 def _cell_model(name):
+    """The cell's model as the benchmark's runner builds it
+    (benchmarks/runners/train.py:make_plan), whatever its family."""
+    import sys
+    bench = os.path.join(REPO, "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from lib import modules
     cell, cfg = _cell(name)
-    model = TransformerLM(
-        vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"],
-        n_layers=cfg["num_hidden_layers"], n_heads=cfg["num_attention_heads"],
-        d_ff=cfg["intermediate_size"], max_seq=cfg["max_position_embeddings"],
-        attn_impl="flash", remat=True, dtype=jnp.bfloat16)
+    family = modules.load_file(os.path.join(
+        bench, "families", cfg["model_type"] + ".py"), "families")
+    model = TransformerLM(dtype=jnp.bfloat16,
+                          **family.model_kwargs(cfg, True))
     return model, cell["batch_per_chip"], cell["row_tokens"]
 
 
@@ -263,6 +289,108 @@ def test_cells_plan_at_the_chips_limit(name, limit, monkeypatch):
     else:                               # does not hold all of it
         assert min(plan.rungs) >= 2 and len(plan.rungs) == 8
         assert plan.estimate_bytes + plan.kept_bytes <= limit * 15 / 16
+
+
+# rungs, kept, budget and estimate of the step each cell traces at a v5e's
+# limit (PERF.md section 4).  The four one-chip cells' are what they were
+# before the plan had a second instant (PR 33's tree reads the same bytes);
+# the four-chip cell's step reads its gradients together and gets the
+# one-chip plan of the same shapes (before PR 34: rungs (2,1,1,1,1,1,1,1),
+# 0.407 GB kept of a 0.460 GB budget, an estimate of 15.332 GB that counted
+# 2.56 GB of gradients beside the residuals they replace), and so does a
+# guarded step on one device.
+_OLMO1B = ((3, 3, 3, 3, 3, 3, 2, 2), 2_955_540_480, 3_019_701_240,
+           12_771_853_320)
+_READ_TOGETHER = (12_591_927_304, 13_801_556_488)       # end, walk
+_PINNED = {
+    "olmo1b-train-b4s2048": ("olmo1b-train-b4s2048", 1, False, _OLMO1B),
+    "olmo7b-train-b2s2048": ("olmo7b-train-b2s2048", 1, False, (
+        (3, 3), 696_962_560, 5_128_281_592, 10_663_272_968)),
+    "qwen3next-train-share16": ("qwen3next-train-share16", 1, False, (
+        (2, 2, 1, 1), 1_476_804_480, 1_494_105_784, 14_297_448_776)),
+    "kimilinear-train-share32": ("kimilinear-train-share32", 1, False, (
+        (2, 2, 1, 1, 1), 1_812_872_960, 2_012_564_216, 13_778_990_344)),
+    "olmo1b-train-ddp4": ("olmo1b-train-ddp4", 4, False, _OLMO1B),
+    "olmo1b_one_chip_guarded": ("olmo1b-train-b4s2048", 1, True, _OLMO1B),
+}
+
+
+@pytest.mark.parametrize("case", list(_PINNED))
+def test_each_cells_plan_at_the_v5e_limit_is_pinned(case, monkeypatch,
+                                                    devices):
+    name, chips, guarded, want = _PINNED[case]
+    limit = V5E_LIMIT >> 26 << 26
+    assert limit == 16_844_324_864
+    strategy = None if chips == 1 else DataParallel(
+        mesh=jax.sharding.Mesh(np.asarray(devices[:chips]), (DATA_AXIS,)))
+    plan = _traced_plan(monkeypatch, *_cell_model(name), limit,
+                        strategy, **({"guard": StepGuard()} if guarded else {}))
+    assert (plan.rungs, plan.kept_bytes, plan.budget_bytes,
+            plan.estimate_bytes) == want
+    assert plan.limit_bytes == limit
+    ceiling = int(limit * 15 / 16)
+    assert plan.estimate_bytes + plan.kept_bytes <= ceiling
+    if chips == 1 and not guarded:
+        # no gradient outlives its block: the first instant alone
+        assert plan.end_bytes is None and plan.walk_bytes is None
+    else:
+        # the second instant is reported, fits, and is what shapes say:
+        # 12 B a parameter of state (and its counters), 4 of gradient, 2 of
+        # bf16 copy, one block's live set; the walk between the two peaks
+        # after blocks 7 and 6 (rung 2: they keep less than their
+        # gradients take) and stays under the ceiling as well
+        assert (plan.end_bytes, plan.walk_bytes) == _READ_TOGETHER
+        assert plan.end_bytes < plan.walk_bytes <= ceiling
+        n_params, t = 639_928_320, 4 * 2047
+        live = t * (6 * 8192 + 8 * 2048) * 2
+        assert 0 <= plan.end_bytes - (n_params * 18 + live) < 4096
+        block = 4 * (4 * 2048 ** 2 + 3 * 2048 * 8192 + 2 * 2048)
+        assert plan.walk_bytes == remat_plan.walk_bytes(
+            plan.end_bytes, _cell_costs(name), plan.rungs, t * 2048 * 2,
+            [block] * 8)
+
+
+@pytest.mark.parametrize("grads, end_room, rungs, kept", [
+    # every block's gradient takes what the block kept: the end is the peak
+    ([150] * 4, 100, (3, 3, 3, 3), 600),
+    # blocks keep 30 more than their gradients take: the walk stands 120
+    # over the end before the first block is done; the ladder's last
+    # residual (block 3's rung 3) goes and the peak is 90, before block 2
+    ([120] * 4, 100, (3, 3, 3, 2), 500),
+    # gradients of no size (a frozen model's): whatever is kept stands over
+    # the end whole, so the plan keeps what the end leaves room for
+    ([0] * 4, 100, (2, 1, 1, 1), 80),
+    ([0] * 4, 39, (1, 1, 1, 0), 30),
+    # uneven blocks: the walk's peak is a prefix sum (here before block 2,
+    # the first blocks' 300 standing over the end), not the total's 50
+    ([0, 0, 400, 150], 320, (3, 3, 3, 3), 600),
+    ([0, 0, 400, 150], 200, (3, 2, 2, 2), 300),
+], ids=["end_is_the_peak", "last_residual_back", "no_gradients",
+        "almost_no_room", "uneven_fits", "uneven_back"])
+def test_the_walk_between_the_instants_gives_residuals_back(
+        grads, end_room, rungs, kept):
+    """A start that would buy every rung and an end that fits alone: the
+    plan is the richest ladder whose walk (walk_bytes) fits as well."""
+    costs = [(10, 40, 100)] * 4
+    ceiling = 15 * 10**6
+    limit = ceiling * 16 // 15
+    model = remat_plan.ModelHeld(ceiling - 600, ceiling - end_room, 0)
+    with remat_plan.step_memory("lm_train_step", 0, limit, 0):
+        plan = remat_plan.plan_checkpoints(costs, model, grads)
+    assert plan.budget_bytes == 600 and plan.end_bytes == ceiling - end_room
+    assert (plan.rungs, plan.kept_bytes) == (rungs, kept)
+    assert plan.walk_bytes <= ceiling
+    # it is a ladder (what some smaller budget buys), and the next richer
+    # ladder's walk does not fit
+    assert remat_plan.ladder(costs, kept) == (rungs, kept)
+    if kept < 600:
+        richer, _ = remat_plan.ladder(costs, kept + 100)
+        assert richer != rungs and remat_plan.walk_bytes(
+            plan.end_bytes, costs, richer, 0, grads) > ceiling
+    # without an end (one device) the same start buys every rung
+    with remat_plan.step_memory("lm_train_step", 0, limit):
+        alone = remat_plan.plan_checkpoints(costs, model, grads)
+    assert alone.rungs == (3, 3, 3, 3) and alone.walk_bytes is None
 
 
 # ---------------------------------------------------------------------------
@@ -434,6 +562,14 @@ def test_plan_is_in_the_account_and_in_the_observers_summary(monkeypatch):
     summary = Observer().summary()
     assert {k: totals[k] for k in want} == want
     assert {k: summary[k] for k in want} == want
+    # a step whose gradients no one reads together has no second instant
+    assert "remat_end_bytes" not in totals
+    step, state, batch = _tiny_step(monkeypatch, (3, 2), guard=StepGuard())
+    step.lower(state, batch)
+    plan, totals = compile_cache.remat_plans()[-1], compile_cache.compile_totals()
+    assert plan.rungs == (3, 2) and plan.end_bytes <= plan.walk_bytes
+    assert (totals["remat_end_bytes"], totals["remat_walk_bytes"]) == (
+        plan.end_bytes, plan.walk_bytes)
 
 
 @pytest.fixture(scope="module")
@@ -447,14 +583,23 @@ def test_ddp_plans_from_one_chips_shapes_and_gspmd_recomputes(
     single = _traced_plan(monkeypatch, _tiny(), 2, 64, 10**9)
     ddp = _traced_plan(monkeypatch, _tiny(), 2, 64, 10**9, two_devices)
     # inside shard_map the step sees one chip's rows; gradients wait for
-    # the all-reduce together, so the estimate grows by the parameters
+    # the all-reduce together, which no residual does: the start's account
+    # is the one-device step's, and the parameters' bytes stand in the end's
     assert ddp.rungs == single.rungs == (3, 3)
     assert ddp.kept_bytes == single.kept_bytes
-    n_param_bytes = remat_plan.tree_bytes(_tiny_state(_tiny())[0].params)
-    assert ddp.estimate_bytes == single.estimate_bytes + n_param_bytes
+    state = _tiny_state(_tiny())[0]
+    n_param_bytes = remat_plan.tree_bytes(state.params)
+    assert ddp.estimate_bytes == single.estimate_bytes
+    assert single.end_bytes is None
+    live = 2 * 63 * (6 * 128 + 8 * 64) * 4
+    assert ddp.end_bytes == (remat_plan.tree_bytes(state)
+                             + 2 * n_param_bytes + live)    # f32: a whole copy
+    assert ddp.end_bytes <= ddp.walk_bytes <= 10**9 * 15 / 16
     guarded = _traced_plan(monkeypatch, _tiny(), 2, 64, 10**9,
                            guard=StepGuard())
     assert guarded.estimate_bytes == ddp.estimate_bytes
+    assert (guarded.end_bytes, guarded.walk_bytes) == (ddp.end_bytes,
+                                                       ddp.walk_bytes)
     # GSPMD traces global shapes: no plan is made from them
     mesh = jax.sharding.Mesh(np.asarray(devices[:2]), (DATA_AXIS,))
     auto = _traced_plan(monkeypatch, _tiny(), 1, 64, 10**9,
