@@ -16,7 +16,9 @@ name            value                                        placed in
 ``flash_out``   flash attention's ``o`` and log-sum-exp      ops/attention.py
 ``flash_qkv``   ``q``, ``k``, ``v`` as the kernels take      ops/attention.py
                 them (unrotated)
-``attn_out``    the ``out`` projection's output              Attention
+``attn_out``    the ``out`` projection's output, and the     Attention
+                output gate's own projection's where the
+                layer has one (``gate='own'``)
 ``mlp_up``      the ``wi`` and ``wg`` outputs                SwiGLU
 ``gdn_loop``    what the delta rule's loop reads of a chunk  ops/gated_delta.py
                 (a linear-attention block)
@@ -180,17 +182,21 @@ def mla_residual_bytes(batch: int, seq: int, d_model: int, n_heads: int,
 
 def residual_bytes(batch: int, seq: int, d_model: int, n_heads: int,
                    d_ff: int, itemsize: int,
-                   attn_width: int | None = None) -> tuple[int, int, int]:
+                   attn_width: int | None = None,
+                   own_gate: bool = False) -> tuple[int, int, int]:
     """Bytes one block keeps at rungs 1, 2 and 3, each beyond the rung below:
-    ``o`` + f32 log-sum-exp; ``q k v`` + the ``out`` projection's output;
-    ``wi`` + ``wg`` (``d_ff`` 0 for a MoE block, which has no rung 3).
+    ``o`` + f32 log-sum-exp; ``q k v`` + the ``out`` projection's output
+    (+ the gate's own projection's output, ``own_gate``); ``wi`` + ``wg``
+    (``d_ff`` 0 for a MoE block, which has no rung 3).
     ``attn_width`` is heads times head size where the model states a head
     size of its own (``q k v`` as the kernels take them, K/V repeated to
-    the query heads); ``d_model`` otherwise."""
+    the query heads); ``d_model`` otherwise.  A windowed layer keeps what a
+    full one keeps: the band changes what recomputing ``flash_out`` costs
+    (the band's tiles, ops/attention.py:band_tiles), not its bytes."""
     t = batch * seq
     width = d_model if attn_width is None else attn_width
     return (t * width * itemsize + batch * n_heads * seq * 4,
-            (3 * width + d_model) * t * itemsize,
+            (3 * width + d_model + (width if own_gate else 0)) * t * itemsize,
             2 * t * d_ff * itemsize)
 
 
@@ -257,6 +263,8 @@ class ModelHeld(NamedTuple):
     start: int          # when the backward pass begins
     end: int            # when it ends: the parameters' copy, one live set
     block_input: int    # one block's input, freed as the block is done
+    # ``start`` with the head and the blocks told apart (None: not reckoned)
+    start_apart: int | None = None
 
 
 def model_held_bytes(batch: int, seq: int, d_model: int, d_ff: int,
@@ -275,21 +283,33 @@ def model_held_bytes(batch: int, seq: int, d_model: int, d_ff: int,
     or ``block_live_bytes`` where the blocks are not the dense one
     (:func:`hybrid_block_live_bytes`, the largest block's).  When it ends
     (``end``) the logits and the blocks' inputs are gone: the parameters'
-    copy and the live set of the block differentiated last."""
+    copy and the live set of the block differentiated last.
+
+    ``start`` adds the logits **and** a block's live set, as if the head's
+    backward and a block's ran at one instant.  They do not: the logits die
+    with the head's backward, before the last block is recomputed.
+    ``start_apart`` has the larger of the two in place of their sum.  The
+    sum is the reckoning every accepted plan stands on and is kept wherever
+    it leaves a budget; :func:`plan_checkpoints` falls back on
+    ``start_apart`` where it leaves none (rows of 8,191 positions: the sum
+    reads 5.9 GB over the v5e compiler's total at rung 0 and the plan would
+    keep nothing, PERF.md section 6, PR 35)."""
     t = batch * seq
     if block_live_bytes is None:
         block_live_bytes = t * (6 * d_ff + 8 * d_model) * itemsize
     block_input = t * d_model * itemsize
     end = param_bytes * itemsize // 4 + block_live_bytes
-    return ModelHeld(
-        t * vocab_size * (4 + itemsize) + n_layers * block_input + end,
-        end, block_input)
+    logits = t * vocab_size * (4 + itemsize)
+    held = n_layers * block_input + end
+    return ModelHeld(logits + held, end, block_input,
+                     held - block_live_bytes + max(logits, block_live_bytes))
 
 
 def hybrid_block_live_bytes(batch: int, seq: int, d_model: int,
                             itemsize: int, attn_width: int = 0,
                             gdn=None, held=None, d_ff: int = 0,
-                            kda=None, mla=None) -> int:
+                            kda=None, mla=None,
+                            post_norms: bool = False) -> int:
     """One hybrid block's live set (models/transformer.py:BlockSpec) while
     it is recomputed and differentiated.
 
@@ -317,10 +337,12 @@ def hybrid_block_live_bytes(batch: int, seq: int, d_model: int,
     and half as much again.  A latent-attention block (``mla``: ``(heads,
     MlaSpec)``): q, the assembled k, v, ``kv_b``'s output and ``o``, each
     with its cotangent.  A dense MLP: six
-    ``d_ff`` values.  How the step's estimate stands against the v5e
+    ``d_ff`` values.  Norms on the sublayers' outputs (``post_norms``): the
+    two sublayers' outputs before their norms, each with its cotangent.  How
+    the step's estimate stands against the v5e
     compiler's total for the Qwen3-Next cell is in PERF.md section 4."""
     t = batch * seq
-    live = 4 * t * d_model * itemsize
+    live = (8 if post_norms else 4) * t * d_model * itemsize
     if attn_width:
         live += 2 * 8 * t * attn_width * itemsize
     if gdn:
@@ -454,7 +476,8 @@ def plan_checkpoints(costs: Sequence[Sequence[int]], model: ModelHeld,
     :func:`step_memory` or where the device reports no limit.
 
     The budget is limit less margin less what is held as the backward pass
-    begins.  Where the step named an end (gradients read together) and that
+    begins (``model.start``; ``model.start_apart`` where that leaves no
+    budget at all).  Where the step named an end (gradients read together) and that
     alone passes limit less margin, the budget is 0; otherwise the walk
     between the two instants is **computed** (:func:`walk_bytes`; the margin
     is not asked to cover it) and, while its peak passes limit less margin,
@@ -465,6 +488,11 @@ def plan_checkpoints(costs: Sequence[Sequence[int]], model: ModelHeld,
     estimate = step.held + model.start
     ceiling = 0 if step.limit is None else int(step.limit * (1 - MARGIN))
     budget = max(0, ceiling - estimate)
+    if not budget and ceiling and model.start_apart is not None:
+        # the cautious sum leaves nothing: tell the head's instant from the
+        # blocks' (model_held_bytes)
+        estimate = step.held + model.start_apart
+        budget = max(0, ceiling - estimate)
     end = walk = None
     if step.end_held is not None:
         end = step.end_held + model.end

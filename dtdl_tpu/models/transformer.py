@@ -38,7 +38,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from dtdl_tpu.models import remat_plan
-from dtdl_tpu.ops.attention import flash_attention, mha_reference
+from dtdl_tpu.ops.attention import (band_tiles, flash_attention,
+                                    mha_reference)
 from dtdl_tpu.ops.gated_delta import gated_delta_rule, kda_rule, stage_plan
 from dtdl_tpu.ops.grouped_matmul import (
     ROW_TILE, first_buffer_rows, grouped_matmul, held_buffer_rows, rows_of,
@@ -50,7 +51,8 @@ from dtdl_tpu.quant import (QuantDenseGeneral, canon_kv_dtype, kv_quantize,
 from dtdl_tpu.runtime.compile_cache import (record_expert_buffer,
                                             record_gdn_path,
                                             record_kda_path,
-                                            record_remat_plan)
+                                            record_remat_plan,
+                                            record_window_call)
 
 Dtype = Any
 
@@ -136,17 +138,25 @@ class Attention(nn.Module):
     # rotation then runs outside the kernel, whose fused one turns the
     # whole head); ``qk_norm`` is an RMSNorm over the head on q and k;
     # ``gate`` doubles the q projection and multiplies the attention's
-    # output by the sigmoid of the second half.
+    # output by the sigmoid of the second half; ``gate='own'`` takes the
+    # gate from a projection of its own (``gate_proj``) instead.
+    # ``window`` (0: none): a query sees the ``window`` keys that end with
+    # its own (ops/attention.py's band); ``rotate=False`` leaves q and k
+    # unrotated (``rope_dims=0`` means the whole head, so "none" is a field
+    # of its own); ``norm_eps`` is the head norms'.
     n_kv_heads: int = 0
     rope_dims: int = 0
     qk_norm: bool = False
-    gate: bool = False
+    gate: Any = False
     norm_zero_centered: bool = False
+    window: int = 0
+    rotate: bool = True
+    norm_eps: float = 1e-6
 
     @property
     def grouped(self) -> bool:
         return bool(self.n_kv_heads or self.rope_dims or self.qk_norm
-                    or self.gate)
+                    or self.gate or self.window or not self.rotate)
 
     @nn.compact
     def __call__(self, x, cos, sin, decode: bool = False):
@@ -235,7 +245,11 @@ class Attention(nn.Module):
                 "grouped-query / gated attention trains only: decoding "
                 "through a KV cache and weight-only serving of it are "
                 "missing (Attention._decode_attend takes as many K/V "
-                "heads as Q heads and rotates the whole head)")
+                "heads as Q heads and rotates the whole head"
+                + (f"; a windowed layer (window={self.window}) also needs "
+                   f"a cache that holds its last {self.window} keys alone, "
+                   f"a page lifetime of its own in serve/paged.py"
+                   if self.window else "") + ")")
         d_model = x.shape[-1]
         h, d = self.n_heads, self.head_dim
         kv = self.n_kv_heads or h
@@ -250,29 +264,44 @@ class Attention(nn.Module):
                                   "embed", "heads", "head_dim"),
                 name=name)(x)
 
-        q = proj("q", h, 2 * d if self.gate else d)
+        own_gate = self.gate == "own"
+        q = proj("q", h, 2 * d if self.gate and not own_gate else d)
         gate = None
-        if self.gate:
+        if own_gate:
+            # kept with the out projection's output (rung 2: every
+            # projection of the layer goes)
+            gate = checkpoint_name(proj("gate_proj", h, d),
+                                   remat_plan.ATTN_OUT)
+        elif self.gate:
             q, gate = q[..., :d], q[..., d:]
         k, v = proj("k", kv, d), proj("v", kv, d)
         if self.qk_norm:
             def head_norm(name):
-                return RMSNorm(dtype=self.dtype, axis_name="head_dim",
+                return RMSNorm(eps=self.norm_eps, dtype=self.dtype,
+                               axis_name="head_dim",
                                zero_centered=self.norm_zero_centered,
                                name=name)
             q, k = head_norm("q_norm")(q), head_norm("k_norm")(k)
         # [B, S, H, D] -> [B, H, S, D]
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        r = self.rope_dims or d
-        q = jnp.concatenate([apply_rope(q[..., :r], cos, sin), q[..., r:]],
-                            axis=-1)
-        k = jnp.concatenate([apply_rope(k[..., :r], cos, sin), k[..., r:]],
-                            axis=-1)
+        if self.rotate:
+            r = self.rope_dims or d
+            q = jnp.concatenate(
+                [apply_rope(q[..., :r], cos, sin), q[..., r:]], axis=-1)
+            k = jnp.concatenate(
+                [apply_rope(k[..., :r], cos, sin), k[..., r:]], axis=-1)
         k, v = (jnp.repeat(t, h // kv, axis=1) for t in (k, v))
+        banded = {"window": self.window} if self.window else {}
         if self.attn_impl == "flash":
-            o = flash_attention(q, k, v, causal=True)
+            step_name = remat_plan.traced_step_name()
+            if self.window and step_name is not None:
+                record_window_call(
+                    step_name, (q.shape[0], h) + q.shape[2:],
+                    band_tiles(q.shape[2], k.shape[2], d, self.window))
+            o = flash_attention(q, k, v, causal=True, **banded)
         else:
-            o = mha_reference(q, k, v, causal=True).astype(self.dtype)
+            o = mha_reference(q, k, v, causal=True,
+                              **banded).astype(self.dtype)
         o = o.transpose(0, 2, 1, 3)
         if gate is not None:
             with jax.named_scope("gate"):
@@ -1497,8 +1526,11 @@ class BlockSpec:
     n_kv_heads: int = 0
     rope_dims: int = 0
     qk_norm: bool = False
-    attn_gate: bool = False
+    attn_gate: Any = False             # True: from a doubled q; 'own'
     norm_zero_centered: bool = False
+    window: int = 0                    # a 'full' layer's band; 0: causal
+    rotate: bool = True                # whether a 'full' layer rotates q, k
+    post_norms: bool = False           # a norm on each sublayer's output
     gdn: Any = None                    # a GdnSpec on a linear layer
     held: Any = None                   # a HeldSpec where experts are held
     kda: Any = None                    # a KdaSpec on a 'kda' layer
@@ -1546,12 +1578,16 @@ class Block(nn.Module):
 
     def _hybrid(self, x, cos, sin, decode):
         """``h = x + Mixer(norm(x)); y = h + FFN(norm(h))`` with the mixer
-        and the expert layer the spec names."""
+        and the expert layer the spec names; with ``post_norms`` a norm on
+        each sublayer's output too, ``h = x + norm(Mixer(norm(x)))``."""
         spec = self.spec
 
         def norm(name):
             return RMSNorm(eps=spec.norm_eps, dtype=self.dtype,
                            zero_centered=spec.norm_zero_centered, name=name)
+
+        def post(name, y):
+            return norm(name)(y) if spec.post_norms else y
 
         h = norm("ln_attn")(x)
         if spec.kind in ("linear", "kda", "mla") and decode:
@@ -1573,20 +1609,26 @@ class Block(nn.Module):
                 self.n_heads, **spec.mla._asdict(), norm_eps=spec.norm_eps,
                 attn_impl=self.attn_impl, dtype=self.dtype, name="attn")(h)
         elif spec.kind == "full":
-            x = x + Attention(
+            x = x + post("ln_attn_out", Attention(
                 self.n_heads, self.head_dim, self.attn_impl, self.dtype,
                 quantize=self.quantize, n_kv_heads=spec.n_kv_heads,
                 rope_dims=spec.rope_dims, qk_norm=spec.qk_norm,
                 gate=spec.attn_gate,
                 norm_zero_centered=spec.norm_zero_centered,
-                name="attn")(h, cos, sin, decode=decode)
+                window=spec.window, rotate=spec.rotate,
+                norm_eps=spec.norm_eps,
+                name="attn")(h, cos, sin, decode=decode))
         else:
             raise ValueError(f"unknown layer kind {spec.kind!r}")
+        if spec.post_norms and spec.kind != "full":
+            raise ValueError("norms on the sublayers' outputs are built for "
+                             "'full' layers alone")
         h = norm("ln_mlp")(x)
         if spec.held:
-            return x + HeldExperts(**spec.held._asdict(), dtype=self.dtype,
-                                   name="moe")(h)
-        return x + SwiGLU(self.d_ff, self.dtype, name="mlp")(h)
+            return x + post("ln_mlp_out", HeldExperts(
+                **spec.held._asdict(), dtype=self.dtype, name="moe")(h))
+        return x + post("ln_mlp_out",
+                        SwiGLU(self.d_ff, self.dtype, name="mlp")(h))
 
 
 @functools.cache
@@ -1639,8 +1681,16 @@ class TransformerLM(nn.Module):
     rope_dims: int = 0            # rotated dims of a head; 0 = all of them
     rope_theta: float = 10000.0
     qk_norm: bool = False         # RMSNorm over the head on q and k
-    attn_gate: bool = False       # sigmoid output gate from a doubled q
+    attn_gate: Any = False        # sigmoid output gate from a doubled q;
+    #                               'own': from a projection of its own
     norm_zero_centered: bool = False   # x_hat * (1 + w), w around 0
+    # per-layer pattern of windowed and full attention: the window of each
+    # 'full' layer (0: causal; () = none windowed), and whether each layer
+    # rotates q and k (() = every layer does)
+    layer_windows: tuple = ()
+    layer_rotates: tuple = ()
+    post_norms: bool = False      # a norm on each sublayer's output too
+    embed_scale: float = 1.0      # the embedding's factor
     gdn_key_heads: int = 0        # linear attention: key heads,
     gdn_value_heads: int = 0      # value heads,
     gdn_key_dim: int = 0          # their head sizes,
@@ -1683,7 +1733,9 @@ class TransformerLM(nn.Module):
         return bool(self.layer_kinds or self.n_kv_heads or self.rope_dims
                     or self.qk_norm or self.attn_gate
                     or self.norm_zero_centered
-                    or self.moe_dispatch == "held")
+                    or self.moe_dispatch == "held"
+                    or self.layer_windows or self.layer_rotates
+                    or self.post_norms)
 
     def block_specs(self, is_moe):
         """A :class:`BlockSpec` a layer, or None a layer for the dense
@@ -1691,8 +1743,11 @@ class TransformerLM(nn.Module):
         if not self.hybrid:
             return [None] * self.n_layers
         kinds = self.layer_kinds or ("full",) * self.n_layers
-        if len(kinds) != self.n_layers:
-            raise ValueError(f"{len(kinds)} layer kinds for "
+        windows = self.layer_windows or (0,) * self.n_layers
+        rotates = self.layer_rotates or (True,) * self.n_layers
+        if not len(kinds) == len(windows) == len(rotates) == self.n_layers:
+            raise ValueError(f"{len(kinds)} layer kinds, {len(windows)} "
+                             f"windows, {len(rotates)} rotations for "
                              f"{self.n_layers} layers")
         held = None
         if self.moe_dispatch == "held":
@@ -1717,12 +1772,15 @@ class TransformerLM(nn.Module):
                           rope_dims=self.rope_dims, qk_norm=self.qk_norm,
                           attn_gate=self.attn_gate,
                           norm_zero_centered=self.norm_zero_centered,
+                          window=int(window), rotate=bool(rotate),
+                          post_norms=self.post_norms,
                           gdn=gdn if kind == "linear" else None,
                           held=held if moe else None,
                           kda=kda if kind == "kda" else None,
                           mla=mla if kind == "mla" else None,
                           norm_eps=self.norm_eps)
-                for kind, moe in zip(kinds, is_moe)]
+                for kind, moe, window, rotate
+                in zip(kinds, is_moe, windows, rotates)]
 
     def cache_shapes(self, batch_size: int, per_slot_index: bool = False,
                      kv_dtype=None):
@@ -1878,8 +1936,9 @@ class TransformerLM(nn.Module):
                         batch, seq, self.d_model, self.n_heads, spec.mla,
                         itemsize)
                 return remat_plan.residual_bytes(
-                    batch, seq, self.d_model, self.n_heads, 0, itemsize,
-                    attn_width=attn_width)
+                    batch, seq, self.d_model, self.n_heads,
+                    0 if spec.held else self.d_ff, itemsize,
+                    attn_width=attn_width, own_gate=spec.attn_gate == "own")
 
             costs = [cost(spec) for spec in specs]
             live = max(remat_plan.hybrid_block_live_bytes(
@@ -1887,7 +1946,8 @@ class TransformerLM(nn.Module):
                 attn_width=attn_width if spec.kind == "full" else 0,
                 gdn=spec.gdn, held=spec.held,
                 d_ff=0 if spec.held else self.d_ff, kda=spec.kda,
-                mla=(self.n_heads, spec.mla) if spec.mla else None)
+                mla=(self.n_heads, spec.mla) if spec.mla else None,
+                post_norms=spec.post_norms)
                 for spec in specs)
             held = remat_plan.model_held_bytes(
                 batch, seq, self.d_model, 0, self.n_layers, vocab,
@@ -1920,12 +1980,19 @@ class TransformerLM(nn.Module):
                 "decode=True on a hybrid model (linear-attention or latent-"
                 "attention layers, grouped-query or gated attention, held "
                 "experts, an untied head): it trains only; serving it needs a recurrent state "
-                "beside the K/V pages and K/V heads in the cached attention")
+                "beside the K/V pages and K/V heads in the cached attention"
+                + ("; a windowed layer also needs a cache of its last "
+                   f"{max(self.layer_windows)} keys alone (a page lifetime "
+                   "of its own in serve/paged.py)"
+                   if any(self.layer_windows) else ""))
         # the model's own two ops outside any flax submodule carry a scope
         # of their own (obs/trace.py:DEVICE_SCOPES), or a device trace can
         # tell them from the blocks by operand names alone
         with jax.named_scope("embed"):
-            x = jnp.take(emb, tokens, axis=0).astype(self.dtype)
+            x = jnp.take(emb, tokens, axis=0)
+            if self.embed_scale != 1.0:
+                x = x * self.embed_scale
+            x = x.astype(self.dtype)
         if self.hybrid:
             # the rotation runs outside the kernels, over the rotated dims
             # and the traced positions alone

@@ -40,8 +40,13 @@ def _lib_path() -> str:
 
 
 def _compile(out: str) -> bool:
+    # a name of this process's own: several processes may build at once (six
+    # test workers importing this on a machine that has no library yet), and
+    # with one shared name the first ``os.replace`` takes the file from
+    # under the others
+    tmp = f"{out}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           _SRC, "-o", out + ".tmp", "-lz"]
+           _SRC, "-o", tmp, "-lz"]
     try:
         r = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
     except (OSError, subprocess.TimeoutExpired) as e:
@@ -50,7 +55,7 @@ def _compile(out: str) -> bool:
     if r.returncode != 0:
         log.warning("native build failed:\n%s", r.stderr[-2000:])
         return False
-    os.replace(out + ".tmp", out)
+    os.replace(tmp, out)
     return True
 
 

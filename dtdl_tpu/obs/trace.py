@@ -189,9 +189,12 @@ REENTERED_SCOPES = frozenset({"experts", "rematted_computation"})
 
 # flax module scopes of models/transformer.py (flax puts them there; this
 # repo only names the modules) -> component
+# (``ln_attn_out``, ``ln_mlp_out``: the norms on the sublayers' outputs of a
+# block with ``post_norms``)
 MODULE_SCOPES = {
     "attn": "attn_other", "mlp": "mlp", "moe": "moe",
     "ln_attn": "norm", "ln_mlp": "norm", "ln_f": "norm",
+    "ln_attn_out": "norm", "ln_mlp_out": "norm",
 }
 _ATTN_PROJECTIONS = frozenset({"q", "k", "v", "out"})
 # latent attention's (``kv_a``, ``kv_b``: its two steps to keys and values)
@@ -210,8 +213,9 @@ _KDA_MODULES = dict(
 _RULE_MODULES = {"gdn": _GDN_MODULES, "kda": _KDA_MODULES}
 _MOE_MODULES = {"router": "moe_router", "shared": "moe_shared",
                 "experts": "moe_experts"}
+# (``gate_proj``: the output gate's projection of its own, ``gate='own'``)
 _ATTN_OTHER = {"gate": "attn_gate", "q_norm": "norm", "k_norm": "norm",
-               "kv_norm": "norm"}
+               "kv_norm": "norm", "gate_proj": "attn_proj"}
 
 # pallas_call ``name=`` -> component.  On the chip the kernel's HLO
 # instruction takes this name (``%flash_fwd.<n> = ... custom-call(...)
@@ -219,6 +223,10 @@ _ATTN_OTHER = {"gate": "attn_gate", "q_norm": "norm", "k_norm": "norm",
 # kernel's own ops in a name stack.
 KERNEL_NAMES = {
     "flash_fwd": "flash", "flash_bwd_dq": "flash", "flash_bwd_dkv": "flash",
+    # the same three under a window (a band under the diagonal): a component
+    # of their own, so that a trace tells the windowed layers from the full
+    "flash_swa_fwd": "flash_swa", "flash_swa_bwd_dq": "flash_swa",
+    "flash_swa_bwd_dkv": "flash_swa",
     "paged_attn": "paged_attn",
     "moe_gmm": "moe_gmm", "moe_tgmm": "moe_gmm",
     "gdn_chunk_fwd": "gdn", "gdn_chunk_bwd": "gdn",
@@ -244,7 +252,8 @@ def device_component(name_stack: str):
 
     ``component`` is a member of :data:`DEVICE_SCOPES`, a value of
     :data:`MODULE_SCOPES` or :data:`KERNEL_NAMES`, ``attn_proj`` for
-    ``attn/{q,k,v,out,kv_a,kv_b}``, ``attn_gate`` for ``attn/gate``; under
+    ``attn/{q,k,v,out,gate_proj,kv_a,kv_b}``, ``attn_gate`` for
+    ``attn/gate``; under
     a Gated DeltaNet module ``gdn_proj`` (``gdn/{in_qkvz,in_ba,out}``),
     ``gdn_conv``, ``gdn`` for the delta rule itself (``gdn/gdn``) and
     ``gdn_other`` for the rest; under a Kimi Delta Attention module the same

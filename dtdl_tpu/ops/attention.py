@@ -151,10 +151,13 @@ def _unrotate_f32(g, c, s):
 
 
 
-def mha_reference(q, k, v, *, causal: bool = True, scale: float | None = None):
+def mha_reference(q, k, v, *, causal: bool = True, scale: float | None = None,
+                  window: int | None = None):
     """Dense reference attention (numerics oracle for the kernels).
 
     q,k,v: [batch, heads, seq, head_dim]  (k/v seq may differ from q's).
+    ``window``: with ``causal``, query ``i`` (bottom-aligned) sees the
+    ``window`` keys that end with its own, ``i - window < j <= i``.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -163,7 +166,11 @@ def mha_reference(q, k, v, *, causal: bool = True, scale: float | None = None):
     if causal:
         sq, sk = q.shape[2], k.shape[2]
         mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq - window)
         logits = jnp.where(mask, logits, NEG_INF)
+    elif window is not None:
+        raise ValueError("a window is a band under the causal diagonal")
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
 
@@ -215,6 +222,25 @@ _BLOCK_TABLE = _build_block_table()
 _BLOCK_DEFAULT = (1024, 1024)
 
 
+def _build_window_table():
+    """(head_dim, window bucket) -> (block_q, block_k) of a windowed call
+    whose row is longer than its window (one that is not takes the causal
+    entry: its band is the causal triangle).
+
+    Square blocks of half the window's bucket, between 128 and 1024: the
+    band is then two blocks wide and a q block touches three k blocks (two
+    whole and the two halves the diagonals cut), so two thirds of what the
+    kernels multiply is the band's, whatever the window.  Measured at one
+    point alone, a window of 2048 in rows of 8,191 at a head of 128
+    (PERF.md section 6, PR 35); every other entry is that ratio carried
+    over, not a sweep."""
+    return {(hd, w): (min(1024, max(128, w // 2)),) * 2
+            for hd in (16, 32, 64, 128, 192) for w in _SEQ_BUCKETS}
+
+
+_WINDOW_TABLE = _build_window_table()
+
+
 def block_table_entry(head_dim: int, seq: int, causal: bool = True):
     """The explicit autotune-table entry covering (head_dim, seq, causal),
     or None if the geometry has no entry (callers then get
@@ -224,15 +250,21 @@ def block_table_entry(head_dim: int, seq: int, causal: bool = True):
 
 
 def resolve_blocks(head_dim: int, seq_q: int, seq_k: int | None = None, *,
-                   causal: bool = True, strict: bool = False):
+                   causal: bool = True, strict: bool = False,
+                   window: int | None = None):
     """(block_q, block_k) for a kernel geometry, from the autotune table.
 
     ``strict=True`` raises instead of falling back to the default — the
     preset-config receipt tests use it to pin that every shipped model
-    geometry resolves to an explicit, swept entry.
+    geometry resolves to an explicit, swept entry.  ``window``: a band
+    narrower than the row takes its entry of :data:`_WINDOW_TABLE`.
     """
     seq = max(int(seq_q), int(seq_k if seq_k is not None else seq_q))
     entry = block_table_entry(head_dim, seq, causal)
+    if window is not None and window < seq:
+        bucket = next((b for b in _SEQ_BUCKETS if window <= b),
+                      _SEQ_BUCKETS[-1])
+        entry = _WINDOW_TABLE.get((int(head_dim), bucket))
     if entry is None:
         if strict:
             raise ValueError(
@@ -248,6 +280,73 @@ def resolve_blocks(head_dim: int, seq_q: int, seq_k: int | None = None, *,
 # causal DMA-eliding index maps
 # ---------------------------------------------------------------------------
 
+class _Band:
+    """The blocks a band of ``window`` keys under the bottom-aligned
+    diagonal touches, from shapes alone: query ``i`` sees keys ``j`` with
+    ``i + off - window < j <= i + off``.  The windowed kernels' sequential
+    grid axis walks ``k_steps`` (``q_steps`` in the dkv grid) blocks from
+    ``first_k(i)`` (``first_q(j)``), the most any block of the other side
+    touches, and not the whole row; a step past ``last_k(i)`` (``last_q(j)``)
+    repeats that block's index, so it fetches nothing, and its guard is
+    false.  Every method takes a Python int or a traced one (``hi``/``lo``:
+    the matching ``max``/``min``)."""
+
+    def __init__(self, window, block_q, block_k, sq, sk):
+        self.window, self.bq, self.bk = int(window), block_q, block_k
+        self.off = sk - sq
+        self.nq, self.nk = pl.cdiv(sq, block_q), pl.cdiv(sk, block_k)
+        self.k_steps = max(self.last_k(i) - self.first_k(i) + 1
+                           for i in range(self.nq))
+        self.q_steps = max(self.last_q(j) - self.first_q(j) + 1
+                           for j in range(self.nk))
+
+    def first_k(self, i, hi=max):
+        return hi(i * self.bq + self.off - self.window + 1, 0) // self.bk
+
+    def last_k(self, i, hi=max, lo=min):
+        return lo(hi((i + 1) * self.bq + self.off - 1, 0) // self.bk,
+                  self.nk - 1)
+
+    def first_q(self, j, hi=max, lo=min):
+        return lo(hi(j * self.bk - self.off, 0) // self.bq, self.nq - 1)
+
+    def last_q(self, j, hi=max, lo=min):
+        return lo(hi((j + 1) * self.bk - self.off + self.window - 2, 0)
+                  // self.bq, self.nq - 1)
+
+    def k_block(self, i, step):
+        """The k block of grid step ``step`` of q block ``i`` (traced)."""
+        return self.first_k(i, jnp.maximum) + step
+
+    def q_block(self, j, step):
+        return self.first_q(j, jnp.maximum, jnp.minimum) + step
+
+    def kmap(self, b, i, j):
+        """Index map of the K-side blocks in the fwd/dq grids: step ``j``
+        is the band's ``j``-th block, a step past its last repeats it."""
+        return (b, jnp.minimum(self.k_block(i, j), self.last_k(
+            i, jnp.maximum, jnp.minimum)), 0)
+
+    def qmap(self, b, j, i):
+        """Index map of the Q-side blocks in the dkv grid: the dead steps
+        sit at the END of the walk and clamp back to the last q block."""
+        return (b, jnp.minimum(self.q_block(j, i), self.last_q(
+            j, jnp.maximum, jnp.minimum)), 0)
+
+    def live(self, qi, ki):
+        """Whether tile ``(qi, ki)`` holds a pair of the band (traced): under
+        the diagonal, over the band's lower edge, inside both rows."""
+        return ((ki * self.bk < (qi + 1) * self.bq + self.off)
+                & ((ki + 1) * self.bk > qi * self.bq + self.off
+                   - self.window + 1)
+                & (ki < self.nk) & (qi < self.nq))
+
+    def mask(self, rows, cols):
+        """The band's pairs of a tile, from its row and column ids."""
+        return ((rows + self.off >= cols)
+                & (rows + self.off - self.window < cols))
+
+
 def _kmaps(causal, block_q, block_k, off, lead_b: bool):
     """Index map for K-side blocks in the fwd/dq grids ``(b, i, j)``.
 
@@ -256,6 +355,7 @@ def _kmaps(causal, block_q, block_k, off, lead_b: bool):
     index repeats, so masked tiles cost no bandwidth (their compute is
     already skipped by the ``pl.when`` guard).  ``lead_b=False`` builds
     the same map for the [S, d] rope tables, which have no batch dim.
+    (A windowed call's map is its band's own: :meth:`_Band.kmap`.)
     """
     if not causal:
         if lead_b:
@@ -274,7 +374,8 @@ def _qmaps(causal, block_q, block_k, off, nq, lead_b: bool):
     """Index map for Q-side blocks in the dkv grid ``(b, j, i)``: the
     masked iterations sit at the START of the q loop, so they clamp
     forward to the first contributing q block (which the pipeline then
-    prefetches during the dead iterations instead of refetching it)."""
+    prefetches during the dead iterations instead of refetching it).
+    (A windowed call's map is its band's own: :meth:`_Band.qmap`.)"""
     if not causal:
         if lead_b:
             return lambda b, j, i: (b, i, 0)
@@ -294,16 +395,19 @@ def _qmaps(causal, block_q, block_k, off, nq, lead_b: bool):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
-                seq_k, off, rope):
+                seq_k, off, rope, band=None):
     if rope:
         (qc_ref, qs_ref, kc_ref, ks_ref,
          o_ref, lse_ref, m_scr, l_scr, acc_scr, qrot_scr) = rest
     else:
         o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
-    qi, ki = pl.program_id(1), pl.program_id(2)
+    # ``step`` walks the sequential axis; it is the k block itself unless a
+    # band starts the walk at the band's first block
+    qi, step = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
+    ki = step if band is None else band.k_block(qi, step)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -316,6 +420,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
 
     # tiles strictly above the (bottom-aligned) diagonal contribute nothing
     guard = (ki * block_k < (qi + 1) * block_q + off) if causal else (ki >= 0)
+    if band is not None:
+        guard = band.live(qi, ki)
 
     @pl.when(guard)
     def _compute():
@@ -339,10 +445,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
             # query row i attends keys <= i + (seq_k - seq_q)
             rows = qi * block_q + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            s = jnp.where(rows + off >= cols, s, NEG_INF)
+            s = jnp.where(rows + off >= cols if band is None
+                          else band.mask(rows, cols), s, NEG_INF)
         if seq_k % block_k:                        # mask padded tail keys
             s = jnp.where(cols < seq_k, s, NEG_INF)
 
+        # a row none of whose keys lie in this tile of a band reads p = 1
+        # here (both maxima are NEG_INF); the first tile that holds one of
+        # its keys (its own is always one) scales that away by alpha = 0
         m_prev = m_scr[:]                          # [bq, 1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -358,7 +468,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
         acc_scr[:] = acc_scr[:] * alpha + pv
         m_scr[:] = m_new
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == nk - 1)
     def _finalize():
         l = l_scr[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -367,17 +477,29 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
         lse_ref[0] = (m_scr[:] + jnp.log(l_safe)).reshape(1, -1)
 
 
-def _fwd(q, k, v, tabs, scale, causal, block_q, block_k):
+def _band_of(window, block_q, block_k, sq, sk):
+    """The :class:`_Band` of a windowed call (causal, no fused rotation:
+    :func:`flash_attention` refuses the rest), None for a call without a
+    window: that one lowers to what it always did."""
+    return None if window is None else _Band(window, block_q, block_k,
+                                             sq, sk)
+
+
+def _fwd(q, k, v, tabs, scale, causal, block_q, block_k, window=None):
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     rope = tabs is not None
-    grid = (bh, pl.cdiv(sq, block_q), pl.cdiv(sk, block_k))
+    band = _band_of(window, block_q, block_k, sq, sk)
+    grid = (bh, pl.cdiv(sq, block_q),
+            pl.cdiv(sk, block_k) if band is None else band.k_steps)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, seq_k=sk, off=sk - sq, rope=rope)
-    kmap = _kmaps(causal, block_q, block_k, sk - sq, lead_b=True)
+        block_q=block_q, block_k=block_k, seq_k=sk, off=sk - sq, rope=rope,
+        band=band)
+    kmap = band.kmap if band else _kmaps(causal, block_q, block_k, sk - sq,
+                                         lead_b=True)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_k, d), kmap),
@@ -395,7 +517,7 @@ def _fwd(q, k, v, tabs, scale, causal, block_q, block_k):
         operands += tabs
     o, lse = pl.pallas_call(
         kernel,
-        name="flash_fwd",
+        name="flash_fwd" if band is None else "flash_swa_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -431,16 +553,18 @@ def _scratch(block_q, d):
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                   scale, causal, block_q, block_k, seq_k, off, rope):
+                   scale, causal, block_q, block_k, seq_k, off, rope,
+                   band=None):
     if rope:
         (qc_ref, qs_ref, kc_ref, ks_ref,
          dq_ref, dq_scr, qrot_scr) = rest
     else:
         dq_ref, dq_scr = rest
-    qi, ki = pl.program_id(1), pl.program_id(2)
+    qi, step = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
+    ki = step if band is None else band.k_block(qi, step)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
         if rope:
@@ -448,6 +572,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             qrot_scr[:] = _rotate(q_ref[0], qc_ref[:], qs_ref[:])
 
     guard = (ki * block_k < (qi + 1) * block_q + off) if causal else (ki >= 0)
+    if band is not None:
+        guard = band.live(qi, ki)
 
     @pl.when(guard)
     def _compute():
@@ -472,7 +598,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         if causal:
             rows = qi * block_q + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            s = jnp.where(rows + off >= cols, s, NEG_INF)
+            s = jnp.where(rows + off >= cols if band is None
+                          else band.mask(rows, cols), s, NEG_INF)
         if seq_k % block_k:
             s = jnp.where(cols < seq_k, s, NEG_INF)
             k = _zero_pad_rows(k, ki * block_k, seq_k)
@@ -486,7 +613,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == nk - 1)
     def _finalize():
         dq = dq_scr[:]
         if rope:
@@ -498,16 +625,18 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                    scale, causal, block_q, block_k, seq_k, seq_q, off, rope):
+                    scale, causal, block_q, block_k, seq_k, seq_q, off, rope,
+                    band=None):
     if rope:
         (qc_ref, qs_ref, kc_ref, ks_ref,
          dk_ref, dv_ref, dk_scr, dv_scr, krot_scr) = rest
     else:
         dk_ref, dv_ref, dk_scr, dv_scr = rest
-    ki, qi = pl.program_id(1), pl.program_id(2)
+    ki, step = pl.program_id(1), pl.program_id(2)
     nq = pl.num_programs(2)
+    qi = step if band is None else band.q_block(ki, step)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -517,6 +646,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             krot_scr[:] = _rotate(k_ref[0], kc_ref[:], ks_ref[:])
 
     guard = ((qi + 1) * block_q + off > ki * block_k) if causal else (qi >= 0)
+    if band is not None:
+        guard = band.live(qi, ki)
 
     @pl.when(guard)
     def _compute():
@@ -544,7 +675,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         if causal:
             rows = qi * block_q + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            s = jnp.where(rows + off >= cols, s, NEG_INF)
+            s = jnp.where(rows + off >= cols if band is None
+                          else band.mask(rows, cols), s, NEG_INF)
         if seq_k % block_k:
             s = jnp.where(cols < seq_k, s, NEG_INF)
         p = jnp.exp(s - lse)                       # [bq, bk] f32
@@ -559,7 +691,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)    # [bk, d]
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == nq - 1)
     def _finalize():
         dk = dk_scr[:]
         if rope:
@@ -568,19 +700,23 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd(scale, causal, block_q, block_k, res, do_4d, tabs=None):
+def _bwd(scale, causal, block_q, block_k, res, do_4d, tabs=None,
+         window=None):
     q, k, v, o, lse = res
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     rope = tabs is not None
+    band = _band_of(window, block_q, block_k, sq, sk)
     do = do_4d
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, None, :]          # [bh, 1, sq]
 
-    kmap = _kmaps(causal, block_q, block_k, sk - sq, lead_b=True)
-    grid_dq = (bh, pl.cdiv(sq, block_q), pl.cdiv(sk, block_k))
+    kmap = band.kmap if band else _kmaps(causal, block_q, block_k, sk - sq,
+                                         lead_b=True)
+    grid_dq = (bh, pl.cdiv(sq, block_q),
+               pl.cdiv(sk, block_k) if band is None else band.k_steps)
     in_specs_dq = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_k, d), kmap),
@@ -602,8 +738,8 @@ def _bwd(scale, causal, block_q, block_k, res, do_4d, tabs=None):
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_k=sk,
-                          off=sk - sq, rope=rope),
-        name="flash_bwd_dq",
+                          off=sk - sq, rope=rope, band=band),
+        name="flash_bwd_dq" if band is None else "flash_swa_bwd_dq",
         grid=grid_dq,
         in_specs=in_specs_dq,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -615,14 +751,16 @@ def _bwd(scale, causal, block_q, block_k, res, do_4d, tabs=None):
     )(*operands)
 
     nq = pl.cdiv(sq, block_q)
-    qmap = _qmaps(causal, block_q, block_k, sk - sq, nq, lead_b=True)
+    qmap = band.qmap if band else _qmaps(causal, block_q, block_k, sk - sq,
+                                         nq, lead_b=True)
     qmap_s = _qmaps(causal, block_q, block_k, sk - sq, nq, lead_b=False)
 
     def _lse_map(b, j, i):
         bi, ii, _ = qmap(b, j, i)
         return (bi, 0, ii)
 
-    grid_dkv = (bh, pl.cdiv(sk, block_k), nq)
+    grid_dkv = (bh, pl.cdiv(sk, block_k),
+                nq if band is None else band.q_steps)
     in_specs_dkv = [
         pl.BlockSpec((1, block_q, d), qmap),
         pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
@@ -643,8 +781,8 @@ def _bwd(scale, causal, block_q, block_k, res, do_4d, tabs=None):
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_k=sk,
-                          seq_q=sq, off=sk - sq, rope=rope),
-        name="flash_bwd_dkv",
+                          seq_q=sq, off=sk - sq, rope=rope, band=band),
+        name="flash_bwd_dkv" if band is None else "flash_swa_bwd_dkv",
         grid=grid_dkv,
         in_specs=in_specs_dkv,
         out_specs=[
@@ -685,6 +823,29 @@ def _flash_bwd(scale, causal, block_q, block_k, res, do):
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_swa(q, k, v, scale, block_q, block_k, window):
+    """:func:`_flash` under a band of ``window`` keys (always causal): calls
+    of their own names, so that a trace tells them from the full ones."""
+    o, _ = _fwd(q, k, v, None, scale, True, block_q, block_k, window)
+    return o
+
+
+def _flash_swa_fwd(q, k, v, scale, block_q, block_k, window):
+    o, lse = checkpoint_name(
+        _fwd(q, k, v, None, scale, True, block_q, block_k, window),
+        FLASH_OUT)
+    q, k, v = checkpoint_name((q, k, v), FLASH_QKV)
+    return o, (q, k, v, o, lse)
+
+
+def _flash_swa_bwd(scale, block_q, block_k, window, res, do):
+    return _bwd(scale, True, block_q, block_k, res, do, window=window)
+
+
+_flash_swa.defvjp(_flash_swa_fwd, _flash_swa_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
@@ -732,10 +893,59 @@ def _legal_block(seq: int, block: int) -> int:
     return seq if seq <= b else b
 
 
+def _call_blocks(d, sq, sk, causal, block_q, block_k, window=None):
+    """The blocks a call runs at: the table's unless given, made legal."""
+    if block_q is None or block_k is None:
+        auto_q, auto_k = resolve_blocks(d, sq, sk, causal=causal,
+                                        window=window)
+        block_q = block_q if block_q is not None else auto_q
+        block_k = block_k if block_k is not None else auto_k
+    return _legal_block(sq, block_q), _legal_block(sk, block_k)
+
+
+def band_tiles(seq_q: int, seq_k: int, head_dim: int, window: int,
+               block_q: int | None = None,
+               block_k: int | None = None) -> dict:
+    """What a windowed call costs, from its shapes alone, a head a row: the
+    blocks it runs at; the sequential grid steps of the forward (and dq)
+    grid and of the dkv grid; of those the tiles each **computes** (its
+    guard is true: the rest neither multiply nor fetch); the tiles that
+    hold a pair of the band (``needed``: the least any kernel at these
+    blocks computes) and its pairs; and the tiles a causal call at these
+    blocks computes.  The compile account keeps one of these a traced
+    windowed layer (runtime/compile_cache.py:record_window_call)."""
+    bq, bk = _call_blocks(head_dim, seq_q, seq_k, True, block_q, block_k,
+                          window)
+    band = _Band(window, bq, bk, seq_q, seq_k)
+    off = seq_k - seq_q
+    # the kernels' own walk and guard, step by step
+    computed = sum(bool(band.live(i, band.first_k(i) + step))
+                   for i in range(band.nq) for step in range(band.k_steps))
+    computed_dkv = sum(bool(band.live(band.first_q(j) + step, j))
+                       for j in range(band.nk)
+                       for step in range(band.q_steps))
+
+    def holds(i, j, w):         # a pair of the band (w: its width) in tile
+        return (j * bk < min((i + 1) * bq, seq_q) + off
+                and min((j + 1) * bk, seq_k) > i * bq + off - w + 1)
+
+    tiles = [(i, j) for i in range(band.nq) for j in range(band.nk)]
+    pairs = sum(max(0, min(i + off, seq_k - 1) - max(i + off - window + 1, 0)
+                    + 1) for i in range(seq_q))
+    return {"block_q": bq, "block_k": bk, "window": int(window),
+            "grid_steps": band.nq * band.k_steps,
+            "grid_steps_dkv": band.nk * band.q_steps,
+            "computed_tiles": computed, "computed_tiles_dkv": computed_dkv,
+            "needed_tiles": sum(holds(i, j, window) for i, j in tiles),
+            "causal_tiles": sum(holds(i, j, seq_k + seq_q) for i, j in tiles),
+            "band_pairs": pairs}
+
+
 def flash_attention(q, k, v, *, causal: bool = True,
                     scale: float | None = None,
                     block_q: int | None = None, block_k: int | None = None,
-                    rope=None, rope_positions=None):
+                    rope=None, rope_positions=None,
+                    window: int | None = None):
     """Flash attention over [batch, heads, seq, head_dim] tensors.
 
     ``v`` may have a head size of its own (latent attention: query/key 192,
@@ -763,21 +973,33 @@ def flash_attention(q, k, v, *, causal: bool = True,
     position (sequence-parallel shards, zigzag layouts); the default is
     k at 0..sk-1 with q bottom-aligned (the self-attention / training
     case: positions 0..seq-1 for both).
+
+    ``window=W`` (with ``causal``, without ``rope``): query ``i``,
+    bottom-aligned as above, sees keys ``j`` with ``i - W < j <= i``: ``W``
+    keys with its own.  The three kernels mask by the band, a tile wholly
+    outside it is neither computed nor fetched from either side, and the
+    sequential grid axis spans the blocks the band touches (three at two
+    blocks' width), not the row: a call's cost follows from its shapes
+    (:func:`band_tiles`).  The calls are named ``flash_swa_fwd``,
+    ``flash_swa_bwd_dq``, ``flash_swa_bwd_dkv``.  ``window=None`` is the
+    call this function always made.
     """
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    if block_q is None or block_k is None:
-        auto_q, auto_k = resolve_blocks(d, sq, sk, causal=causal)
-        block_q = block_q if block_q is not None else auto_q
-        block_k = block_k if block_k is not None else auto_k
-    block_q = _legal_block(sq, block_q)
-    block_k = _legal_block(sk, block_k)
+    if window is not None and (window < 1 or not causal or rope is not None):
+        raise ValueError(
+            f"window={window}: a band of at least one key under the causal "
+            f"diagonal; the fused rotation takes none (rotate outside)")
+    block_q, block_k = _call_blocks(d, sq, sk, causal, block_q, block_k,
+                                    window)
     qf = q.reshape(b * h, sq, d)
     kf = k.reshape(b * h, sk, d)
     vf = v.reshape(b * h, sk, dv)
-    if rope is None:
+    if window is not None:
+        o = _flash_swa(qf, kf, vf, scale, block_q, block_k, int(window))
+    elif rope is None:
         o = _flash(qf, kf, vf, scale, causal, block_q, block_k)
     else:
         cos, sin = rope

@@ -45,7 +45,13 @@ implementation of the delta rule's chunk-local stage its shapes chose
 :func:`gdn_paths`, and ``gdn_kernel_calls``, ``gdn_jnp_calls`` in the totals;
 a Kimi Delta Attention layer the same for the channel-wise rule
 (:func:`record_kda_path`, :func:`kda_paths`, ``kda_kernel_calls``,
-``kda_jnp_calls``).
+``kda_jnp_calls``).  A windowed attention layer adds what its three flash
+calls cost, from shapes alone (``ops/attention.py:band_tiles``: the blocks,
+the grid steps, the tiles computed beside the tiles the band needs and the
+tiles a causal call would compute): :func:`record_window_call`,
+:func:`window_calls`, and ``swa_calls``, ``swa_computed_tiles``,
+``swa_needed_tiles``, ``swa_causal_tiles`` (a head a row, summed over the
+traced layers) in the totals.
 """
 
 from __future__ import annotations
@@ -86,6 +92,7 @@ _PLANS: list = []       # models.remat_plan.RematPlan, one a traced step
 _EXPERT_BUFFERS: list = []      # one a held-experts layer a traced step
 _GDN_PATHS: list = []           # one a call of the delta rule a traced step
 _KDA_PATHS: list = []           # one a call of the channel-wise rule
+_WINDOW_CALLS: list = []        # one a windowed attention layer
 _listening = False
 
 
@@ -161,6 +168,20 @@ def kda_paths() -> list:
     return list(_KDA_PATHS)
 
 
+def record_window_call(fun_name: str, shapes: tuple, tiles: dict) -> None:
+    """Keep what a windowed attention layer's flash calls cost in the train
+    step that was just traced (models/transformer.py:Attention): ``shapes``
+    is the call's ``(rows, heads, positions, head size)``, ``tiles`` the
+    counts of ``ops/attention.py:band_tiles`` a head a row."""
+    _WINDOW_CALLS.append(dict(tiles, fun_name=fun_name,
+                              shapes=tuple(shapes)))
+
+
+def window_calls() -> list:
+    """The windowed layers so far, oldest first (a copy)."""
+    return list(_WINDOW_CALLS)
+
+
 def expert_buffers() -> list:
     """The expert buffers so far, oldest first (a copy)."""
     return list(_EXPERT_BUFFERS)
@@ -217,6 +238,10 @@ def compile_totals() -> dict:
         totals["moe_first_buffer_rows"] = newest["first_rows"]
         totals["moe_row_tile"] = newest["row_tile"]
         totals["moe_expected_rows"] = newest["expected_rows"]
+    if _WINDOW_CALLS:
+        totals["swa_calls"] = len(_WINDOW_CALLS)
+        for key in ("computed_tiles", "needed_tiles", "causal_tiles"):
+            totals[f"swa_{key}"] = sum(c[key] for c in _WINDOW_CALLS)
     for rule, rows in (("gdn", _GDN_PATHS), ("kda", _KDA_PATHS)):
         for path in sorted({r["path"] for r in rows}):
             totals[f"{rule}_{path}_calls"] = sum(

@@ -89,6 +89,132 @@ def test_flash_takes_a_value_head_size_of_its_own(dims, causal):
                                    atol=2e-4, rtol=1e-4)
 
 
+# (rows, keys, window, block_q, block_k): the band narrower than a block,
+# equal to one, wider than one, wider than the row (then the causal
+# triangle), meeting a padded tail on either side, with blocks that are not
+# square, and with more keys than queries (the diagonal bottom-aligned)
+_BANDS = [
+    (512, 512, 100, 128, 128), (512, 512, 128, 128, 128),
+    (512, 512, 300, 128, 128), (300, 300, 1000, 128, 128),
+    (500, 500, 256, 128, 128), (511, 511, 200, 256, 128),
+    (511, 511, 200, 128, 256), (200, 456, 64, 128, 128),
+    (300, 300, 1, 128, 128)]
+
+
+@pytest.mark.parametrize("sq, sk, window, bq, bk", _BANDS, ids=[
+    "narrower_than_a_block", "a_block_wide", "wider_than_a_block",
+    "wider_than_the_row", "padded_tail", "tall_blocks", "wide_blocks",
+    "more_keys_than_queries", "its_own_key_alone"])
+def test_windowed_flash_matches_dense(sq, sk, window, bq, bk):
+    """The three kernels under a band against ``mha_reference`` with the
+    same window: the output and all three gradients."""
+    from dtdl_tpu.ops.attention import band_tiles
+    q = _rand((1, 2, sq, 32), 0)
+    k, v = _rand((1, 2, sk, 32), 1), _rand((1, 2, sk, 32), 2)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=bq, block_k=bk)
+
+    def dense(q, k, v):
+        return mha_reference(q, k, v, causal=True, window=window)
+
+    with jax.default_matmul_precision("highest"):
+        out, ref = flash(q, k, v), dense(q, k, v)
+        g_flash = jax.grad(_sq_loss(flash), (0, 1, 2))(q, k, v)
+        g_ref = jax.grad(_sq_loss(dense), (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=5e-6, rtol=1e-5)
+    for a, b in zip(g_flash, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-4, rtol=1e-4)
+    if window >= sk:        # the band is the causal triangle
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(mha_reference(q, k, v, causal=True)),
+            atol=5e-6, rtol=1e-5)
+    # the call's cost follows from its shapes: the tiles the kernels compute
+    # (their own walk and guard) are the tiles that hold a pair of the band
+    # by an explicit mask, never more than a causal call's, and the grid
+    # spans the band's blocks where that is less than the row's
+    tiles = band_tiles(sq, sk, 32, window, bq, bk)
+    off = sk - sq
+    rows, cols = np.arange(sq)[:, None] + off, np.arange(sk)[None, :]
+    causal = rows >= cols
+    band = causal & (rows - window < cols)
+
+    def blocks(mask):
+        return sum(bool(mask[i:i + bq, j:j + bk].any())
+                   for i in range(0, sq, bq) for j in range(0, sk, bk))
+
+    assert tiles["needed_tiles"] == blocks(band)
+    assert tiles["causal_tiles"] == blocks(causal)
+    assert tiles["computed_tiles"] == tiles["computed_tiles_dkv"] \
+        == tiles["needed_tiles"] <= tiles["causal_tiles"]
+    assert tiles["band_pairs"] == int(band.sum())
+    nq, nk = -(-sq // bq), -(-sk // bk)
+    assert tiles["computed_tiles"] <= tiles["grid_steps"] <= nq * nk
+    assert tiles["computed_tiles"] <= tiles["grid_steps_dkv"] <= nq * nk
+
+
+def test_a_window_is_a_band_under_the_causal_diagonal_alone():
+    q = _rand((1, 1, 64, 16))
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, q, q, causal=False, window=8)
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, q, q, window=8, rope=rope_frequencies(16, 64))
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, q, q, window=0)
+    with pytest.raises(ValueError, match="window"):
+        mha_reference(q, q, q, causal=False, window=8)
+
+
+# sha256 of the lowered text of the call without a window, forward and all
+# three gradients, as the parent of PR 35 lowers it (bf16, 2 heads of 32,
+# 300 positions, 128 x 128 blocks; plain and with the fused rotation)
+_PARENT_TEXT = {
+    False: "0dddf4ae5852f4f57c756c6407377b87fd9dd42325d2f68e86ee67a336f9b0e9",
+    True: "4d3858374a9f8e8d5b5417e7d5a1dca3f8875d5cc9b4846a4ef055ff34e5580d",
+}
+
+
+@pytest.mark.parametrize("rope", [False, True], ids=["plain", "rope"])
+def test_without_a_window_the_call_lowers_to_the_parents_text(rope):
+    import hashlib
+    q = jax.ShapeDtypeStruct((1, 2, 300, 32), jnp.bfloat16)
+    tabs = rope_frequencies(32, 300) if rope else None
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=128, rope=tabs,
+            window=None).astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
+        q, q, q).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == _PARENT_TEXT[rope]
+
+
+def test_windowed_block_table_entries():
+    """A band narrower than the row takes square blocks of half its bucket
+    (the band two blocks wide, three computed a q block); one that is not
+    takes the causal entry."""
+    from dtdl_tpu.ops.attention import band_tiles, resolve_blocks
+    assert resolve_blocks(128, 8191, causal=True, window=2048,
+                          strict=True) == (1024, 1024)
+    assert resolve_blocks(128, 8191, causal=True, window=1024,
+                          strict=True) == (512, 512)
+    assert resolve_blocks(128, 8191, causal=True, window=100,
+                          strict=True) == (128, 128)
+    assert resolve_blocks(128, 2047, causal=True, window=4096) \
+        == resolve_blocks(128, 2047, causal=True)
+    # the Trinity cell's windowed layer, a head a row: 21 tiles computed of
+    # a grid of 24 steps, for 14.0 tiles' worth of pairs; a causal call 36
+    tiles = band_tiles(8191, 8191, 128, 2048)
+    assert (tiles["block_q"], tiles["block_k"]) == (1024, 1024)
+    assert (tiles["grid_steps"], tiles["computed_tiles"],
+            tiles["needed_tiles"], tiles["causal_tiles"]) == (24, 21, 21, 36)
+    assert tiles["band_pairs"] == 14_679_040
+
+
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_cross_attention(causal):
     """q shorter than k/v; causal must be bottom-aligned like the oracle.

@@ -344,9 +344,14 @@ def test_module_scopes_are_the_models_own_modules():
         k for k in params if not k.startswith("block_")}
     # the embedding is a parameter of the LM itself, under a scope
     assert len(blocks) == 2 and "embed" in DEVICE_SCOPES
-    assert modules - {"embed"} == set(MODULE_SCOPES)
+    # (the norms on the sublayers' outputs are a ``post_norms`` block's:
+    # ``test_windowed_module_scopes_are_the_models_own_modules``)
+    assert modules - {"embed"} == set(MODULE_SCOPES) - _POST_NORMS
     assert {name for block in blocks
             for name in block["attn"]} == set(_ATTN_PROJECTIONS)
+
+
+_POST_NORMS = {"ln_attn_out", "ln_mlp_out"}
 
 
 def _hybrid_lm(**over):
@@ -374,7 +379,8 @@ def test_hybrid_module_scopes_are_the_models_own_modules():
         == set(_GDN_MODULES)
     assert set(linear["moe"]) == set(_MOE_MODULES)
     assert set(full["attn"]) == set(_ATTN_PROJECTIONS) | (
-        set(_ATTN_OTHER) - {"gate", "kv_norm"})     # kv_norm: latent attention's
+        # kv_norm: latent attention's; gate_proj: a gate of its own
+        set(_ATTN_OTHER) - {"gate", "kv_norm", "gate_proj"})
     assert {"head", "embed", "ln_f"} == {k for k in params
                                          if not k.startswith("block_")}
     assert "head" in DEVICE_SCOPES and "gate" in DEVICE_SCOPES
@@ -536,7 +542,9 @@ def test_paged_pallas_call_carries_its_name():
         jnp.zeros((b, h, 1, d), jnp.float32))
     assert _pallas_names(jaxpr.jaxpr) == ["paged_attn"]
     assert set(KERNEL_NAMES) == {"flash_fwd", "flash_bwd_dq",
-                                 "flash_bwd_dkv", "paged_attn",
+                                 "flash_bwd_dkv", "flash_swa_fwd",
+                                 "flash_swa_bwd_dq", "flash_swa_bwd_dkv",
+                                 "paged_attn",
                                  "moe_gmm", "moe_tgmm",
                                  "gdn_chunk_fwd", "gdn_chunk_bwd",
                                  "kda_chunk_fwd", "kda_chunk_bwd"}
@@ -632,6 +640,136 @@ def test_the_lowered_tpu_step_holds_kda_kernels_at_kernel_sized_heads_alone(
     assert not [name for name in small if name.startswith("kda_")]
 
 
+def _windowed_lm(**over):
+    """A windowed layer with a dense FFN, a full layer and a windowed one
+    with held experts: grouped-query heads with q/k norm and a gate
+    projection of their own, four norms a block (the Trinity block)."""
+    kw = dict(vocab_size=64, d_model=16, n_layers=3, n_heads=4, n_kv_heads=2,
+              attn_head_dim=8, d_ff=32, max_seq=128, norm_eps=1e-5,
+              qk_norm=True, attn_gate="own", layer_windows=(24, 0, 24),
+              layer_rotates=(True, False, True), post_norms=True,
+              embed_scale=4.0, n_experts=2, moe_every=1,
+              first_dense_layers=1, moe_dispatch="held", moe_router_width=8,
+              moe_first_expert=2, moe_top_k=2, moe_d_ff=8, moe_shared_d_ff=8,
+              moe_router_act="sigmoid", moe_routed_scale=2.826,
+              moe_shared_gate=False, tie_embeddings=False)
+    return TransformerLM(**dict(kw, **over))
+
+
+def test_windowed_module_scopes_are_the_models_own_modules():
+    """The audit for the block of PR 35: the two norms on the sublayers'
+    outputs are module scopes, the gate's projection and the head norms are
+    named under ``attn``, and nothing else is new."""
+    params = jax.eval_shape(_windowed_lm().init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+    dense, full = params["block_0"], params["block_1"]
+    assert set(dense) == {"ln_attn", "attn", "ln_attn_out", "ln_mlp", "mlp",
+                          "ln_mlp_out"}
+    assert set(full) == set(dense) - {"mlp"} | {"moe"}
+    assert (set(dense) | set(full)) - {"attn", "mlp", "moe"} \
+        == {k for k, v in MODULE_SCOPES.items() if v == "norm"} - {"ln_f"}
+    assert _POST_NORMS <= set(MODULE_SCOPES)
+    assert set(full["attn"]) == set(_ATTN_PROJECTIONS) | {
+        "gate_proj", "q_norm", "k_norm"}
+    assert set(full["attn"]) - set(_ATTN_PROJECTIONS) <= set(_ATTN_OTHER)
+    assert _ATTN_OTHER["gate_proj"] == "attn_proj"
+    assert device_component(_FWD + "block_0/ln_attn_out/mul") == (
+        "norm", "forward")
+    assert device_component(
+        _REMAT + "block_2/attn/attn._grouped_attend/gate_proj/dot_general") \
+        == ("attn_proj", "recompute")
+    assert device_component(
+        _BWD + "jvp(TransformerLM)/block_2/attn/attn._grouped_attend/"
+        "flash_swa_bwd_dkv/mul") == ("flash_swa", "backward")
+
+
+def test_lowered_windowed_step_leaves_no_matmul_or_kernel_unscoped():
+    """The lowered train step of the Trinity block: every ``dot_general``
+    and every op of a kernel lies under a component, the windowed layers'
+    kernels under ``flash_swa`` and the full layer's under ``flash``, and
+    each shows forward, recompute and backward (rung 0 on the CPU)."""
+    model = _windowed_lm(attn_impl="flash", remat=True, dtype=jnp.bfloat16)
+    tokens = jnp.zeros((2, 72), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens[:, :-1])["params"]
+    state = TrainState.create(apply_fn=model.apply, params=params,
+                              tx=optax.adamw(3e-4))
+    lowered = make_lm_train_step(SingleDevice()).lower(state,
+                                                       {"tokens": tokens})
+    seen = _passes_by_component(lowered)
+    every = {"forward", "recompute", "backward"}
+    for component in ("flash_swa", "flash", "attn_proj", "attn_gate", "norm",
+                      "mlp", "moe_gmm", "moe_router", "moe_shared"):
+        assert seen[component] >= every, (component, seen.get(component))
+    assert seen["embed"] >= {"forward", "backward"}
+
+
+def test_every_op_of_the_rehearsed_trinity_step_has_a_component():
+    """The step ``benchmarks/run.py --rehearse`` drives for the Trinity cell
+    (the family's own keywords at the rehearsal's sizes): every
+    ``dot_general`` and every op of a kernel lies under a component, and so
+    does every other op that lies inside a block (the norms on the
+    sublayers' outputs, the gate, the rotation of the windowed layers)."""
+    import os
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(repo, "benchmarks")
+    for path in (bench, repo):
+        if path not in sys.path:
+            sys.path.insert(0, path)
+    import run as harness
+    from runners import train
+    manifest = harness.load_json(os.path.join(repo, "BENCHMARK.json"))
+    cell, cfg = harness.resolve(manifest, "trinitymini-train-share16", True)
+    plan = train.make_plan(cell, cfg)
+    state = jax.eval_shape(plan.build, jax.random.PRNGKey(0))
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (cell["batch_per_chip"], cell["row_tokens"]), jnp.int32)}
+    lowered = make_lm_train_step(SingleDevice()).lower(state, batch)
+    seen = _passes_by_component(lowered)
+    every = {"forward", "recompute", "backward"}
+    for component in ("flash_swa", "flash", "attn_proj", "attn_gate", "norm",
+                      "mlp", "moe_gmm", "moe_dispatch", "moe_router",
+                      "moe_shared", "moe_experts"):
+        assert seen[component] >= every, (component, seen.get(component))
+    # (the residual stream's two adds sit in the block's own scope, under no
+    # sub-module: XLA fuses them into their neighbours)
+    inside = [(op, stack) for op, stack, _ in _op_stacks(lowered)
+              if re.search(r"(^|/)block_\d+/(block_\d+\._hybrid/)?\w+/", stack)
+              and device_component(stack)[0] is None]
+    assert not inside, inside[:5]
+
+
+def test_windowed_flash_pallas_calls_carry_their_names():
+    from dtdl_tpu.ops.attention import flash_attention
+
+    q = jnp.zeros((1, 2, 16, 8), jnp.float32)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=5).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
+    names = _pallas_names(jaxpr.jaxpr)
+    assert names == ["flash_swa_fwd", "flash_swa_bwd_dq",
+                     "flash_swa_bwd_dkv"]
+    assert {KERNEL_NAMES[n] for n in names} == {"flash_swa"}
+
+
+def test_the_lowered_tpu_step_holds_windowed_and_full_flash_calls(
+        monkeypatch):
+    """The Trinity-shaped step lowered for a TPU holds the windowed calls
+    under their own names beside the full layer's, and a model without a
+    window none of them."""
+    common = dict(attn_impl="flash", remat=True, dtype=jnp.bfloat16)
+    taken = _mosaic_calls(_windowed_lm(**common), 73, monkeypatch)
+    assert KERNEL_NAMES.keys() >= taken.keys() >= {
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_swa_fwd",
+        "flash_swa_bwd_dq", "flash_swa_bwd_dkv", "moe_gmm", "moe_tgmm"}
+    plain = _mosaic_calls(_windowed_lm(layer_windows=(), **common), 73,
+                          monkeypatch)
+    assert plain["flash_fwd"] >= 1
+    assert not [name for name in plain if name.startswith("flash_swa")]
+
+
 # ---------------------------------------------------------------------------
 # the compile account
 # ---------------------------------------------------------------------------
@@ -679,7 +817,8 @@ def test_compile_account_rows_totals_and_single_registration():
     # (and the newest checkpoint plan, experts' buffer and delta-rule paths, where a step of
     # this process made one: tests/test_remat_plan.py, test_qwen3_next.py)
     assert ({k for k in whole
-             if not k.startswith(("remat_", "moe_", "gdn_", "kda_"))}
+             if not k.startswith(("remat_", "moe_", "gdn_", "kda_",
+                                  "swa_"))}
             == set(compile_cache.ACCOUNT_EVENTS.values()))
     assert whole["compile_trace_s"] > 0 and whole["compile_backend_s"] > 0
     summary = Observer().summary()
@@ -709,6 +848,7 @@ def test_compile_totals_count_a_nested_trace_and_a_retrieval_once(
     monkeypatch.setattr(compile_cache, "_EXPERT_BUFFERS", [])
     monkeypatch.setattr(compile_cache, "_GDN_PATHS", [])
     monkeypatch.setattr(compile_cache, "_KDA_PATHS", [])
+    monkeypatch.setattr(compile_cache, "_WINDOW_CALLS", [])
     assert compile_cache.compile_totals() == {}
     monkeypatch.setattr(compile_cache, "_ROWS", rows)
     assert compile_cache.compile_totals() == {

@@ -207,7 +207,10 @@ def test_device_scope_catalog_audit_no_silent_drift():
                                     REENTERED_SCOPES, STEP_NAMES)
     pkg = pathlib.Path(dtdl_tpu.__file__).parent
     scope_pat = re.compile(r"named_scope\(\s*(f?)\"(\w[^\"]*)\"")
-    name_pat = re.compile(r"^\s+name=\"(\w+)\",$", re.M)
+    # one name a call, or two where a call has a windowed twin
+    # (``name="flash_fwd" if band is None else "flash_swa_fwd",``)
+    name_pat = re.compile(
+        r"^\s+name=\"(\w+)\"(?: if [\w ]+ else \"(\w+)\")?,$", re.M)
     scopes, kernels = set(), set()
     for py in pkg.rglob("*.py"):
         text = py.read_text()
@@ -220,7 +223,7 @@ def test_device_scope_catalog_audit_no_silent_drift():
             assert len(names) == calls, (
                 f"{py.name}: {calls} pallas_call(s), {len(names)} name= "
                 f"lines")
-            kernels.update(names)
+            kernels.update(n for pair in names for n in pair if n)
     # a scope re-entered by name is another catalogue's: a module's, or
     # jax's own for recomputation
     assert REENTERED_SCOPES <= scopes

@@ -310,6 +310,12 @@ _PINNED = {
         (2, 2, 1, 1), 1_476_804_480, 1_494_105_784, 14_297_448_776)),
     "kimilinear-train-share32": ("kimilinear-train-share32", 1, False, (
         (2, 2, 1, 1, 1), 1_812_872_960, 2_012_564_216, 13_778_990_344)),
+    # PR 35: the sum of the head's instant and a block's reads 16.476 GB and
+    # leaves nothing; told apart 14.284 GB (model_held_bytes.start_apart)
+    "trinitymini-train-share16": ("trinitymini-train-share16", 1, False, (
+        (2, 1, 1, 1, 1), 1_285_397_248, 1_507_103_224, 14_284_451_336)),
+    "olmo1b-train-b4s2048-zipf": ("olmo1b-train-b4s2048-zipf", 1, False,
+                                  _OLMO1B),
     "olmo1b-train-ddp4": ("olmo1b-train-ddp4", 4, False, _OLMO1B),
     "olmo1b_one_chip_guarded": ("olmo1b-train-b4s2048", 1, True, _OLMO1B),
 }
@@ -645,3 +651,134 @@ def test_chunked_loss_frees_the_logits_for_a_richer_plan(monkeypatch):
                           vocab_chunk_size=16)
     assert richer.rungs > (1, 1) and richer.kept_bytes > sum(
         c[0] for c in costs)
+
+
+# ---------------------------------------------------------------------------
+# the Trinity block (PR 35): windowed and full layers, a gate projection of
+# its own, four norms
+# ---------------------------------------------------------------------------
+
+def test_the_trinity_blocks_bytes_are_what_shapes_say():
+    """A windowed layer keeps what a full one keeps (the band changes the
+    recomputation's cost, not the bytes); the gate's own projection joins
+    rung 2; a dense FFN inside a hybrid stack has a third rung; the norms on
+    the sublayers' outputs add four ``[tokens, d_model]`` values to the live
+    set."""
+    b, s, d, h, width = 2, 8191, 2048, 32, 4096
+    t = b * s
+    plain = remat_plan.residual_bytes(b, s, d, h, 0, 2, attn_width=width)
+    gated = remat_plan.residual_bytes(b, s, d, h, 6144, 2, attn_width=width,
+                                      own_gate=True)
+    assert plain == (t * width * 2 + b * h * s * 4,
+                     (3 * width + d) * t * 2, 0)
+    assert gated == (plain[0], plain[1] + t * width * 2, 2 * t * 6144 * 2)
+    live = remat_plan.hybrid_block_live_bytes
+    assert live(b, s, d, 2, attn_width=width, post_norms=True) \
+        - live(b, s, d, 2, attn_width=width) == 4 * t * d * 2
+
+
+def test_the_head_and_the_blocks_are_told_apart_where_the_sum_leaves_nothing():
+    """``start`` adds the logits to a block's live set; ``start_apart`` takes
+    the larger.  The plan stands on the sum wherever that leaves a budget
+    (every accepted cell: pinned above) and falls back where it leaves
+    none."""
+    held = remat_plan.model_held_bytes(2, 100, 64, 0, 3, 1000, 4000, 2,
+                                       block_live_bytes=900_000)
+    logits = 200 * 1000 * 6
+    assert held.start - held.start_apart == min(logits, 900_000)
+    dense = remat_plan.model_held_bytes(4, 100, 64, 256, 2, 1000, 4000, 2)
+    assert dense.start - dense.start_apart == 400 * (6 * 256 + 8 * 64) * 2
+    costs = [(10_000, 40_000, 0)] * 3
+    limit = 16 * (held.start + 31_000) // 15
+    with remat_plan.step_memory("lm_train_step", 0, limit):
+        roomy = remat_plan.plan_checkpoints(costs, held, [0] * 3)
+    assert roomy.estimate_bytes == held.start and roomy.rungs == (1, 1, 1)
+    limit = 16 * (held.start_apart + 71_000) // 15
+    with remat_plan.step_memory("lm_train_step", 0, limit):
+        apart = remat_plan.plan_checkpoints(costs, held, [0] * 3)
+        none = remat_plan.plan_checkpoints(
+            costs, held._replace(start_apart=None), [0] * 3)
+    assert apart.estimate_bytes == held.start_apart
+    assert apart.rungs == (2, 1, 1) and apart.kept_bytes == 70_000
+    assert none.rungs == (0, 0, 0) and none.estimate_bytes == held.start
+
+
+def test_the_trinity_cells_estimate_against_the_compilers_total(monkeypatch):
+    """The plan the cell's step traces at the v5e limit against the bytes
+    the v5e compiler gave that step (``tools/topology_compile.py``, recorded
+    in the configuration's ``memory``): the estimate (what the step holds
+    with nothing kept) stands over the compiler's total (with what the plan
+    keeps), by no more than 3 GB, and the total under the ceiling."""
+    _, cfg = _cell("trinitymini-train-share16")
+    limit = V5E_LIMIT >> 26 << 26
+    plan = _traced_plan(monkeypatch, *_cell_model("trinitymini-train-share16"),
+                        limit)
+    compiled = cfg["memory"]["5"]["step_bytes"]
+    assert "(2,1,1,1,1)" in cfg["memory"]["5"]["remat_plan"]
+    assert plan.rungs == (2, 1, 1, 1, 1)
+    assert compiled <= plan.estimate_bytes <= compiled + 3 * 10**9
+    assert compiled < int(limit * 15 / 16)
+    # the gate's projection is kept with the out projection's output
+    kept = remat_plan.residual_bytes(2, 8191, 2048, 32, 6144, 2,
+                                     attn_width=4096, own_gate=True)
+    assert plan.kept_bytes == 5 * kept[0] + kept[1]
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_the_rehearsed_trinity_step_compiles_for_a_v5e_under_its_plan(
+        monkeypatch, v5e_chip):
+    """The rehearsal's step, compiled for a described v5e under a plan that
+    keeps every residual: Mosaic takes the windowed kernels (a band two
+    tiles narrower than the row) beside the full ones, and the compiled
+    step holds at least what the plan reckons (at this size every small
+    tensor is padded to whole tiles, so the compiler's total stands far over
+    the estimate: the cell's own size is the case above)."""
+    import sys
+    bench = os.path.join(REPO, "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import run as harness
+    from runners import train
+    monkeypatch.setattr(attention, "_use_interpret", lambda: False)
+    monkeypatch.setattr(remat_plan, "device_bytes_limit", lambda: 30_000_000)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        manifest = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
+        cell, cfg = harness.resolve(manifest, "trinitymini-train-share16",
+                                    True)
+        made = train.make_plan(cell, cfg)
+
+        def placed(tree):
+            return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=v5e_chip), tree)
+
+        state = placed(jax.eval_shape(made.build, jax.random.PRNGKey(0)))
+        batch = placed({"tokens": jax.ShapeDtypeStruct(
+            (cell["batch_per_chip"], cell["row_tokens"]), jnp.int32)})
+        before = len(compile_cache.remat_plans())
+        compiled = make_lm_train_step(SingleDevice()).lower(
+            state, batch).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+    plan, = compile_cache.remat_plans()[before:]
+    assert plan.rungs == (3, 2, 2, 2, 2)
+    text = compiled.as_text()
+    for name in ("flash_swa_fwd", "flash_swa_bwd_dq", "flash_swa_bwd_dkv",
+                 "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert re.search(rf"%{name}[.\d]* = .*tpu_custom_call", text), name
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert total >= plan.estimate_bytes + plan.kept_bytes
