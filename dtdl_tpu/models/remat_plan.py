@@ -25,6 +25,9 @@ name            value                                        placed in
 ``gdn_in``      the ``in_qkvz`` projection's output          GatedDeltaNet
 ``kda_loop``    what the channel-wise rule's loop reads      ops/gated_delta.py
 ``kda_in``      the ``in_q``, ``in_k``, ``in_v`` outputs     KimiDeltaAttention
+``moe_plan``    the held experts' top-k and sorts            HeldExperts
+``moe_out``     the held experts' output where a norm reads  Block._hybrid
+                it (``post_norms``), before any rung
 ==============  ===========================================  =================
 
 **The ladder.**  :data:`RUNGS` orders them by the recomputation a kept byte
@@ -36,7 +39,13 @@ adds ``flash_qkv`` and ``attn_out`` (all four projections go); rung 3 adds
 elementwise and fuses into ``wo``'s backward).  :func:`ladder` fills rung 1
 on every block, then rung 2, then rung 3, block 0 first, and stops at the
 first residual that does not fit the budget: so the first *k* blocks may
-stand one rung above the rest.  MoE blocks carry the attention names only.
+stand one rung above the rest.  MoE blocks carry the attention names only,
+and a block with held experts ``moe_plan`` at every rung.  Where a norm
+reads the held experts' output, the block keeps that output too
+(``moe_out``), paid for before the ladder (:func:`plan_checkpoints`):
+without it the recomputation runs the expert layer's whole forward again
+for the norm's backward: 180 ms a GB kept in the Trinity cell, the most of
+any name (measured on a v5e, PERF.md section 6, PR 36).
 A linear-attention block (Gated DeltaNet) has two rungs of its own:
 ``gdn_loop`` (the rule's chunk-local stage is not run again: the
 ``gdn_chunk_fwd`` kernel, or the batched matmuls of the ``jax.numpy`` path),
@@ -96,6 +105,7 @@ MLP_UP = "mlp_up"
 GDN_IN = "gdn_in"       # a linear-attention block's ``in_qkvz`` output
 KDA_IN = "kda_in"       # a Kimi Delta Attention block's q, k, v projections
 MOE_PLAN = "moe_plan"   # the held experts' choice and sort (a megabyte)
+MOE_OUT = "moe_out"     # the held experts' output, where a norm reads it
 
 RUNGS = ("recompute", "flash", "attn_proj", "mlp")
 # the names each rung adds to those of the rung below it
@@ -116,25 +126,31 @@ _LINEAR_RUNG_NAMES = ((), (GDN_LOOP,), (GDN_IN,))
 _KDA_RUNG_NAMES = ((), (KDA_LOOP,), (KDA_IN,))
 
 
-def saved_names(rung: int, linear: bool | str = False,
-                held: bool = False) -> tuple[str, ...]:
+def saved_names(rung: int, linear: bool | str = False, held: bool = False,
+                moe_out: bool = False) -> tuple[str, ...]:
     """The checkpoint names a block at ``rung`` keeps.  A linear-attention
     block (``linear``: True or ``"linear"`` for Gated DeltaNet, ``"kda"``
     for Kimi Delta Attention) has two rungs of its own: what the delta
     rule's loop reads (the chunk-local stage goes), the input projections'
     output (they go); it has no third.  A block with held experts
     always keeps ``moe_plan``: the top-k and the sort of the assignments are
-    a megabyte to keep and milliseconds to run again."""
+    a megabyte to keep and milliseconds to run again.  ``moe_out``: the
+    block's held experts feed a norm (``post_norms``) and the plan holds
+    their output (:func:`plan_checkpoints`), so it keeps ``moe_out`` too,
+    at every rung: the norm's backward reads it, and without it the
+    recomputation runs the whole expert layer's forward again for it."""
     names = (_KDA_RUNG_NAMES if linear == "kda"
              else _LINEAR_RUNG_NAMES if linear else _RUNG_NAMES)
-    return sum(names[:rung + 1], ()) + ((MOE_PLAN,) if held else ())
+    return (sum(names[:rung + 1], ()) + ((MOE_PLAN,) if held else ())
+            + ((MOE_OUT,) if held and moe_out else ()))
 
 
-def policy(rung: int, linear: bool | str = False, held: bool = False):
+def policy(rung: int, linear: bool | str = False, held: bool = False,
+           moe_out: bool = False):
     """The ``jax.checkpoint`` policy of a block at ``rung``; None where it
     keeps nothing (rung 0 of a dense block: the program ``remat=True``
     compiled before there was a plan)."""
-    names = saved_names(rung, linear, held)
+    names = saved_names(rung, linear, held, moe_out)
     if not names:
         return None
     return jax.checkpoint_policies.save_only_these_names(*names)
@@ -409,6 +425,9 @@ class RematPlan(NamedTuple):
     # that keeps less than the ladder buys for its budget was held to these.
     end_bytes: int | None = None
     walk_bytes: int | None = None
+    # bytes of ``moe_out`` each block keeps (0: none), counted in
+    # ``kept_bytes``; () where no block's held experts feed a norm
+    moe_out: tuple[int, ...] = ()
 
 
 # outside a train step: nobody to plan for, no limit known
@@ -448,13 +467,15 @@ def step_memory(fun_name: str, held: int, limit: int | None,
 
 def walk_bytes(end: int, costs: Sequence[Sequence[int]],
                rungs: Sequence[int], block_input: int,
-               block_grads: Sequence[int]) -> int:
+               block_grads: Sequence[int],
+               moe_out: Sequence[int] = ()) -> int:
     """The most a step holds from the head's backward to the end of the
     backward pass, where every gradient stays live to the end (``end``:
     what it holds then).  The blocks are differentiated last to first; until
     block ``i`` is, the step holds what the block keeps (``costs[i]`` up to
     ``rungs[i]``) and its input in place of its parameters' gradient
-    (``block_grads[i]``).  So
+    (``block_grads[i]``), and the ``moe_out`` it keeps at every rung
+    (``moe_out[i]``, where given).  So
     before block ``k`` the step holds ``end`` plus the sum over the blocks
     before ``k`` of ``kept + input - gradient``, and the peak is ``end``
     plus the largest such prefix sum (the empty one is the end itself).
@@ -462,14 +483,16 @@ def walk_bytes(end: int, costs: Sequence[Sequence[int]],
     from the head's backward on, and one block's live set throughout, as
     ``end`` has them."""
     peak = held = end
-    for cost, rung, grad in zip(costs, rungs, block_grads):
-        held += sum(cost[:rung]) + block_input - grad
+    for i, (cost, rung, grad) in enumerate(zip(costs, rungs, block_grads)):
+        held += (sum(cost[:rung]) + block_input - grad
+                 + (moe_out[i] if moe_out else 0))
         peak = max(peak, held)
     return peak
 
 
 def plan_checkpoints(costs: Sequence[Sequence[int]], model: ModelHeld,
-                     block_grads: Sequence[int]) -> RematPlan:
+                     block_grads: Sequence[int],
+                     moe_out: Sequence[int] = ()) -> RematPlan:
     """The plan for blocks of ``costs`` in a model that holds ``model``
     (:func:`model_held_bytes`) under rung 0 and whose blocks' parameters
     take ``block_grads`` bytes each.  Rung 0 throughout outside
@@ -477,11 +500,17 @@ def plan_checkpoints(costs: Sequence[Sequence[int]], model: ModelHeld,
 
     The budget is limit less margin less what is held as the backward pass
     begins (``model.start``; ``model.start_apart`` where that leaves no
-    budget at all).  Where the step named an end (gradients read together) and that
+    budget at all).  ``moe_out`` gives each block's bytes of ``moe_out``
+    (0 where its held experts feed no norm): the budget pays for them first,
+    all of them at every rung, or for none where it cannot hold them all;
+    the ladder spends what is left (a kept byte there removes more
+    recomputation than on any rung: PERF.md section 6, PR 36).  Where the
+    step named an end (gradients read together) and that
     alone passes limit less margin, the budget is 0; otherwise the walk
     between the two instants is **computed** (:func:`walk_bytes`; the margin
     is not asked to cover it) and, while its peak passes limit less margin,
-    the residual the ladder filled last is given back.  That ends: each
+    the residual the ladder filled last is given back, and ``moe_out`` once
+    the ladder has nothing left.  That ends: each
     round keeps less, and at rung 0 a walk that still does not fit is an
     estimate over the limit, as it always was."""
     step = _STEP.get()
@@ -498,10 +527,16 @@ def plan_checkpoints(costs: Sequence[Sequence[int]], model: ModelHeld,
         end = step.end_held + model.end
         if end > ceiling:
             budget = 0
-    rungs, kept = ladder(costs, budget)
+    moe_out = tuple(moe_out) if sum(moe_out) <= budget else ()
+    rungs, kept = ladder(costs, budget - sum(moe_out))
     if end is not None:
         while (walk := walk_bytes(end, costs, rungs, model.block_input,
-                                  block_grads)) > ceiling and kept:
-            rungs, kept = ladder(costs, kept - 1)
-    return RematPlan(step.fun_name, rungs, kept, budget, estimate,
-                     step.limit, end, walk)
+                                  block_grads, moe_out)) > ceiling and (
+                kept or moe_out):
+            if kept:
+                rungs, kept = ladder(costs, kept - 1)
+            else:
+                moe_out = ()
+    return RematPlan(step.fun_name, rungs, kept + sum(moe_out), budget,
+                     estimate, step.limit, end, walk,
+                     moe_out if any(moe_out) else ())

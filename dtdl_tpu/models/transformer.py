@@ -1579,7 +1579,15 @@ class Block(nn.Module):
     def _hybrid(self, x, cos, sin, decode):
         """``h = x + Mixer(norm(x)); y = h + FFN(norm(h))`` with the mixer
         and the expert layer the spec names; with ``post_norms`` a norm on
-        each sublayer's output too, ``h = x + norm(Mixer(norm(x)))``."""
+        each sublayer's output too, ``h = x + norm(Mixer(norm(x)))``.
+
+        Where that norm reads held experts' output, the output carries the
+        checkpoint name ``moe_out`` (models/remat_plan.py): the norm's
+        backward reads its input, and a recomputation that must produce it
+        again runs the expert layer's whole forward ``cond`` a second time
+        (gather in, three grouped matmuls, weighted sum out), where an
+        output that goes straight into the residual add is read by no
+        backward and falls away.  Nothing else gets the name."""
         spec = self.spec
 
         def norm(name):
@@ -1625,18 +1633,24 @@ class Block(nn.Module):
                              "'full' layers alone")
         h = norm("ln_mlp")(x)
         if spec.held:
-            return x + post("ln_mlp_out", HeldExperts(
-                **spec.held._asdict(), dtype=self.dtype, name="moe")(h))
+            y = HeldExperts(**spec.held._asdict(), dtype=self.dtype,
+                            name="moe")(h)
+            if spec.post_norms:
+                y = checkpoint_name(y, remat_plan.MOE_OUT)
+            return x + post("ln_mlp_out", y)
         return x + post("ln_mlp_out",
                         SwiGLU(self.d_ff, self.dtype, name="mlp")(h))
 
 
 @functools.cache
-def _remat_block(rung: int, linear: bool | str = False, held: bool = False):
+def _remat_block(rung: int, linear: bool | str = False, held: bool = False,
+                 moe_out: bool = False):
     """``Block`` under ``jax.checkpoint`` with the policy of ``rung``
-    (models/remat_plan.py); rung 0 is no policy, the whole forward again."""
+    (models/remat_plan.py); rung 0 is no policy, the whole forward again.
+    ``moe_out``: the block's held experts feed a norm and the plan keeps
+    their output."""
     return nn.remat(Block, static_argnums=(),
-                    policy=remat_plan.policy(rung, linear, held))
+                    policy=remat_plan.policy(rung, linear, held, moe_out))
 
 
 class TransformerLM(nn.Module):
@@ -1911,6 +1925,7 @@ class TransformerLM(nn.Module):
         block_grads = [remat_plan.tree_bytes(params.get(f"block_{i}", {}))
                        for i in range(self.n_layers)]
         vocab = 0 if return_hidden else self.vocab_size
+        moe_out = ()
         if specs is None or specs[0] is None:
             costs = [remat_plan.residual_bytes(
                 batch, seq, self.d_model, self.n_heads,
@@ -1952,7 +1967,12 @@ class TransformerLM(nn.Module):
             held = remat_plan.model_held_bytes(
                 batch, seq, self.d_model, 0, self.n_layers, vocab,
                 param_bytes, itemsize, block_live_bytes=live)
-        plan = remat_plan.plan_checkpoints(costs, held, block_grads)
+            # what a block keeps of its held experts for the norm on their
+            # output (Block._hybrid)
+            moe_out = [batch * seq * self.d_model * itemsize
+                       if spec.held and spec.post_norms else 0
+                       for spec in specs]
+        plan = remat_plan.plan_checkpoints(costs, held, block_grads, moe_out)
         if plan.fun_name is not None:
             record_remat_plan(plan)
         return plan
@@ -2011,17 +2031,18 @@ class TransformerLM(nn.Module):
                   and i >= self.first_dense_layers
                   for i in range(self.n_layers)]
         specs = self.block_specs(is_moe)
-        rungs = None
+        plan = None
         if self.remat and not decode:
-            rungs = self._checkpoint_plan(tokens.shape, is_moe,
-                                          return_hidden, specs).rungs
+            plan = self._checkpoint_plan(tokens.shape, is_moe,
+                                         return_hidden, specs)
         for i, moe in enumerate(is_moe):
-            block_cls = Block if rungs is None else (
-                _remat_block(rungs[i]) if specs[i] is None
+            block_cls = Block if plan is None else (
+                _remat_block(plan.rungs[i]) if specs[i] is None
                 else _remat_block(
-                    rungs[i],
+                    plan.rungs[i],
                     specs[i].kind if specs[i].kind in ("linear", "kda")
-                    else False, bool(specs[i].held)))
+                    else False, bool(specs[i].held),
+                    bool(plan.moe_out and plan.moe_out[i])))
             block = block_cls(
                 self.n_heads, self.head_dim, self.d_ff,
                 n_experts=self.n_experts if moe else 0,

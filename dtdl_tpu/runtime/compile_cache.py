@@ -34,7 +34,10 @@ newest as ``remat_blocks_by_rung``, ``remat_kept_bytes``,
 ``remat_budget_bytes``, ``remat_estimate_bytes`` (held as the backward pass
 begins) and, where the step's gradients are read together,
 ``remat_end_bytes`` and ``remat_walk_bytes`` (held as it ends, and the most
-between the two instants: the plan was held to all three).  A held-experts layer
+between the two instants: the plan was held to all three), and
+``remat_moe_out_blocks``, ``remat_moe_out_bytes`` (how many blocks keep
+their held experts' output for the norm that reads it, and its bytes: 0
+where none does).  A held-experts layer
 (``models/transformer.py:HeldExperts``) adds the shapes of its two row
 buffers the same way: :func:`record_expert_buffer`, :func:`expert_buffers`,
 and ``moe_buffer_rows``, ``moe_first_buffer_rows``, ``moe_row_tile``,
@@ -229,6 +232,8 @@ def compile_totals() -> dict:
         totals["remat_kept_bytes"] = plan.kept_bytes
         totals["remat_budget_bytes"] = plan.budget_bytes
         totals["remat_estimate_bytes"] = plan.estimate_bytes
+        totals["remat_moe_out_blocks"] = sum(b > 0 for b in plan.moe_out)
+        totals["remat_moe_out_bytes"] = sum(plan.moe_out)
         if plan.end_bytes is not None:
             totals["remat_end_bytes"] = plan.end_bytes
             totals["remat_walk_bytes"] = plan.walk_bytes

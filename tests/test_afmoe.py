@@ -315,6 +315,126 @@ def test_a_windowed_layer_records_its_tiles_in_a_traced_step():
     assert totals["swa_calls"] == len(compile_cache.window_calls())
 
 
+# top 2 of 64 with 4 held over 2 x 69 tokens: two buffers (a first of 6
+# tiles and a full one of 7), so each expert layer's forward is a ``cond``;
+# a dense layer, then a full and a windowed layer with held experts
+_TWO_BUFFERS = dict(_layers(CFG, MIXED, 1), router_num_experts=64,
+                    first_expert_held=8, num_experts_per_tok=2)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of its sub-jaxprs."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for item in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(item, "jaxpr", item)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+def _expert_conds(jaxpr, shape):
+    """``[forward, backward]``: the expert layers' ``cond``s.  A forward
+    one gives the layer's output alone (``shape``); a backward one the
+    cotangents of its five operands, the tokens' first."""
+    out = [0, 0]
+    for eqn in _eqns(jaxpr):
+        shapes = [v.aval.shape for v in eqn.outvars]
+        if eqn.primitive.name == "cond" and shapes[:1] == [shape]:
+            out[len(shapes) != 1] += 1
+    return out
+
+
+def _names(jaxpr):
+    """Every checkpoint name in a jaxpr and its sub-jaxprs."""
+    return [eqn.params["name"] for eqn in _eqns(jaxpr)
+            if eqn.primitive.name == "name"]
+
+
+def _planned(model, params, limit, run=False):
+    """``(jaxpr, plan, (loss, grads) where run)`` of the loss's gradient,
+    traced as a train step on a device of ``limit`` bytes would trace it
+    (None: no limit, the plan keeps nothing)."""
+    from dtdl_tpu.models import remat_plan
+    from dtdl_tpu.runtime import compile_cache
+
+    def loss(p):
+        return _program_loss(model, p, TOKENS)[0]
+    grad = jax.value_and_grad(loss)
+    with remat_plan.step_memory("lm_train_step", 0, limit):
+        jaxpr = jax.make_jaxpr(grad)(params)
+        plan = compile_cache.remat_plans()[-1]
+        got = None
+        if run:
+            with jax.default_matmul_precision("highest"):
+                got = jax.jit(grad)(params)
+    return jaxpr.jaxpr, plan, got
+
+
+def test_the_experts_output_a_norm_reads_is_kept_and_never_run_again():
+    """Trinity's expert block ends in a norm on the experts' output, whose
+    backward reads that output.  Under a plan that keeps ``moe_out`` the
+    differentiated step holds one forward ``cond`` an expert layer and one
+    backward one, as ``remat=False``'s does; where nothing is kept (no
+    limit: the parent's program at any limit) the recomputation runs each
+    layer's forward ``cond`` again for the norm."""
+    from dtdl_tpu.ops.grouped_matmul import first_buffer_rows, held_buffer_rows
+    cfg = _TWO_BUFFERS
+    t, d = TOKENS.shape[0] * (ROW - 1), cfg["hidden_size"]
+    shapes = (t, 2, 4, 64)
+    assert first_buffer_rows(*shapes) < held_buffer_rows(*shapes)[0]
+    model, params, _, _ = _model_and_params(cfg)
+    jaxpr, plan, _ = _planned(model, params, 10**9)
+    assert plan.moe_out == (0, t * d * 4, t * d * 4)
+    assert plan.kept_bytes >= sum(plan.moe_out)
+    assert _expert_conds(jaxpr, (t, d)) == [2, 2]
+    assert _names(jaxpr).count("moe_out") == 2
+    jaxpr, plan, _ = _planned(model, params, None)
+    assert plan.moe_out == () and plan.rungs == (0, 0, 0)
+    assert _expert_conds(jaxpr, (t, d)) == [4, 2]
+    bare = model.clone(remat=False)
+    assert _expert_conds(jax.make_jaxpr(jax.value_and_grad(
+        lambda p: _program_loss(bare, p, TOKENS)[0]))(params).jaxpr,
+        (t, d)) == [2, 2]
+
+
+def test_keeping_the_experts_output_changes_no_number():
+    """Loss and every gradient under the plan that keeps ``moe_out`` are
+    ``remat=False``'s to float32's rounding."""
+    model, params, _, paths = _model_and_params(_TWO_BUFFERS)
+    _, plan, (loss, grads) = _planned(model, params, 10**9, run=True)
+    assert plan.moe_out
+    bare = model.clone(remat=False)
+    with jax.default_matmul_precision("highest"):
+        want_loss, want = jax.jit(jax.value_and_grad(
+            lambda p: _program_loss(bare, p, TOKENS)[0]))(params)
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-6)
+    for path, a, b in zip(paths, jax.tree.leaves(grads),
+                          jax.tree.leaves(want)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.linalg.norm(a - b) <= 1e-5 * max(np.linalg.norm(b),
+                                                   1e-6), path
+
+
+def test_held_experts_without_a_norm_on_their_output_carry_no_name():
+    """The same stack without the norms on the sublayers' outputs (the
+    Qwen3-Next and Kimi-Linear blocks): the experts' output goes into the
+    residual add, whose backward reads nothing, so the recomputation drops
+    the forward ``cond`` by itself; no value carries ``moe_out``, the plan
+    keeps none, and its bytes go to the ladder as before."""
+    cfg = _TWO_BUFFERS
+    t, d = TOKENS.shape[0] * (ROW - 1), cfg["hidden_size"]
+    model, params, _, _ = _model_and_params(cfg, post_norms=False)
+    params = {k: {n: v for n, v in block.items() if not n.endswith("_out")}
+              if k.startswith("block_") else block
+              for k, block in params.items()}
+    for limit in (10**9, None):
+        jaxpr, plan, _ = _planned(model, params, limit)
+        assert plan.moe_out == ()
+        assert "moe_out" not in _names(jaxpr)
+        assert _expert_conds(jaxpr, (t, d)) == [2, 2]
+
+
 # digests of lowered CPU programs as the parent of PR 35 lowers them: the
 # rehearsal steps of the three accepted families' cells.  The keywords of
 # PR 35 at their defaults (no window, every layer rotated, no output norms,
@@ -332,11 +452,25 @@ _PARENT_TEXT = {
 }
 
 
-@pytest.mark.parametrize("cell_name", ["qwen3next-train-share16",
-                                       "olmo1b-train-b4s2048",
-                                       "kimilinear-train-share32",
-                                       "olmo7b-train-b2s2048"])
-def test_the_accepted_cells_rehearsal_steps_lower_as_the_parents(cell_name):
+# the same four steps traced on a device of a v5e's limit, where each
+# rehearsal's plan keeps its blocks' every rung, as the parent of PR 36
+# lowers them: none of these blocks feeds its held experts' output to a
+# norm, so ``moe_out`` is nowhere in them
+_PARENT_TEXT_PLANNED = {
+    "qwen3next-train-share16":
+        "e477151b03baacd51689cab29e922909e23ee60edab6cf93238589b37ccaf564",
+    "olmo1b-train-b4s2048":
+        "dc287908383588f6ef8b37788a3146794f5924f4af8dfedad66ce5c5a0bff683",
+    "kimilinear-train-share32":
+        "3f89c1ba282af916d046b5be5cb944e1b94dfcf8dc7151cd6ae7f2f39f0a38ca",
+    "olmo7b-train-b2s2048":
+        "52c795361ef0ecea692ea132238f9cbf9766d29ebf499d2f7c78d9316d35fed2",
+}
+_ACCEPTED = ["qwen3next-train-share16", "olmo1b-train-b4s2048",
+             "kimilinear-train-share32", "olmo7b-train-b2s2048"]
+
+
+def _rehearsal_text_digest(cell_name):
     import run as harness
     from runners import train
     manifest = harness.load_json(str(BENCH.parent / "BENCHMARK.json"))
@@ -347,5 +481,22 @@ def test_the_accepted_cells_rehearsal_steps_lower_as_the_parents(cell_name):
         (cell["batch_per_chip"], cell["row_tokens"]), jnp.int32)}
     text = make_lm_train_step(SingleDevice(), vocab_chunk_size=0).lower(
         state, batch).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() \
-        == _PARENT_TEXT[cell_name]
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("cell_name", _ACCEPTED)
+def test_the_accepted_cells_rehearsal_steps_lower_as_the_parents(cell_name):
+    assert _rehearsal_text_digest(cell_name) == _PARENT_TEXT[cell_name]
+
+
+@pytest.mark.parametrize("cell_name", _ACCEPTED)
+def test_the_accepted_cells_planned_rehearsal_steps_lower_as_the_parents(
+        cell_name, monkeypatch):
+    from dtdl_tpu.models import remat_plan
+    from dtdl_tpu.runtime import compile_cache
+    monkeypatch.setattr(remat_plan, "device_bytes_limit",
+                        lambda: 16_844_324_864)
+    assert _rehearsal_text_digest(cell_name) \
+        == _PARENT_TEXT_PLANNED[cell_name]
+    plan = compile_cache.remat_plans()[-1]
+    assert min(plan.rungs) >= 2 and plan.moe_out == ()
