@@ -36,6 +36,11 @@ discipline":
 When a ``jax.profiler`` capture is active, each span also opens a
 ``jax.profiler.TraceAnnotation`` (a TraceMe: ~ns while no capture
 runs) so host phases line up with XLA ops inside one Perfetto view.
+Beside the spans the host plane carries one name of the program's own:
+``gc``, a ``TraceAnnotation`` (argument ``generation``) around each
+collection of the Python collector, opened by the compile account's
+callback (``runtime/compile_cache.py``), so a pause sits under the idle
+gap it caused.  No span emits it, so it is not in :data:`SPAN_CATALOG`.
 
 **Request correlation (round 16).**  Fleet-era serving spreads one user
 request over many threads — router intake, a pump dispatch, one worker

@@ -55,10 +55,25 @@ tiles a causal call would compute): :func:`record_window_call`,
 :func:`window_calls`, and ``swa_calls``, ``swa_computed_tiles``,
 ``swa_needed_tiles``, ``swa_causal_tiles`` (a head a row, summed over the
 traced layers) in the totals.
+
+**The collector's pauses.**  The same call registers one callback on
+``gc.callbacks``: each collection of the Python collector, in any thread,
+becomes one :class:`GcPause` (a :class:`CompileRow` with its generation
+beside it) on the same ``time.perf_counter`` clock, covering ``[at - value,
+at]``, and a ``jax.profiler.TraceAnnotation("gc", generation=...)`` around
+it, so that a capture holds the pause on its host plane beside the device
+ops it held up.  The rows go to a ring of their own, :data:`GC_RING` rows
+(the newest), returned by :func:`gc_pauses` and never by
+:func:`compile_account`; :func:`compile_totals` carries running totals that
+never roll out: ``gc_collections_gen<g>``, ``gc_pause_s_gen<g>`` and
+``gc_longest_pause_s``.  A collection costs two clock reads, one annotation
+and one append more than it did.
 """
 
 from __future__ import annotations
 
+import collections
+import gc
 import os
 import pathlib
 import time
@@ -99,6 +114,25 @@ _WINDOW_CALLS: list = []        # one a windowed attention layer
 _listening = False
 
 
+class GcPause(NamedTuple):
+    """One collection of the Python collector, in :class:`CompileRow`'s
+    form (so :func:`covered_s` reduces it) with its generation."""
+    event: str              # "gc"
+    fun_name: str | None    # None
+    at: float               # time.perf_counter() as the collection ended
+    value: float            # its seconds, the callback's own included
+    generation: int         # 0, 1 or 2 (2: a full collection)
+
+
+# The Kimi-Linear step's set-up alone makes about 800 collections; a ring of
+# 16,384 holds a benchmark run's whole set-up and window, and bounds an
+# operator's run of days.
+GC_RING = 16_384
+_GC_PAUSES: collections.deque = collections.deque(maxlen=GC_RING)
+_GC_TOTALS: dict = {}           # running totals, never rolled out
+_gc_open = None                 # (start, annotation) of the collection under way
+
+
 def _on_duration(event, seconds, fun_name=None, **_):
     if event in ACCOUNT_EVENTS:
         _ROWS.append(CompileRow(event, fun_name, time.perf_counter(),
@@ -110,6 +144,28 @@ def _on_count(event, **_):
         _ROWS.append(CompileRow(event, None, time.perf_counter(), 1))
 
 
+def _on_gc(phase, info):
+    global _gc_open
+    if phase == "start":
+        start = time.perf_counter()
+        note = jax.profiler.TraceAnnotation(
+            "gc", generation=info["generation"])
+        note.__enter__()
+        _gc_open = (start, note)
+    elif _gc_open is not None:
+        start, note = _gc_open
+        _gc_open = None
+        note.__exit__(None, None, None)
+        at = time.perf_counter()
+        gen, seconds = info["generation"], at - start
+        _GC_PAUSES.append(GcPause("gc", None, at, seconds, gen))
+        for key, add in ((f"gc_collections_gen{gen}", 1),
+                         (f"gc_pause_s_gen{gen}", seconds)):
+            _GC_TOTALS[key] = _GC_TOTALS.get(key, 0) + add
+        _GC_TOTALS["gc_longest_pause_s"] = max(
+            _GC_TOTALS.get("gc_longest_pause_s", 0.0), seconds)
+
+
 def enable_compile_cache() -> str:
     """Turn the persistent compile cache and the compile account on;
     returns the cache directory used."""
@@ -117,6 +173,7 @@ def enable_compile_cache() -> str:
     if not _listening:
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
         jax.monitoring.register_event_listener(_on_count)
+        gc.callbacks.append(_on_gc)
         _listening = True
     if ENV_VAR in os.environ:
         return os.environ[ENV_VAR]
@@ -200,6 +257,11 @@ def compile_account() -> list[CompileRow]:
     return list(_ROWS)
 
 
+def gc_pauses() -> list[GcPause]:
+    """The collector's pauses still in the ring, oldest first (a copy)."""
+    return list(_GC_PAUSES)
+
+
 def covered_s(rows) -> float:
     """Seconds covered by duration rows: the length of the union of their
     intervals ``[at - value, at]``.  A jitted function traced inside
@@ -217,8 +279,9 @@ def compile_totals() -> dict:
     """The account's totals under the keys of :data:`ACCOUNT_EVENTS`: the
     seconds covered by each kind of duration, and the counts; and of the
     newest checkpoint plan the blocks at each rung (rung 0 first), the bytes
-    it keeps, its budget and the estimates it was chosen against.  ``{}``
-    while the account is empty."""
+    it keeps, its budget and the estimates it was chosen against; the
+    collector's collections and pause seconds by generation and its longest
+    pause.  ``{}`` while the account is empty."""
     totals = {}
     if _ROWS:
         for event, key in ACCOUNT_EVENTS.items():
@@ -251,4 +314,5 @@ def compile_totals() -> dict:
         for path in sorted({r["path"] for r in rows}):
             totals[f"{rule}_{path}_calls"] = sum(
                 r["path"] == path for r in rows)
+    totals.update(_GC_TOTALS)
     return totals
