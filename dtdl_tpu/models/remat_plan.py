@@ -63,8 +63,16 @@ rung 0 **when the backward pass begins**: that is the instant at which every
 kept residual is live.  The step knows what lives outside the model
 (:func:`step_held_bytes`: the state's leaves, the loss's chunk) and says so
 through :func:`step_memory`; the model adds what it holds itself
-(:func:`model_held_bytes`: logits, the blocks' inputs, one block's live set)
-and plans (:func:`plan_checkpoints`).
+(:func:`model_held_bytes`: the blocks' inputs, and the larger of the logits
+and one block's live set) and plans (:func:`plan_checkpoints`).
+
+**The head's instant and the blocks', not their sum.**  The logits and
+their cotangent die with the head's backward, before the last block is
+recomputed, so they are never live beside a block's live set: the estimate
+takes the larger of the two, and the plan says which (``start_by``:
+``"head"`` in the OLMo cells, ``"block"`` in the hybrid ones).  Their sum
+would stand high by the smaller of the two (0.9 to 1.1 GB in the
+benchmark's cells) and, at rows of 8,191 positions, leave no budget at all.
 
 **Two instants, not one sum.**  Where a collective or the guard reads the
 gradients together (DDP, ``StepGuard``) every parameter's float32 gradient
@@ -279,8 +287,7 @@ class ModelHeld(NamedTuple):
     start: int          # when the backward pass begins
     end: int            # when it ends: the parameters' copy, one live set
     block_input: int    # one block's input, freed as the block is done
-    # ``start`` with the head and the blocks told apart (None: not reckoned)
-    start_apart: int | None = None
+    start_by: str = "block"     # what set ``start``: "head" or "block"
 
 
 def model_held_bytes(batch: int, seq: int, d_model: int, d_ff: int,
@@ -288,37 +295,32 @@ def model_held_bytes(batch: int, seq: int, d_model: int, d_ff: int,
                      itemsize: int,
                      block_live_bytes: int | None = None) -> ModelHeld:
     """What the model's forward and backward hold under rung 0, from shapes.
-    When the backward pass begins (``start``):
-    the f32 logits and their cotangent in the compute dtype (``vocab_size``
-    0 where the caller takes the hidden states instead), the parameters'
-    copy in the compute dtype (made in the forward pass and read again in
-    the backward; an untied head's table is a parameter like any other),
-    every block's input, and one block's live set while it is
-    recomputed and differentiated (``wi wg``, their product and the three
-    cotangents; eight ``[tokens, d_model]`` values of the attention half),
-    or ``block_live_bytes`` where the blocks are not the dense one
-    (:func:`hybrid_block_live_bytes`, the largest block's).  When it ends
-    (``end``) the logits and the blocks' inputs are gone: the parameters'
-    copy and the live set of the block differentiated last.
-
-    ``start`` adds the logits **and** a block's live set, as if the head's
-    backward and a block's ran at one instant.  They do not: the logits die
-    with the head's backward, before the last block is recomputed.
-    ``start_apart`` has the larger of the two in place of their sum.  The
-    sum is the reckoning every accepted plan stands on and is kept wherever
-    it leaves a budget; :func:`plan_checkpoints` falls back on
-    ``start_apart`` where it leaves none (rows of 8,191 positions: the sum
-    reads 5.9 GB over the v5e compiler's total at rung 0 and the plan would
-    keep nothing, PERF.md section 6, PR 35)."""
+    When the backward pass begins (``start``): the parameters' copy in the
+    compute dtype (made in the forward pass and read again in the backward;
+    an untied head's table is a parameter like any other), every block's
+    input, and the larger of two things that are never live at once: the
+    f32 logits with their cotangent in the compute dtype, while the head is
+    differentiated (``vocab_size`` 0 where the caller takes the hidden
+    states instead), and one block's live set while it is recomputed and
+    differentiated (``wi wg``, their product and the three cotangents; eight
+    ``[tokens, d_model]`` values of the attention half), or
+    ``block_live_bytes`` where the blocks are not the dense one
+    (:func:`hybrid_block_live_bytes`, the largest block's).  The logits die
+    with the head's backward, before the last block is recomputed, so the
+    two are a head's instant and a block's, not one sum; ``start_by`` names
+    the one that set ``start`` (``"head"`` where the logits weigh more).
+    When the backward pass ends (``end``) the logits and the blocks' inputs
+    are gone: the parameters' copy and the live set of the block
+    differentiated last."""
     t = batch * seq
     if block_live_bytes is None:
         block_live_bytes = t * (6 * d_ff + 8 * d_model) * itemsize
     block_input = t * d_model * itemsize
-    end = param_bytes * itemsize // 4 + block_live_bytes
+    copy = param_bytes * itemsize // 4
     logits = t * vocab_size * (4 + itemsize)
-    held = n_layers * block_input + end
-    return ModelHeld(logits + held, end, block_input,
-                     held - block_live_bytes + max(logits, block_live_bytes))
+    start = n_layers * block_input + copy + max(logits, block_live_bytes)
+    return ModelHeld(start, copy + block_live_bytes, block_input,
+                     "head" if logits > block_live_bytes else "block")
 
 
 def hybrid_block_live_bytes(batch: int, seq: int, d_model: int,
@@ -419,6 +421,9 @@ class RematPlan(NamedTuple):
     budget_bytes: int           # limit less margin less the estimate; >= 0
     estimate_bytes: int         # held under rung 0 as the backward begins
     limit_bytes: int | None
+    # which instant set the model's part of the estimate: "head" (the
+    # logits and their cotangent) or "block" (one block's live set)
+    start_by: str
     # where gradients are read together (None where not): what the step
     # holds as the backward pass ends, whatever the plan; and the most it
     # holds between the two instants under this plan (walk_bytes).  A plan
@@ -499,8 +504,8 @@ def plan_checkpoints(costs: Sequence[Sequence[int]], model: ModelHeld,
     :func:`step_memory` or where the device reports no limit.
 
     The budget is limit less margin less what is held as the backward pass
-    begins (``model.start``; ``model.start_apart`` where that leaves no
-    budget at all).  ``moe_out`` gives each block's bytes of ``moe_out``
+    begins (``model.start``: the head's instant or a block's, whichever
+    holds more).  ``moe_out`` gives each block's bytes of ``moe_out``
     (0 where its held experts feed no norm): the budget pays for them first,
     all of them at every rung, or for none where it cannot hold them all;
     the ladder spends what is left (a kept byte there removes more
@@ -517,11 +522,6 @@ def plan_checkpoints(costs: Sequence[Sequence[int]], model: ModelHeld,
     estimate = step.held + model.start
     ceiling = 0 if step.limit is None else int(step.limit * (1 - MARGIN))
     budget = max(0, ceiling - estimate)
-    if not budget and ceiling and model.start_apart is not None:
-        # the cautious sum leaves nothing: tell the head's instant from the
-        # blocks' (model_held_bytes)
-        estimate = step.held + model.start_apart
-        budget = max(0, ceiling - estimate)
     end = walk = None
     if step.end_held is not None:
         end = step.end_held + model.end
@@ -538,5 +538,5 @@ def plan_checkpoints(costs: Sequence[Sequence[int]], model: ModelHeld,
             else:
                 moe_out = ()
     return RematPlan(step.fun_name, rungs, kept + sum(moe_out), budget,
-                     estimate, step.limit, end, walk,
+                     estimate, step.limit, model.start_by, end, walk,
                      moe_out if any(moe_out) else ())

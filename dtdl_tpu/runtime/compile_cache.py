@@ -32,7 +32,9 @@ The account is one a process because jax's listeners are.
 :func:`remat_plans` returns them, and :func:`compile_totals` carries the
 newest as ``remat_blocks_by_rung``, ``remat_kept_bytes``,
 ``remat_budget_bytes``, ``remat_estimate_bytes`` (held as the backward pass
-begins) and, where the step's gradients are read together,
+begins), ``remat_start_by`` (``"head"`` where the logits and their cotangent
+set that estimate, ``"block"`` where one block's live set did) and, where
+the step's gradients are read together,
 ``remat_end_bytes`` and ``remat_walk_bytes`` (held as it ends, and the most
 between the two instants: the plan was held to all three), and
 ``remat_moe_out_blocks``, ``remat_moe_out_bytes`` (how many blocks keep
@@ -279,7 +281,8 @@ def compile_totals() -> dict:
     """The account's totals under the keys of :data:`ACCOUNT_EVENTS`: the
     seconds covered by each kind of duration, and the counts; and of the
     newest checkpoint plan the blocks at each rung (rung 0 first), the bytes
-    it keeps, its budget and the estimates it was chosen against; the
+    it keeps, its budget, the estimates it was chosen against and which
+    instant set the first; the
     collector's collections and pause seconds by generation and its longest
     pause.  ``{}`` while the account is empty."""
     totals = {}
@@ -295,6 +298,7 @@ def compile_totals() -> dict:
         totals["remat_kept_bytes"] = plan.kept_bytes
         totals["remat_budget_bytes"] = plan.budget_bytes
         totals["remat_estimate_bytes"] = plan.estimate_bytes
+        totals["remat_start_by"] = plan.start_by
         totals["remat_moe_out_blocks"] = sum(b > 0 for b in plan.moe_out)
         totals["remat_moe_out_bytes"] = sum(plan.moe_out)
         if plan.end_bytes is not None:

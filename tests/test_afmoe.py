@@ -500,3 +500,35 @@ def test_the_accepted_cells_planned_rehearsal_steps_lower_as_the_parents(
         == _PARENT_TEXT_PLANNED[cell_name]
     plan = compile_cache.remat_plans()[-1]
     assert min(plan.rungs) >= 2 and plan.moe_out == ()
+
+
+# the Trinity cell's rehearsal step as it was lowered while the plan added
+# the head's instant to a block's and took the larger of the two only where
+# that sum left no budget: without a limit; at a limit where the sum leaves
+# none, the cell's own case at a v5e's limit (the plan pays for ``moe_out``
+# and keeps three blocks' ``flash_out``); and at a v5e's.  Taking the larger
+# everywhere leaves all three as they were (the olmo7b cell's rehearsal,
+# whose plan keeps every rung either way, is pinned above)
+_TRINITY_TEXT = {
+    None: ("8891a237631354fa85138beefff01e84dae51355b55f65d15ac5c7af0a5f1f8f",
+           (0, 0, 0, 0, 0)),
+    2_960_000: (
+        "2709cc41df966337fc5c89a38f2deec7d52761529d8105c13571b9fa11e2cb2f",
+        (1, 1, 1, 0, 0)),
+    16_844_324_864: (
+        "1bc18a5066f7f90ee116cfb731d08059aee7f03eae0f464a7fc7c9007a8e81ec",
+        (3, 2, 2, 2, 2)),
+}
+
+
+@pytest.mark.parametrize("limit", list(_TRINITY_TEXT),
+                         ids=["no_limit", "sum_leaves_none", "v5e_limit"])
+def test_the_trinity_rehearsal_step_lowers_as_the_parents(limit,
+                                                          monkeypatch):
+    from dtdl_tpu.models import remat_plan
+    from dtdl_tpu.runtime import compile_cache
+    monkeypatch.setattr(remat_plan, "device_bytes_limit", lambda: limit)
+    digest, rungs = _TRINITY_TEXT[limit]
+    assert _rehearsal_text_digest("trinitymini-train-share16") == digest
+    plan = compile_cache.remat_plans()[-1]
+    assert plan.rungs == rungs and bool(plan.moe_out) == bool(limit)

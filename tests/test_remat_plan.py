@@ -226,7 +226,11 @@ def test_what_the_step_holds_beside_the_model():
         1650 + 4 * 1000 * 8 * 4 + 400, 1650 + 550)
     dense = remat_plan.model_held_bytes(4, 100, 64, 256, 2, 1000, 4000, 2)
     hidden = remat_plan.model_held_bytes(4, 100, 64, 256, 2, 0, 4000, 2)
-    assert dense.start - hidden.start == 400 * 1000 * (4 + 2)   # logits + cotangent
+    # the logits and their cotangent outweigh the block's live set and take
+    # its place as the backward pass begins
+    assert dense.start - hidden.start == (400 * 1000 * (4 + 2)
+                                          - 400 * (6 * 256 + 8 * 64) * 2)
+    assert (dense.start_by, hidden.start_by) == ("head", "block")
     assert hidden.start == (2000 + 2 * 400 * 64 * 2
                             + 400 * (6 * 256 + 8 * 64) * 2)
     # at the end: the parameters' copy and one block's live set, no input
@@ -292,36 +296,38 @@ def test_cells_plan_at_the_chips_limit(name, limit, monkeypatch):
     assert plan.kept_bytes <= plan.budget_bytes
     assert plan.kept_bytes == sum(sum(c[:r])
                                   for c, r in zip(costs, plan.rungs))
-    if name.startswith("olmo7b"):       # holds all of it several times over
-        assert plan.rungs == (3, 3)
+    if name.startswith("olmo7b") or limit == V5E_LIMIT:     # holds all of it
+        assert set(plan.rungs) == {3}
         assert plan.kept_bytes == sum(map(sum, costs))
     else:                               # does not hold all of it
         assert min(plan.rungs) >= 2 and len(plan.rungs) == 8
-        assert plan.estimate_bytes + plan.kept_bytes <= limit * 15 / 16
+        assert plan.kept_bytes < sum(map(sum, costs))
+    assert plan.estimate_bytes + plan.kept_bytes <= limit * 15 / 16
 
 
 # rungs, kept, budget and estimate of the step each cell traces at a v5e's
-# limit (PERF.md section 4).  The four one-chip cells' are what they were
-# before the plan had a second instant (PR 33's tree reads the same bytes);
-# the four-chip cell's step reads its gradients together and gets the
-# one-chip plan of the same shapes (before PR 34: rungs (2,1,1,1,1,1,1,1),
-# 0.407 GB kept of a 0.460 GB budget, an estimate of 15.332 GB that counted
-# 2.56 GB of gradients beside the residuals they replace), and so does a
-# guarded step on one device.
-_OLMO1B = ((3, 3, 3, 3, 3, 3, 2, 2), 2_955_540_480, 3_019_701_240,
-           12_771_853_320)
-_READ_TOGETHER = (12_591_927_304, 13_801_556_488)       # end, walk
+# limit (PERF.md section 4), with the head's instant and a block's told
+# apart (model_held_bytes): their sum would stand high by the smaller of the
+# two (the OLMo cells' block live set, 1.073 GB; the hybrid cells' logits,
+# 0.933 and 1.006 GB), and the OLMo-1B, Qwen3-Next and Kimi-Linear cells
+# would recompute residuals that fit.  The four-chip cell's step reads its
+# gradients together and gets the one-chip plan of the same shapes, and so
+# does a guarded step on one device.
+_OLMO1B = ((3, 3, 3, 3, 3, 3, 3, 3), 3_492_149_248, 4_092_918_776,
+           11_698_635_784)
+_READ_TOGETHER = (12_591_927_304, 14_204_766_216)       # end, walk
 _PINNED = {
     "olmo1b-train-b4s2048": ("olmo1b-train-b4s2048", 1, False, _OLMO1B),
+    # the same rungs and bytes kept as under the sum: the same program
     "olmo7b-train-b2s2048": ("olmo7b-train-b2s2048", 1, False, (
-        (3, 3), 696_962_560, 5_128_281_592, 10_663_272_968)),
+        (3, 3), 696_962_560, 5_937_387_000, 9_854_167_560)),
     "qwen3next-train-share16": ("qwen3next-train-share16", 1, False, (
-        (2, 2, 1, 1), 1_476_804_480, 1_494_105_784, 14_297_448_776)),
+        (2, 2, 2, 2), 1_912_905_600, 2_427_372_664, 13_364_181_896)),
     "kimilinear-train-share32": ("kimilinear-train-share32", 1, False, (
-        (2, 2, 1, 1, 1), 1_812_872_960, 2_012_564_216, 13_778_990_344)),
-    # PR 35: the sum of the head's instant and a block's reads 16.476 GB and
-    # leaves nothing; told apart 14.284 GB (model_held_bytes.start_apart).
-    # PR 36: the four expert blocks keep ``moe_out`` for the norm on their
+        (2, 2, 2, 2, 2), 2_521_537_280, 3_018_951_416, 12_772_603_144)),
+    # the sum of the head's instant and a block's read 16.476 GB here and
+    # left nothing, so this cell stood on the two told apart already.
+    # The four expert blocks keep ``moe_out`` for the norm on their
     # output, 4 x 16,382 x 2048 x 2 = 268,402,688 B, paid for first; block
     # 0's rung 2 (603,906,048 B) then no longer fits, where PR 35 gave
     # rungs (2,1,1,1,1) and 1,285,397,248 B
@@ -359,15 +365,21 @@ def test_each_cells_plan_at_the_v5e_limit_is_pinned(case, monkeypatch,
     totals = compile_cache.compile_totals()
     assert (totals["remat_moe_out_blocks"],
             totals["remat_moe_out_bytes"]) == (blocks, out)
+    # and which instant set the estimate: the logits and their cotangent in
+    # the OLMo cells (a vocabulary of 50,304 at a row of 2,047), one block's
+    # live set in the hybrid cells (an eighth of the vocabulary, wide blocks)
+    start_by = "head" if name.startswith("olmo") else "block"
+    assert plan.start_by == totals["remat_start_by"] == start_by
     if chips == 1 and not guarded:
         # no gradient outlives its block: the first instant alone
         assert plan.end_bytes is None and plan.walk_bytes is None
     else:
         # the second instant is reported, fits, and is what shapes say:
         # 12 B a parameter of state (and its counters), 4 of gradient, 2 of
-        # bf16 copy, one block's live set; the walk between the two peaks
-        # after blocks 7 and 6 (rung 2: they keep less than their
-        # gradients take) and stays under the ceiling as well
+        # bf16 copy, one block's live set; every block at rung 3 keeps more
+        # than its gradient takes, so the walk between the two peaks as it
+        # begins, before block 7 is differentiated, and stays under the
+        # ceiling as well: nothing is given back
         assert (plan.end_bytes, plan.walk_bytes) == _READ_TOGETHER
         assert plan.end_bytes < plan.walk_bytes <= ceiling
         n_params, t = 639_928_320, 4 * 2047
@@ -627,17 +639,23 @@ def test_plan_is_in_the_account_and_in_the_observers_summary(monkeypatch):
     want = {"remat_blocks_by_rung": [0, 0, 1, 1],
             "remat_kept_bytes": plan.kept_bytes,
             "remat_budget_bytes": plan.budget_bytes,
-            "remat_estimate_bytes": plan.estimate_bytes}
+            "remat_estimate_bytes": plan.estimate_bytes,
+            # the tiny model's block live set outweighs its logits
+            "remat_start_by": "block"}
     totals = compile_cache.compile_totals()
     summary = Observer().summary()
     assert {k: totals[k] for k in want} == want
     assert {k: summary[k] for k in want} == want
     # a step whose gradients no one reads together has no second instant
     assert "remat_end_bytes" not in totals
-    step, state, batch = _tiny_step(monkeypatch, (3, 2), guard=StepGuard())
+    # (a guarded step on a device roomy enough for its walk as well: the
+    # limit that buys (3, 2) as the backward pass begins leaves the walk to
+    # its end too little, and the plan gives block 0's rung 3 back)
+    step, state, batch = _tiny_step(monkeypatch, None, guard=StepGuard())
+    monkeypatch.setattr(remat_plan, "device_bytes_limit", lambda: 10**9)
     step.lower(state, batch)
     plan, totals = compile_cache.remat_plans()[-1], compile_cache.compile_totals()
-    assert plan.rungs == (3, 2) and plan.end_bytes <= plan.walk_bytes
+    assert plan.rungs == (3, 3) and plan.end_bytes <= plan.walk_bytes
     assert (totals["remat_end_bytes"], totals["remat_walk_bytes"]) == (
         plan.end_bytes, plan.walk_bytes)
 
@@ -676,9 +694,10 @@ def test_ddp_plans_from_one_chips_shapes_and_gspmd_recomputes(
                         AutoSharded(mesh=mesh))
     assert auto.rungs == (0, 0) and auto.limit_bytes is None
 
-    # and the step runs at the top rung under shard_map
-    step, state, batch = _tiny_step(monkeypatch, (3, 3),
-                                    strategy=two_devices)
+    # and the step runs at the top rung under shard_map, on a device whose
+    # limit buys it at both instants and on the walk between them
+    step, state, batch = _tiny_step(monkeypatch, None, strategy=two_devices)
+    monkeypatch.setattr(remat_plan, "device_bytes_limit", lambda: 10**9)
     state = two_devices.replicate(state)
     new, metrics = step(state, two_devices.shard_batch(batch))
     assert np.isfinite(float(metrics["loss"]))
@@ -698,14 +717,18 @@ def test_moe_blocks_keep_the_attention_names_only(monkeypatch):
 
 
 def test_chunked_loss_frees_the_logits_for_a_richer_plan(monkeypatch):
-    model = _tiny(dtype=jnp.bfloat16)
+    # a vocabulary whose logits outweigh a block's live set, so that the
+    # dense head's instant sets the estimate and the chunked loss's does not
+    model = _tiny(dtype=jnp.bfloat16).clone(vocab_size=1024)
     dense = _traced_plan(monkeypatch, model, 2, 64, None)
     chunked = _traced_plan(monkeypatch, model, 2, 64, None,
                            vocab_chunk_size=16)
     tokens = 2 * 63
-    table = 256 * 64 * 4
+    table = 1024 * 64 * 4
+    live = tokens * (6 * 128 + 8 * 64) * 2
+    assert (dense.start_by, chunked.start_by) == ("head", "block")
     assert dense.estimate_bytes - chunked.estimate_bytes == (
-        tokens * 256 * (4 + 2) - 4 * tokens * 16 * 4 - table)
+        tokens * 1024 * (4 + 2) - live - 4 * tokens * 16 * 4 - table)
     # at the limit that buys the dense head the flash rung, the chunked
     # loss gets further up the ladder
     costs = [remat_plan.residual_bytes(2, 63, 64, 4, 128, 2)] * 2
@@ -742,29 +765,41 @@ def test_the_trinity_blocks_bytes_are_what_shapes_say():
 
 
 def test_the_head_and_the_blocks_are_told_apart_where_the_sum_leaves_nothing():
-    """``start`` adds the logits to a block's live set; ``start_apart`` takes
-    the larger.  The plan stands on the sum wherever that leaves a budget
-    (every accepted cell: pinned above) and falls back where it leaves
-    none."""
+    """The logits die with the head's backward, before the last block is
+    recomputed: what the model holds as the backward pass begins has the
+    larger of the head's instant and a block's, never their sum, and says
+    which.  The plan stands on it whatever the budget: where the sum would
+    leave none, and where it would leave room."""
+    rest = 3 * 200 * 64 * 2 + 2000      # the blocks' inputs, the bf16 copy
+    logits = 200 * 1000 * 6             # f32 logits, bf16 cotangent
     held = remat_plan.model_held_bytes(2, 100, 64, 0, 3, 1000, 4000, 2,
                                        block_live_bytes=900_000)
-    logits = 200 * 1000 * 6
-    assert held.start - held.start_apart == min(logits, 900_000)
+    assert (held.start, held.start_by) == (rest + logits, "head")
+    wide = remat_plan.model_held_bytes(2, 100, 64, 0, 3, 1000, 4000, 2,
+                                       block_live_bytes=2_000_000)
+    assert (wide.start, wide.start_by) == (rest + 2_000_000, "block")
+    assert held.end == 2000 + 900_000   # the end has no logits either way
+    # the dense block: the logits (2.4 MB) take the place of its live set
+    # (1.6 MB), which the sum added to them
     dense = remat_plan.model_held_bytes(4, 100, 64, 256, 2, 1000, 4000, 2)
-    assert dense.start - dense.start_apart == 400 * (6 * 256 + 8 * 64) * 2
+    live = 400 * (6 * 256 + 8 * 64) * 2
+    assert live < 400 * 1000 * 6 and dense.start_by == "head"
+    assert dense.start == 2000 + 2 * 400 * 64 * 2 + 400 * 1000 * 6
+    assert dense.end == 2000 + live
     costs = [(10_000, 40_000, 0)] * 3
-    limit = 16 * (held.start + 31_000) // 15
-    with remat_plan.step_memory("lm_train_step", 0, limit):
-        roomy = remat_plan.plan_checkpoints(costs, held, [0] * 3)
-    assert roomy.estimate_bytes == held.start and roomy.rungs == (1, 1, 1)
-    limit = 16 * (held.start_apart + 71_000) // 15
-    with remat_plan.step_memory("lm_train_step", 0, limit):
-        apart = remat_plan.plan_checkpoints(costs, held, [0] * 3)
-        none = remat_plan.plan_checkpoints(
-            costs, held._replace(start_apart=None), [0] * 3)
-    assert apart.estimate_bytes == held.start_apart
-    assert apart.rungs == (2, 1, 1) and apart.kept_bytes == 70_000
-    assert none.rungs == (0, 0, 0) and none.estimate_bytes == held.start
+    for room, rungs, kept in [(31_000, (1, 1, 1), 30_000),
+                              (71_000, (2, 1, 1), 70_000),
+                              (931_000, (2, 2, 2), 150_000)]:
+        limit = 16 * (held.start + room) // 15
+        ceiling = int(limit * 15 / 16)
+        with remat_plan.step_memory("lm_train_step", 0, limit):
+            plan = remat_plan.plan_checkpoints(costs, held, [0] * 3)
+        assert plan.estimate_bytes == held.start and plan.start_by == "head"
+        assert plan.budget_bytes == ceiling - held.start
+        assert (plan.rungs, plan.kept_bytes) == (rungs, kept)
+        # the sum would have left room only in the last case
+        assert (ceiling - held.start - min(logits, 900_000) > 0) == (
+            room > 900_000)
 
 
 def test_the_trinity_cells_estimate_against_the_compilers_total(monkeypatch):
