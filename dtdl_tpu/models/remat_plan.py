@@ -293,7 +293,8 @@ class ModelHeld(NamedTuple):
 def model_held_bytes(batch: int, seq: int, d_model: int, d_ff: int,
                      n_layers: int, vocab_size: int, param_bytes: int,
                      itemsize: int,
-                     block_live_bytes: int | None = None) -> ModelHeld:
+                     block_live_bytes: int | None = None,
+                     heads: int = 1) -> ModelHeld:
     """What the model's forward and backward hold under rung 0, from shapes.
     When the backward pass begins (``start``): the parameters' copy in the
     compute dtype (made in the forward pass and read again in the backward;
@@ -301,9 +302,11 @@ def model_held_bytes(batch: int, seq: int, d_model: int, d_ff: int,
     input, and the larger of two things that are never live at once: the
     f32 logits with their cotangent in the compute dtype, while the head is
     differentiated (``vocab_size`` 0 where the caller takes the hidden
-    states instead), and one block's live set while it is recomputed and
-    differentiated (``wi wg``, their product and the three cotangents; eight
-    ``[tokens, d_model]`` values of the attention half), or
+    states instead; ``heads`` 2 where a multi-token-prediction module's
+    head is differentiated beside the model's), and one block's live set
+    while it is recomputed and differentiated (``wi wg``, their product and
+    the three cotangents; eight ``[tokens, d_model]`` values of the
+    attention half), or
     ``block_live_bytes`` where the blocks are not the dense one
     (:func:`hybrid_block_live_bytes`, the largest block's).  The logits die
     with the head's backward, before the last block is recomputed, so the
@@ -317,7 +320,7 @@ def model_held_bytes(batch: int, seq: int, d_model: int, d_ff: int,
         block_live_bytes = t * (6 * d_ff + 8 * d_model) * itemsize
     block_input = t * d_model * itemsize
     copy = param_bytes * itemsize // 4
-    logits = t * vocab_size * (4 + itemsize)
+    logits = heads * t * vocab_size * (4 + itemsize)
     start = n_layers * block_input + copy + max(logits, block_live_bytes)
     return ModelHeld(start, copy + block_live_bytes, block_input,
                      "head" if logits > block_live_bytes else "block")
@@ -354,7 +357,9 @@ def hybrid_block_live_bytes(batch: int, seq: int, d_model: int,
     in the compute dtype, ``o`` in float32 and the state at every chunk,
     and half as much again.  A latent-attention block (``mla``: ``(heads,
     MlaSpec)``): q, the assembled k, v, ``kv_b``'s output and ``o``, each
-    with its cotangent.  A dense MLP: six
+    with its cotangent; with a query latent also ``q_a``'s output and its
+    norm's, and with a rotation the unrotated q beside the rotated one.  A
+    dense MLP: six
     ``d_ff`` values.  Norms on the sublayers' outputs (``post_norms``): the
     two sublayers' outputs before their norms, each with its cotangent.  How
     the step's estimate stands against the v5e
@@ -395,6 +400,8 @@ def hybrid_block_live_bytes(batch: int, seq: int, d_model: int,
         qk = sizes.nope_dim + sizes.rope_dim
         live += 2 * t * heads * (2 * qk + sizes.nope_dim
                                  + 3 * sizes.v_dim) * itemsize
+        rotated = heads * qk if sizes.rope != "none" else 0
+        live += 2 * t * (2 * sizes.q_rank + rotated) * itemsize
     if held:
         rows, _ = held_buffer_rows(t, held.top_k, held.held,
                                    held.router_width)

@@ -1169,26 +1169,36 @@ class KimiDeltaAttention(nn.Module):
 
 
 class LatentAttention(nn.Module):
-    """Multi-head latent attention without a rotation (MLA, NoPE): ``q``
-    projects to ``heads x (nope_dim + rope_dim)``; ``kv_a`` to ``kv_rank +
-    rope_dim``, of which the first ``kv_rank`` pass an RMSNorm
-    (``kv_norm``) and ``kv_b`` to each head's ``nope_dim`` of key and
-    ``v_dim`` of value, and the last ``rope_dim`` are one key part shared by
-    all heads (not rotated); causal softmax attention at the query/key size
-    ``nope_dim + rope_dim`` with values of ``v_dim`` (the flash kernels take
-    the two sizes), then ``out``.  Trains only: in training there is no
-    cache, so what is latent about it is the shape."""
+    """Multi-head latent attention (MLA), in two forms.
+
+    The query: with ``q_rank`` 0, ``q`` projects ``x`` to ``heads x
+    (nope_dim + rope_dim)`` in one step; otherwise through a query latent,
+    ``q_b(RMSNorm(q_a x))`` with ``q_a`` to ``q_rank`` (norm ``q_a_norm``).
+    Keys and values: ``kv_a`` to ``kv_rank + rope_dim``, of which the first
+    ``kv_rank`` pass an RMSNorm (``kv_norm``) and ``kv_b`` to each head's
+    ``nope_dim`` of key and ``v_dim`` of value, and the last ``rope_dim``
+    are one key part shared by all heads.  ``rope``: ``"none"`` rotates
+    neither (NoPE); ``"pairs"`` rotates the query's last ``rope_dim`` and
+    that shared key part by position, adjacent pairs ``(2i, 2i + 1)``
+    (``cos``, ``sin`` ``rope_dim / 2`` wide).  Causal softmax
+    attention at the query/key size ``nope_dim + rope_dim`` with values of
+    ``v_dim`` (the flash kernels take the two sizes), then ``out``.  Trains
+    only: in training there is no cache, so what is latent about it is the
+    shape (a served form would cache ``kv_a``'s row a token and absorb
+    ``kv_b`` into the query and the output)."""
     n_heads: int
     nope_dim: int
     rope_dim: int
     v_dim: int
     kv_rank: int
+    q_rank: int = 0
+    rope: str = "none"
     norm_eps: float = 1e-6
     attn_impl: str = "flash"
     dtype: Dtype = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, cos=None, sin=None):
         b, s, d_model = x.shape
         h = self.n_heads
 
@@ -1200,15 +1210,32 @@ class LatentAttention(nn.Module):
                                   "embed", "heads", "head_dim"),
                 name=name)
 
-        q = heads_of("q", self.nope_dim + self.rope_dim)(x)
-        latent = nn.Dense(self.kv_rank + self.rope_dim, use_bias=False,
-                          dtype=self.dtype,
-                          kernel_init=_part(nn.initializers.lecun_normal(),
-                                            "embed", None), name="kv_a")(x)
+        def norm(name):
+            return RMSNorm(eps=self.norm_eps, dtype=self.dtype,
+                           axis_name=None, name=name)
+
+        def latent_of(name, width):
+            return nn.Dense(width, use_bias=False, dtype=self.dtype,
+                            kernel_init=_part(nn.initializers.lecun_normal(),
+                                              "embed", None), name=name)(x)
+
+        qk = self.nope_dim + self.rope_dim
+        if self.q_rank:
+            q = heads_of("q_b", qk)(norm("q_a_norm")(
+                latent_of("q_a", self.q_rank)))
+        else:
+            q = heads_of("q", qk)(x)
+        latent = latent_of("kv_a", self.kv_rank + self.rope_dim)
         shared = latent[..., self.kv_rank:]              # [B, S, rope_dim]
         kv = heads_of("kv_b", self.nope_dim + self.v_dim)(
-            RMSNorm(eps=self.norm_eps, dtype=self.dtype, axis_name=None,
-                    name="kv_norm")(latent[..., :self.kv_rank]))
+            norm("kv_norm")(latent[..., :self.kv_rank]))
+        if self.rope not in ("none", "pairs"):
+            raise ValueError(f"latent attention's rope {self.rope!r}: "
+                             "'none' or 'pairs'")
+        if self.rope == "pairs":
+            def turned(t):              # [B, H, S, rope_dim], rotated
+                return apply_rope(t, cos, sin, interleave=True)
+            shared = turned(shared[:, None])[:, 0]
         k = jnp.concatenate(
             [kv[..., :self.nope_dim],
              jnp.broadcast_to(shared[:, :, None, :],
@@ -1216,6 +1243,9 @@ class LatentAttention(nn.Module):
         v = kv[..., self.nope_dim:]
         # [B, S, H, D] -> [B, H, S, D]
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+        if self.rope == "pairs":
+            q = jnp.concatenate([q[..., :self.nope_dim],
+                                 turned(q[..., self.nope_dim:])], axis=-1)
         if self.attn_impl == "flash":
             o = flash_attention(q, k, v, causal=True)
         else:
@@ -1497,11 +1527,14 @@ class KdaSpec(NamedTuple):
 
 
 class MlaSpec(NamedTuple):
-    """``LatentAttention``'s sizes."""
+    """``LatentAttention``'s sizes, its query latent's width (0: none) and
+    its rotation (``"none"`` or by adjacent ``"pairs"``)."""
     nope_dim: int
     rope_dim: int
     v_dim: int
     kv_rank: int
+    q_rank: int = 0
+    rope: str = "none"
 
 
 class HeldSpec(NamedTuple):
@@ -1615,7 +1648,8 @@ class Block(nn.Module):
         elif spec.kind == "mla":
             x = x + LatentAttention(
                 self.n_heads, **spec.mla._asdict(), norm_eps=spec.norm_eps,
-                attn_impl=self.attn_impl, dtype=self.dtype, name="attn")(h)
+                attn_impl=self.attn_impl, dtype=self.dtype,
+                name="attn")(h, cos, sin)
         elif spec.kind == "full":
             x = x + post("ln_attn_out", Attention(
                 self.n_heads, self.head_dim, self.attn_impl, self.dtype,
@@ -1653,8 +1687,48 @@ def _remat_block(rung: int, linear: bool | str = False, held: bool = False,
                     policy=remat_plan.policy(rung, linear, held, moe_out))
 
 
+class MtpLogits(NamedTuple):
+    """What a model with a multi-token-prediction module returns beside its
+    logits (``TransformerLM(mtp_layers=1)``): the module's logits ``[B, S -
+    1, V]`` over inputs of ``S`` positions, position ``i`` predicting the
+    input two ahead of it (the next-token target at ``i + 1``), and the
+    weight of their cross entropy in the training loss."""
+    logits: Any
+    weight: float
+
+
+class MultiTokenPrediction(nn.Module):
+    """DeepSeek-V3's multi-token-prediction module at one depth
+    (arXiv:2412.19437).  At position ``i`` it reads the model's last hidden
+    state ``h_i`` (before the final norm) and the embedding of the next
+    input ``t_{i+1}``: ``eh_proj([enorm(Emb t_{i+1}); hnorm(h_i)])``, then
+    a block of its own (``block``, built as the model's last block), then
+    ``head_norm`` and the model's head: logits that predict ``t_{i+2}``.
+    Over ``S`` inputs it has ``S - 1`` positions.  The embedding and the
+    head are the model's; everything else is the module's own."""
+    block: Any                    # an unbound Block (or its remat)
+    norm_eps: float = 1e-6
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, hidden, tokens, emb, head, cos, sin):
+        def norm(name):
+            return RMSNorm(eps=self.norm_eps, dtype=self.dtype, name=name)
+
+        nxt = jnp.take(emb, tokens[:, 1:], axis=0).astype(self.dtype)
+        x = jnp.concatenate([norm("enorm")(nxt),
+                             norm("hnorm")(hidden[:, :-1])], axis=-1)
+        x = nn.Dense(hidden.shape[-1], use_bias=False, dtype=self.dtype,
+                     kernel_init=_part(nn.initializers.lecun_normal(),
+                                       None, "embed"), name="eh_proj")(x)
+        x = norm("head_norm")(self.block(x, cos, sin))
+        return jnp.einsum("bsd,vd->bsv", x,
+                          head.astype(self.dtype)).astype(jnp.float32)
+
+
 class TransformerLM(nn.Module):
-    """Decoder-only LM; input int32 tokens [batch, seq] -> logits f32."""
+    """Decoder-only LM; input int32 tokens [batch, seq] -> logits f32 (and,
+    with a multi-token-prediction module, ``(logits, MtpLogits)``)."""
     vocab_size: int = 32000
     d_model: int = 512
     n_layers: int = 8
@@ -1687,8 +1761,8 @@ class TransformerLM(nn.Module):
     # --- hybrid architectures (all at their defaults: the model above, the
     # same parameter paths and the same compiled programs) -----------------
     # per-layer token mixer: 'full' (softmax attention), 'linear' (Gated
-    # DeltaNet), 'kda' (Kimi Delta Attention) or 'mla' (latent attention
-    # without a rotation); () = every layer 'full'
+    # DeltaNet), 'kda' (Kimi Delta Attention) or 'mla' (latent attention,
+    # LatentAttention); () = every layer 'full'
     layer_kinds: tuple = ()
     n_kv_heads: int = 0           # K/V heads; 0 = n_heads
     attn_head_dim: int = 0        # stated head size; 0 = d_model // n_heads
@@ -1715,9 +1789,18 @@ class TransformerLM(nn.Module):
     kda_gate_rank: int = 0        # decay's and the gate's two-step
     kda_conv: int = 4             # projections, the causal conv's width
     mla_nope_dim: int = 0         # latent attention: a head's key part of
-    mla_rope_dim: int = 0         # its own, the part all heads share (not
-    mla_v_dim: int = 0            # rotated), the value head, the latent's
-    mla_kv_rank: int = 0          # width
+    mla_rope_dim: int = 0         # its own, the part all heads share (and
+    mla_v_dim: int = 0            # the query's last as many), the value
+    mla_kv_rank: int = 0          # head, the key/value latent's width, the
+    mla_q_rank: int = 0           # query latent's (0: q in one projection)
+    # latent attention's rotation of the query's last mla_rope_dim and the
+    # shared key part: 'none' (NoPE) or by adjacent 'pairs' (the rotation
+    # tables are then mla_rope_dim wide)
+    mla_rope: str = "none"
+    # multi-token prediction (MultiTokenPrediction): 0 or 1 module after the
+    # last block; its cross entropy weighs mtp_loss_weight in the loss
+    mtp_layers: int = 0
+    mtp_loss_weight: float = 0.0
     norm_eps: float = 1e-6        # of the blocks' and the final RMSNorm
     # moe_dispatch='held' (one chip's share under expert parallelism,
     # HeldExperts): n_experts are held here, ids moe_first_expert onward, of
@@ -1781,7 +1864,7 @@ class TransformerLM(nn.Module):
         kda = KdaSpec(self.kda_heads, self.kda_head_dim, self.kda_gate_rank,
                       self.kda_conv)
         mla = MlaSpec(self.mla_nope_dim, self.mla_rope_dim, self.mla_v_dim,
-                      self.mla_kv_rank)
+                      self.mla_kv_rank, self.mla_q_rank, self.mla_rope)
         return [BlockSpec(kind=kind, n_kv_heads=self.n_kv_heads,
                           rope_dims=self.rope_dims, qk_norm=self.qk_norm,
                           attn_gate=self.attn_gate,
@@ -1916,7 +1999,10 @@ class TransformerLM(nn.Module):
                          specs=None):
         """What each rematerialized block keeps (models/remat_plan.py), from
         the traced token shape and this model's widths; recorded in the
-        compile account beside the step that traces it."""
+        compile account beside the step that traces it.  The
+        multi-token-prediction module's block is one more block, the last
+        (reckoned at the model's positions, one more than it has), and its
+        head's logits join the model's at the head's instant."""
         batch, seq = tokens_shape
         itemsize = jnp.dtype(self.dtype).itemsize
         params = self.variables.get("params", {})
@@ -1924,6 +2010,12 @@ class TransformerLM(nn.Module):
         # a block's gradient has its parameters' shapes and dtypes
         block_grads = [remat_plan.tree_bytes(params.get(f"block_{i}", {}))
                        for i in range(self.n_layers)]
+        n_blocks, heads = self.n_layers, 1
+        if self.mtp_layers:
+            is_moe, specs = list(is_moe) + [is_moe[-1]], list(specs) + [
+                specs[-1]]
+            block_grads.append(remat_plan.tree_bytes(params.get("mtp", {})))
+            n_blocks, heads = n_blocks + 1, 2
         vocab = 0 if return_hidden else self.vocab_size
         moe_out = ()
         if specs is None or specs[0] is None:
@@ -1931,8 +2023,8 @@ class TransformerLM(nn.Module):
                 batch, seq, self.d_model, self.n_heads,
                 0 if moe else self.d_ff, itemsize) for moe in is_moe]
             held = remat_plan.model_held_bytes(
-                batch, seq, self.d_model, self.d_ff, self.n_layers, vocab,
-                param_bytes, itemsize)
+                batch, seq, self.d_model, self.d_ff, n_blocks, vocab,
+                param_bytes, itemsize, heads=heads)
         else:
             # each block's own bytes: a linear block may keep what the delta
             # rule's loop reads, a full one the attention names at its own
@@ -1965,8 +2057,8 @@ class TransformerLM(nn.Module):
                 post_norms=spec.post_norms)
                 for spec in specs)
             held = remat_plan.model_held_bytes(
-                batch, seq, self.d_model, 0, self.n_layers, vocab,
-                param_bytes, itemsize, block_live_bytes=live)
+                batch, seq, self.d_model, 0, n_blocks, vocab,
+                param_bytes, itemsize, block_live_bytes=live, heads=heads)
             # what a block keeps of its held experts for the norm on their
             # output (Block._hybrid)
             moe_out = [batch * seq * self.d_model * itemsize
@@ -1991,10 +2083,20 @@ class TransformerLM(nn.Module):
         ``mutable=['cache']``) — the autoregressive-generation contract of
         :func:`generate`."""
         del train
-        emb = self.param(
+        emb = table = self.param(
             "embed", _part(nn.initializers.normal(stddev=0.02),
                            "vocab", "embed"),
             (self.vocab_size, self.d_model))
+        if self.mtp_layers not in (0, 1):
+            raise NotImplementedError(
+                f"{self.mtp_layers} multi-token-prediction modules: one is "
+                "built (a chain of them feeds each module's output to the "
+                "next)")
+        if self.mtp_layers and (decode or return_hidden):
+            raise NotImplementedError(
+                "the multi-token-prediction module trains only, over the "
+                "dense head: drafting with it (serve/draft.py) and the "
+                "chunked loss over its head are missing")
         if decode and (self.hybrid or not self.tie_embeddings):
             raise NotImplementedError(
                 "decode=True on a hybrid model (linear-attention or latent-"
@@ -2019,8 +2121,14 @@ class TransformerLM(nn.Module):
             if tokens.shape[1] > self.max_seq:
                 raise ValueError(f"{tokens.shape[1]} positions exceed "
                                  f"max_seq={self.max_seq}")
-            cos, sin = rope_frequencies(self.rope_dims or self.head_dim,
-                                        tokens.shape[1], self.rope_theta)
+            rotated = self.mla_rope != "none"
+            if rotated and "full" in (self.layer_kinds or ("full",)):
+                raise ValueError("one rotation table a model: rotated "
+                                 "latent attention beside 'full' layers")
+            cos, sin = rope_frequencies(
+                self.mla_rope_dim if rotated
+                else self.rope_dims or self.head_dim,
+                tokens.shape[1], self.rope_theta)
         else:
             cos, sin = rope_frequencies(self.head_dim, self.max_seq)
 
@@ -2035,15 +2143,15 @@ class TransformerLM(nn.Module):
         if self.remat and not decode:
             plan = self._checkpoint_plan(tokens.shape, is_moe,
                                          return_hidden, specs)
-        for i, moe in enumerate(is_moe):
+
+        def block_of(i, spec, moe, **place):
             block_cls = Block if plan is None else (
-                _remat_block(plan.rungs[i]) if specs[i] is None
+                _remat_block(plan.rungs[i]) if spec is None
                 else _remat_block(
                     plan.rungs[i],
-                    specs[i].kind if specs[i].kind in ("linear", "kda")
-                    else False, bool(specs[i].held),
-                    bool(plan.moe_out and plan.moe_out[i])))
-            block = block_cls(
+                    spec.kind if spec.kind in ("linear", "kda") else False,
+                    bool(spec.held), bool(plan.moe_out and plan.moe_out[i])))
+            return block_cls(
                 self.n_heads, self.head_dim, self.d_ff,
                 n_experts=self.n_experts if moe else 0,
                 attn_impl=self.attn_impl, dtype=self.dtype,
@@ -2053,13 +2161,16 @@ class TransformerLM(nn.Module):
                 moe_group_size=self.moe_group_size,
                 quantize=self.quantize,
                 paged_kernel=self.paged_kernel,
-                **({} if specs[i] is None else {"spec": specs[i]}),
-                name=f"block_{i}")
+                **({} if spec is None else {"spec": spec}), **place)
+
+        for i, moe in enumerate(is_moe):
+            block = block_of(i, specs[i], moe, name=f"block_{i}")
             # only pass the flag when set: a kwarg through nn.remat is
             # traced, and Attention branches on it in Python
             x = block(x, cos, sin, decode=True) if decode \
                 else block(x, cos, sin)
 
+        hidden = x
         x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
                     zero_centered=self.norm_zero_centered, name="ln_f")(x)
         if not self.tie_embeddings:
@@ -2073,7 +2184,16 @@ class TransformerLM(nn.Module):
             return x
         with jax.named_scope("head"):
             logits = jnp.einsum("bsd,vd->bsv", x, emb.astype(self.dtype))
-            return logits.astype(jnp.float32)
+            logits = logits.astype(jnp.float32)
+        if not self.mtp_layers:
+            return logits
+        # the module's block is built as the last block is, with the plan's
+        # last rung; the head is the model's (``emb`` here: tied or not)
+        mtp = MultiTokenPrediction(
+            block_of(self.n_layers, specs[-1], is_moe[-1], parent=None),
+            norm_eps=self.norm_eps, dtype=self.dtype, name="mtp")
+        return logits, MtpLogits(mtp(hidden, tokens, table, emb, cos, sin),
+                                 self.mtp_loss_weight)
 
 
 def generate(model: TransformerLM, params, prompt, max_new_tokens: int,

@@ -181,9 +181,15 @@ EVENT_CATALOG = frozenset({
 # file's ``kda_rule``, the same two parts), ``moe_dispatch`` the held
 # experts' routing plan, gather and weighted scatter-add
 # (models/transformer.py:HeldExperts), ``gate`` the attention's sigmoid
-# output gate (under ``attn``: the component ``attn_gate``).
+# output gate (under ``attn``: the component ``attn_gate``), ``mtp`` the
+# multi-token-prediction module's cross entropy in train/step.py: the same
+# word as the module's own flax scope (models/transformer.py:
+# MultiTokenPrediction, :data:`_MTP_MODULES`), so that what the module
+# costs, its block, its head and its loss, is the one component ``mtp``
+# (its block's kernels keep their own).
 DEVICE_SCOPES = frozenset({"embed", "head", "loss", "grad_sync", "update",
-                           "guard", "gdn", "kda", "moe_dispatch", "gate"})
+                           "guard", "gdn", "kda", "moe_dispatch", "gate",
+                           "mtp"})
 
 # scopes a function enters under a name that is another catalogue's, and
 # that map as there: the held experts' grouped SwiGLU runs outside its flax
@@ -202,8 +208,15 @@ MODULE_SCOPES = {
     "ln_attn_out": "norm", "ln_mlp_out": "norm",
 }
 _ATTN_PROJECTIONS = frozenset({"q", "k", "v", "out"})
-# latent attention's (``kv_a``, ``kv_b``: its two steps to keys and values)
-_MLA_PROJECTIONS = frozenset({"q", "kv_a", "kv_b", "out"})
+# latent attention's (``kv_a``, ``kv_b``: its two steps to keys and values;
+# ``q_a``, ``q_b``: the two to queries where it has a query latent)
+_MLA_PROJECTIONS = frozenset({"q", "kv_a", "kv_b", "out", "q_a", "q_b"})
+# the multi-token-prediction module's own sub-modules (flax scope ``mtp``):
+# the two norms before ``eh_proj``, its block, and the norm before the
+# model's head.  Every op under ``mtp`` is the component ``mtp`` but the
+# block's kernels, which keep their own (``flash``, ``moe_gmm``)
+_MTP_MODULES = frozenset({"enorm", "hnorm", "eh_proj", "block",
+                          "head_norm"})
 # the sub-modules of the hybrid blocks, by the module they sit in: a Gated
 # DeltaNet layer (flax scope ``gdn``, the same word as the delta rule's own
 # scope inside it) and the held experts' layer (``moe``)
@@ -220,7 +233,8 @@ _MOE_MODULES = {"router": "moe_router", "shared": "moe_shared",
                 "experts": "moe_experts"}
 # (``gate_proj``: the output gate's projection of its own, ``gate='own'``)
 _ATTN_OTHER = {"gate": "attn_gate", "q_norm": "norm", "k_norm": "norm",
-               "kv_norm": "norm", "gate_proj": "attn_proj"}
+               "kv_norm": "norm", "q_a_norm": "norm",
+               "gate_proj": "attn_proj"}
 
 # pallas_call ``name=`` -> component.  On the chip the kernel's HLO
 # instruction takes this name (``%flash_fwd.<n> = ... custom-call(...)
@@ -257,8 +271,9 @@ def device_component(name_stack: str):
 
     ``component`` is a member of :data:`DEVICE_SCOPES`, a value of
     :data:`MODULE_SCOPES` or :data:`KERNEL_NAMES`, ``attn_proj`` for
-    ``attn/{q,k,v,out,gate_proj,kv_a,kv_b}``, ``attn_gate`` for
-    ``attn/gate``; under
+    ``attn/{q,k,v,out,gate_proj,kv_a,kv_b,q_a,q_b}``, ``attn_gate`` for
+    ``attn/gate``; ``mtp`` for the multi-token-prediction module but its
+    block's kernels; under
     a Gated DeltaNet module ``gdn_proj`` (``gdn/{in_qkvz,in_ba,out}``),
     ``gdn_conv``, ``gdn`` for the delta rule itself (``gdn/gdn``) and
     ``gdn_other`` for the rest; under a Kimi Delta Attention module the same
@@ -309,6 +324,9 @@ def device_component(name_stack: str):
             inner = rest[0] if rest else None
             component = (name if inner == name else
                          _RULE_MODULES[name].get(inner, name + "_other"))
+        elif name == "mtp":
+            # the module's block runs the kernels under their own names
+            component = kernels[0] if kernels else component
         elif name == "moe":
             if kernels:
                 component = kernels[0]

@@ -52,7 +52,7 @@ def rope_rows(cos, sin, positions):
             jnp.concatenate([s, s], axis=-1))
 
 
-def apply_rope(x, cos, sin, offset=0, positions=None):
+def apply_rope(x, cos, sin, offset=0, positions=None, interleave=False):
     """Rotate [batch, heads, seq, head_dim] queries/keys.
 
     ``offset`` (int or traced scalar) is the global position of the shard's
@@ -60,6 +60,10 @@ def apply_rope(x, cos, sin, offset=0, positions=None):
     ``positions`` ([seq] int array, overrides ``offset``) gives each local
     row an arbitrary global position — the hook the zigzag ring layout uses
     (dtdl_tpu/parallel/sequence.py zigzag_positions).
+
+    Frequency ``i`` turns the pair of dims ``(i, i + head_dim/2)`` (the two
+    halves), or with ``interleave`` the adjacent pair ``(2i, 2i + 1)``, in
+    place (a config's ``rope_interleave``).
     """
     seq = x.shape[2]
     if positions is not None:
@@ -72,6 +76,11 @@ def apply_rope(x, cos, sin, offset=0, positions=None):
         s = jnp.take(sin, offset + jnp.arange(seq), axis=0)
     c = c[None, None, :, :]
     s = s[None, None, :, :]
+    if interleave:
+        pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
+        return out.reshape(x.shape).astype(x.dtype)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
     return out.astype(x.dtype)

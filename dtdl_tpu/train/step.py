@@ -174,6 +174,12 @@ def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
     wrong one).  A model with an untied head refuses ``vocab_chunk_size >
     0``.
 
+    A model that returns ``(logits, MtpLogits)`` (a multi-token-prediction
+    module: models/transformer.py) adds its weight times the module's cross
+    entropy to the loss: the mean over the targets two ahead
+    (``tokens[:, 2:]``, masked by ``mask[:, 1:]``), a mean of its own beside
+    the next-token one.  It is reported as the metric ``mtp_loss``.
+
     ``guard`` folds the resil anomaly check into the program, exactly as
     in :func:`make_train_step`.
     """
@@ -263,26 +269,40 @@ def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
                 if term is not None:
                     loss = loss + term
                 loss, held = held_experts(loss, muts)
-                return loss, (correct * scale, aux, held)
+                return loss, (correct * scale, aux, held, None)
         else:
+            def cross_entropy_sum(logits, targets, mask):
+                lse = jax.nn.logsumexp(logits, axis=-1)
+                true = jnp.take_along_axis(
+                    logits, targets[..., None].astype(jnp.int32), -1)[..., 0]
+                return jnp.sum((lse - true) * mask)
+
             def compute_loss(params):
-                logits, muts = state.apply_fn({"params": params}, inputs,
-                                              train=True, rngs=rngs,
-                                              mutable=collections)
+                out, muts = state.apply_fn({"params": params}, inputs,
+                                           train=True, rngs=rngs,
+                                           mutable=collections)
+                logits, mtp = out if isinstance(out, tuple) else (out, None)
+                mtp_loss = None
                 with jax.named_scope("loss"):
                     logits = logits.astype(jnp.float32)
-                    lse = jax.nn.logsumexp(logits, axis=-1)
-                    true = jnp.take_along_axis(
-                        logits, targets[..., None].astype(jnp.int32),
-                        -1)[..., 0]
-                    loss = jnp.sum((lse - true) * mask) * scale
+                    loss = cross_entropy_sum(logits, targets, mask) * scale
                     correct = jnp.sum(
                         (jnp.argmax(logits, -1) == targets) * mask) * scale
+                if mtp is not None:
+                    # the module's own scope (its flax name): a trace reads
+                    # all of the module's work under one word
+                    with jax.named_scope("mtp"):
+                        ahead = mask[:, 1:]
+                        mtp_loss = cross_entropy_sum(
+                            mtp.logits, targets[:, 1:], ahead) * (
+                            strategy.num_replicas / jnp.maximum(
+                                strategy.sum_sync(ahead.sum()), 1.0))
+                        loss = loss + mtp.weight * mtp_loss
                 term, aux = aux_term(muts)
                 if term is not None:
                     loss = loss + term
                 loss, held = held_experts(loss, muts)
-                return loss, (correct, aux, held)
+                return loss, (correct, aux, held, mtp_loss)
 
         # what a remat=True model may keep of its forward pass is planned
         # against what this step holds beside it as the backward pass
@@ -297,7 +317,7 @@ def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
                  if strategy.traces_one_device else None)
         with remat_plan.step_memory("lm_train_step", beside.start, limit,
                                     beside.end):
-            (loss, (acc, aux, held)), grads = jax.value_and_grad(
+            (loss, (acc, aux, held, mtp_loss)), grads = jax.value_and_grad(
                 compute_loss, has_aux=True)(strategy.localize(state.params))
         with jax.named_scope("grad_sync"):
             grads = strategy.grad_sync(grads)
@@ -308,6 +328,8 @@ def make_lm_train_step(strategy: Strategy | None = None, seed: int = 0,
             metrics["moe_aux_loss"] = aux
         if held is not None:
             metrics.update(held)
+        if mtp_loss is not None:
+            metrics["mtp_loss"] = mtp_loss
         metrics = strategy.metric_sync(metrics)
         if guard is not None:
             with jax.named_scope("guard"):

@@ -32,12 +32,15 @@ for _path in sorted(BENCH_TESTS.glob("test_*.py")):
 
 def _qwen3_next_manifest_entries_by_name():
     """``benchmarks/tests/test_qwen3_next.py`` asks that the Qwen3-Next cell
-    and configuration be the *last* entries of ``BENCHMARK.json``'s lists.
-    They were when PR 29 wrote it; PR 33 appended another cell, and a PR
-    that adds to the benchmark may not edit a file the benchmark has.  So
-    the case is run here with the entries found by name (everything else
-    it asserts is kept); the positional lines are a `benchmark` PR's to
-    repair (PERF.md section 7)."""
+    and configuration be the *last* entries of ``BENCHMARK.json``'s lists,
+    and that its four metrics list that cell alone.  They did when PR 29
+    wrote it; PR 33 appended another cell, the grouped-matmul metrics
+    (``moe_gmm.*``) now list the JoyAI-LLM-Flash cell too (whose five expert
+    layers run the same kernels), and a PR that adds to the benchmark may
+    not edit a file the benchmark has.  So the case is run here with the
+    entries found by name and the second cell of ``moe_gmm.*`` named
+    (everything else it asserts is kept); the file's own lines are a
+    `benchmark` PR's to repair (PERF.md section 7)."""
     import json
     import os
 
@@ -46,8 +49,12 @@ def _qwen3_next_manifest_entries_by_name():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     mine = {m["name"]: m for m in manifest["per_layer"]
-            if m.get("workloads") == [module.CELL]}
+            if module.CELL in m.get("workloads", ())}
     assert sorted(mine) == sorted(module.READERS)
+    assert {name: m["workloads"] for name, m in mine.items()} == {
+        "gdn.ms": [module.CELL], "gdn_roofline": [module.CELL],
+        "moe_gmm.ms": [module.CELL, "joyaiflash-train-share32"],
+        "moe_gmm_roofline": [module.CELL, "joyaiflash-train-share32"]}
     assert {m["layer"] for m in mine.values()} == {"linear attention",
                                                    "experts"}
     assert all(m["moves"] == "train_tokens_per_s" for m in mine.values())
@@ -178,3 +185,38 @@ _rehearsal_line_with_the_host_metrics.pytestmark = _MODULES[
     "test_run_line"].test_rehearsal_prints_the_contracts_last_line.pytestmark
 test_run_line__rehearsal_prints_the_contracts_last_line = \
     _rehearsal_line_with_the_host_metrics
+
+
+def _host_manifest_entries_by_name():
+    """``benchmarks/tests/test_host_pauses.py`` asks that the ``host``
+    layer's three be the *last* entries of ``per_layer``.  The
+    multi-token-prediction reader ``mtp.ms`` was appended behind them, and
+    a PR that adds to the benchmark may not edit a file the benchmark has.
+    So the case is run here with the three found by name, in their order
+    (everything else it asserts is kept); the file's own line is a
+    `benchmark` PR's to repair (PERF.md section 7)."""
+    import json
+    import os
+
+    module = _MODULES["test_host_pauses"]
+    with open(os.path.join(_paths.ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    moves = {"setup.gc_s": ("s", "setup_s"),
+             "host.gc_ms": ("ms", "train_tokens_per_s"),
+             "host.gc_longest_ms": ("ms", "train_tokens_per_s")}
+    assert [m["name"] for m in manifest["per_layer"]
+            if m["layer"] == "host"] == list(module.HOST_READERS)
+    for name, (unit, moved) in moves.items():
+        assert by_name[name] == {
+            "name": name, "unit": unit, "better": "lower",
+            "source": "program_counter", "layer": "host", "moves": moved}
+        assert os.path.isfile(os.path.join(_paths.BENCH, "metrics",
+                                           name + ".py"))
+    with open(os.path.join(_paths.ROOT, "PERF.md")) as f:
+        perf = f.read()
+    assert all(name in perf for name in module.HOST_READERS)
+
+
+test_host_pauses__manifest_entries_of_the_host_metrics = \
+    _host_manifest_entries_by_name

@@ -30,7 +30,8 @@ from dtdl_tpu.models.transformer import TransformerLM
 from dtdl_tpu.obs import Observer
 from dtdl_tpu.obs.trace import (_ATTN_OTHER, _ATTN_PROJECTIONS, _GDN_MODULES,
                                  _KDA_MODULES, _MLA_PROJECTIONS,
-                                _MOE_MODULES, DEVICE_SCOPES, KERNEL_NAMES,
+                                _MOE_MODULES, _MTP_MODULES, DEVICE_SCOPES,
+                                KERNEL_NAMES,
                                 MODULE_SCOPES, STEP_NAMES, device_component)
 from dtdl_tpu.parallel import DataParallel, SingleDevice
 from dtdl_tpu.runtime import compile_cache
@@ -310,6 +311,25 @@ STACKS = [
     (_FWD + "block_3/block_3._hybrid/attn/kv_norm/mul", "norm", "forward"),
     (_FWD + "block_3/block_3._hybrid/attn/flash_fwd/pallas_call", "flash",
      "forward"),
+    # latent attention's query latent and the multi-token-prediction
+    # module, whose ops are the one component ``mtp`` but its block's
+    # kernels
+    (_FWD + "block_1/block_1._hybrid/attn/q_a/dot_general", "attn_proj",
+     "forward"),
+    (_BWD + "block_1/block_1._hybrid/attn/q_b/dot_general", "attn_proj",
+     "backward"),
+    (_REMAT + "block_1/block_1._hybrid/attn/q_a_norm/mul", "norm",
+     "recompute"),
+    (_FWD + "mtp/eh_proj/dot_general", "mtp", "forward"),
+    (_FWD + "mtp/hnorm/mul", "mtp", "forward"),
+    (_BWD + "mtp/checkpoint/rematted_computation/block/block._hybrid/attn/"
+     "q_b/dot_general", "mtp", "recompute"),
+    (_BWD + "mtp/block/block._hybrid/attn/flash_bwd_dq/pallas_call",
+     "flash", "backward"),
+    (_BWD + "mtp/block/block._hybrid/moe/cond/branch_0_fun/"
+     "transpose(rematted_computation)/jvp(experts)/moe_tgmm/pallas_call",
+     "moe_gmm", "backward"),
+    ("jit(lm_train_step)/transpose(jvp(mtp))/reduce_sum", "mtp", "backward"),
     # the step's own scalar bookkeeping and the rope table carry no scope
     ("jit(lm_train_step)/div", None, "forward"),
     ("jit(lm_train_step)/jvp(TransformerLM)/cos", None, "forward"),
@@ -379,8 +399,9 @@ def test_hybrid_module_scopes_are_the_models_own_modules():
         == set(_GDN_MODULES)
     assert set(linear["moe"]) == set(_MOE_MODULES)
     assert set(full["attn"]) == set(_ATTN_PROJECTIONS) | (
-        # kv_norm: latent attention's; gate_proj: a gate of its own
-        set(_ATTN_OTHER) - {"gate", "kv_norm", "gate_proj"})
+        # kv_norm, q_a_norm: latent attention's; gate_proj: a gate of its
+        # own
+        set(_ATTN_OTHER) - {"gate", "kv_norm", "q_a_norm", "gate_proj"})
     assert {"head", "embed", "ln_f"} == {k for k in params
                                          if not k.startswith("block_")}
     assert "head" in DEVICE_SCOPES and "gate" in DEVICE_SCOPES
@@ -436,7 +457,8 @@ def test_kimi_module_scopes_are_the_models_own_modules():
     assert set(mla) == {"ln_attn", "attn", "ln_mlp", "moe"}
     assert {n for n in kda["kda"] if n not in ("A_log", "dt_bias")} \
         == set(_KDA_MODULES)
-    assert set(mla["attn"]) == set(_MLA_PROJECTIONS) | {"kv_norm"}
+    assert set(mla["attn"]) == set(_MLA_PROJECTIONS) - {"q_a", "q_b"} | {
+        "kv_norm"}
     assert _ATTN_OTHER["kv_norm"] == "norm"
     assert set(mla["moe"]) == set(_MOE_MODULES)
     assert set(mla["moe"]["shared"]) == {"wi", "wg", "wo"}
@@ -463,6 +485,56 @@ def test_lowered_kimi_step_leaves_no_matmul_or_kernel_unscoped(dim):
                       "attn_proj", "flash", "mlp", "moe_gmm", "moe_router",
                       "moe_shared"):
         assert seen[component] >= every, (component, seen.get(component))
+
+
+def _joyai_lm(**over):
+    """Two latent-attention layers with a query latent and a rotation by
+    adjacent pairs, the first with a dense FFN, the second with held
+    experts, and a multi-token-prediction module."""
+    return _kimi_lm(**dict(dict(
+        layer_kinds=("mla", "mla"), mla_q_rank=12, mla_rope="pairs",
+        mtp_layers=1, mtp_loss_weight=0.3), **over))
+
+
+def test_joyai_module_scopes_are_the_models_own_modules():
+    """The audit for the JoyAI blocks: latent attention with a query latent has
+    ``q_a``, its norm and ``q_b`` in place of ``q``; the
+    multi-token-prediction module's children are :data:`_MTP_MODULES`, its
+    block is built as the model's last; ``mtp`` is a scope of the step's
+    too."""
+    params = jax.eval_shape(_joyai_lm().init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+    for name in ("block_0", "block_1"):
+        assert set(params[name]["attn"]) == set(_MLA_PROJECTIONS) - {
+            "q"} | {"q_a_norm", "kv_norm"}
+    assert _ATTN_OTHER["q_a_norm"] == "norm"
+    assert set(params["mtp"]) == set(_MTP_MODULES)
+    assert set(params["mtp"]["block"]) == set(params["block_1"])
+    assert {k for k in params if not k.startswith("block_")} == {
+        "embed", "head", "ln_f", "mtp"}
+    assert "mtp" in DEVICE_SCOPES
+
+
+def test_lowered_joyai_step_leaves_no_matmul_or_kernel_unscoped():
+    """The lowered train step of the JoyAI blocks: every ``dot_general``
+    and every op of a kernel lies under a component, the module's under
+    ``mtp`` but its block's kernels (the block is recomputed like any
+    other), the latent projections under ``attn_proj``."""
+    model = _joyai_lm(attn_impl="flash", remat=True, dtype=jnp.bfloat16)
+    tokens = jnp.zeros((2, 72), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens[:, :-1])["params"]
+    state = TrainState.create(apply_fn=model.apply, params=params,
+                              tx=optax.adamw(3e-4))
+    lowered = make_lm_train_step(SingleDevice()).lower(state,
+                                                       {"tokens": tokens})
+    seen = _passes_by_component(lowered)
+    every = {"forward", "recompute", "backward"}
+    for component in ("mtp", "attn_proj", "flash", "mlp", "moe_gmm",
+                      "moe_router", "moe_shared", "norm"):
+        assert seen[component] >= every, (component, seen.get(component))
+    assert {device_component(stack)[0] for _, stack, _ in
+            _op_stacks(lowered) if "/mtp/" in stack} == {
+        "mtp", "flash", "moe_gmm"}
 
 
 @pytest.mark.parametrize("gdn_dim, router_width", [
