@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from dtdl_tpu.models import remat_plan
-from dtdl_tpu.ops.attention import (band_tiles, flash_attention,
+from dtdl_tpu.ops.attention import (band_tiles, bwd_plan, flash_attention,
                                     mha_reference)
 from dtdl_tpu.ops.gated_delta import gated_delta_rule, kda_rule, stage_plan
 from dtdl_tpu.ops.grouped_matmul import (
@@ -49,6 +49,7 @@ from dtdl_tpu.ops.rope import apply_rope, rope_frequencies
 from dtdl_tpu.quant import (QuantDenseGeneral, canon_kv_dtype, kv_quantize,
                             kv_scale_dtype, weight_dtypes)
 from dtdl_tpu.runtime.compile_cache import (record_expert_buffer,
+                                            record_flash_bwd_path,
                                             record_gdn_path,
                                             record_kda_path,
                                             record_remat_plan,
@@ -85,6 +86,21 @@ def cache_max_seq(cache) -> int:
 
 def _part(init, *names):
     return nn.with_logical_partitioning(init, names)
+
+
+def _flash(q, k, v, **kw):
+    """Causal ``flash_attention``; in a train step's trace it also records
+    which backward the call's shapes choose
+    (runtime/compile_cache.py:record_flash_bwd_path)."""
+    step_name = remat_plan.traced_step_name()
+    if step_name is not None:
+        b, h, sq, d = q.shape
+        path, vmem = bwd_plan(sq, k.shape[2], d, v.shape[3], dtype=q.dtype,
+                              rope=kw.get("rope") is not None,
+                              window=kw.get("window"))
+        record_flash_bwd_path(step_name, path, (b, h, sq, d, v.shape[3]),
+                              vmem)
+    return flash_attention(q, k, v, causal=True, **kw)
 
 
 def _required_cache_leaf(name):
@@ -207,7 +223,7 @@ class Attention(nn.Module):
             # tile loads instead of round-tripping [B, H, S, D] through
             # HBM per layer (ops/attention.py); block shapes resolve from
             # the static autotune table keyed on (head_dim, seq, causal)
-            o = flash_attention(q, k, v, causal=True, rope=(cos, sin))
+            o = _flash(q, k, v, rope=(cos, sin))
         else:
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
@@ -298,7 +314,7 @@ class Attention(nn.Module):
                 record_window_call(
                     step_name, (q.shape[0], h) + q.shape[2:],
                     band_tiles(q.shape[2], k.shape[2], d, self.window))
-            o = flash_attention(q, k, v, causal=True, **banded)
+            o = _flash(q, k, v, **banded)
         else:
             o = mha_reference(q, k, v, causal=True,
                               **banded).astype(self.dtype)
@@ -1247,7 +1263,7 @@ class LatentAttention(nn.Module):
             q = jnp.concatenate([q[..., :self.nope_dim],
                                  turned(q[..., self.nope_dim:])], axis=-1)
         if self.attn_impl == "flash":
-            o = flash_attention(q, k, v, causal=True)
+            o = _flash(q, k, v)
         else:
             o = mha_reference(q, k, v, causal=True).astype(self.dtype)
         out = nn.DenseGeneral(

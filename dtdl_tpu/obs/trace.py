@@ -241,11 +241,14 @@ _ATTN_OTHER = {"gate": "attn_gate", "q_norm": "norm", "k_norm": "norm",
 # custom_call_target="tpu_custom_call"``), and it is a scope of the
 # kernel's own ops in a name stack.
 KERNEL_NAMES = {
-    "flash_fwd": "flash", "flash_bwd_dq": "flash", "flash_bwd_dkv": "flash",
-    # the same three under a window (a band under the diagonal): a component
-    # of their own, so that a trace tells the windowed layers from the full
-    "flash_swa_fwd": "flash_swa", "flash_swa_bwd_dq": "flash_swa",
-    "flash_swa_bwd_dkv": "flash_swa",
+    # the backward is one kernel (``flash_bwd``) where the row's dq fits its
+    # VMEM, else the pair (``ops/attention.py:bwd_plan``)
+    "flash_fwd": "flash", "flash_bwd": "flash", "flash_bwd_dq": "flash",
+    "flash_bwd_dkv": "flash",
+    # the same under a window (a band under the diagonal): a component of
+    # their own, so that a trace tells the windowed layers from the full
+    "flash_swa_fwd": "flash_swa", "flash_swa_bwd": "flash_swa",
+    "flash_swa_bwd_dq": "flash_swa", "flash_swa_bwd_dkv": "flash_swa",
     "paged_attn": "paged_attn",
     "moe_gmm": "moe_gmm", "moe_tgmm": "moe_gmm",
     "gdn_chunk_fwd": "gdn", "gdn_chunk_bwd": "gdn",

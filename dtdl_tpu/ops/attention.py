@@ -11,9 +11,15 @@ Design (the standard TPU flash decomposition):
   innermost (sequential) grid axis, so VMEM scratch carries the online-softmax
   state (running max ``m``, normalizer ``l``, accumulator ``acc``) across k
   steps.  O(S) memory instead of O(S²); the S×S score matrix never exists.
-* backward — two kernels with the same tiling: one accumulates ``dq`` over k
-  blocks, one accumulates ``dk``/``dv`` over q blocks, both recomputing the
-  probability tile from the saved logsumexp (no S×S residual is stored).
+* backward — one kernel (``flash_bwd``) on the grid ``(batch*heads,
+  k_blocks, q_blocks)``: each live tile's probabilities P and their
+  cotangent dS are rebuilt once from the saved logsumexp (no S×S residual
+  is stored) and feed all three gradients; ``dk``/``dv`` accumulate over
+  the q walk, ``dq`` of the whole row in f32 VMEM across the k blocks.  A
+  row whose ``dq`` would not fit (:func:`bwd_plan`, from shapes alone)
+  takes the pair it replaced: ``flash_bwd_dq`` accumulates ``dq`` over k
+  blocks, ``flash_bwd_dkv`` ``dk``/``dv`` over q blocks, each rebuilding
+  the tile.
 * causal masking skips whole tiles above the diagonal via ``pl.when``, and
   (round 13) the k/v **index maps clamp** masked iterations to the last
   useful block — consecutive grid steps that map to the same block elide
@@ -27,8 +33,8 @@ Design (the standard TPU flash decomposition):
   traffic) and the rotation is VPU work between the DMA and the matmul.
   The backward kernels re-rotate the saved UNROTATED q/k tiles on load
   (recompute, like the probability tiles) and apply the inverse rotation
-  to the accumulated dq/dk at finalize — rope is per-row orthogonal, so
-  its VJP is the same rotation with the angle negated.
+  to the accumulated dq/dk once each is whole — rope is per-row
+  orthogonal, so its VJP is the same rotation with the angle negated.
 * grid ``dimension_semantics`` mark the two outer axes ``parallel`` and the
   sequential (scratch-carrying) axis ``arbitrary``, so Mosaic's pipeliner
   double-buffers the next iteration's K/V tiles against the current tile's
@@ -624,26 +630,37 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                    scale, causal, block_q, block_k, seq_k, seq_q, off, rope,
-                    band=None):
-    if rope:
-        (qc_ref, qs_ref, kc_ref, ks_ref,
-         dk_ref, dv_ref, dk_scr, dv_scr, krot_scr) = rest
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+                scale, causal, block_q, block_k, seq_k, seq_q, off, rope,
+                band=None, with_dq=True):
+    """dq, dk and dv from one pass over the live tiles of the grid ``(b, k
+    block, q step)``: each tile's P and dS are built once and feed all
+    three.  dk and dv accumulate across the q walk; dq of the whole row in
+    f32 scratch across the k blocks (ascending, as ``_bwd_dq_kernel`` sums
+    it), written at the row's last step.  ``with_dq=False`` is the pair's
+    second kernel, ``flash_bwd_dkv``: the same walk without dq."""
+    tabs, rest = (rest[:4], rest[4:]) if rope else ((), rest)
+    qc_ref, qs_ref, kc_ref, ks_ref = tabs or (None,) * 4
+    if with_dq:
+        dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *krot = rest
     else:
-        dk_ref, dv_ref, dk_scr, dv_scr = rest
+        dk_ref, dv_ref, dk_scr, dv_scr, *krot = rest
     ki, step = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+    nk, nq = pl.num_programs(1), pl.num_programs(2)
     qi = step if band is None else band.q_block(ki, step)
+
+    if with_dq:
+        @pl.when((ki == 0) & (step == 0))
+        def _init_row():
+            dq_scr[:] = jnp.zeros_like(dq_scr)
 
     @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
         if rope:
-            # this grid holds the K tile fixed and walks q blocks, so
-            # here it is K that rotates once into scratch
-            krot_scr[:] = _rotate(k_ref[0], kc_ref[:], ks_ref[:])
+            # the K tile is fixed for the q walk: it rotates once
+            krot[0][:] = _rotate(k_ref[0], kc_ref[:], ks_ref[:])
 
     guard = ((qi + 1) * block_q + off > ki * block_k) if causal else (qi >= 0)
     if band is not None:
@@ -658,14 +675,19 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         do = do_ref[0]
         if rope:
             q = _rotate(q, qc_ref[:], qs_ref[:])
-            k = krot_scr[:]
+            k = krot[0][:]
         lse = lse_ref[0].reshape(block_q, 1)      # f32 (fwd out_shape)
         delta = delta_ref[0].reshape(block_q, 1)  # f32 (computed in _bwd)
+        # both tails zeroed: dS feeds dq through K and dk through Q, so a
+        # padded row on either side would reach a real one
         if seq_q % block_q:
             q = _zero_pad_rows(q, qi * block_q, seq_q)
             do = _zero_pad_rows(do, qi * block_q, seq_q)
             lse = _zero_pad_rows(lse, qi * block_q, seq_q)
             delta = _zero_pad_rows(delta, qi * block_q, seq_q)
+        if seq_k % block_k:
+            k = _zero_pad_rows(k, ki * block_k, seq_k)
+            v = _zero_pad_rows(v, ki * block_k, seq_k)
 
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
@@ -682,7 +704,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         p = jnp.exp(s - lse)                       # [bq, bk] f32
         dv_scr[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)    # [bk, d]
+            preferred_element_type=jnp.float32)    # [bk, dv]
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -690,6 +712,23 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dk_scr[:] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)    # [bk, d]
+        if not with_dq:
+            return
+        rows_q = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        dq_scr[rows_q] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)    # [bq, d]
+        if rope:
+            # the q block's last k block: its dq is whole, and the inverse
+            # rotation takes the q tables this step already holds
+            last = jnp.minimum(
+                jnp.maximum((qi + 1) * block_q + off - 1, 0) // block_k
+                if causal else nk - 1, nk - 1)
+
+            @pl.when(ki == last)
+            def _unrotate():
+                dq_scr[rows_q] = _unrotate_f32(dq_scr[rows_q], qc_ref[:],
+                                               qs_ref[:])
 
     @pl.when(step == nq - 1)
     def _finalize():
@@ -699,20 +738,85 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dk_ref[0] = dk.astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
+    if with_dq:
+        @pl.when((ki == nk - 1) & (step == nq - 1))
+        def _write_row():
+            dq_ref[0] = dq_scr[:seq_q].astype(dq_ref.dtype)
+
+
+# What the fused backward may hold in VMEM (:func:`fused_bwd_vmem_bytes`),
+# and the limit its call asks Mosaic for.  A v5e core has 128 MiB; the
+# other kernels here that raise the limit ask for 64 MiB.
+FUSED_BWD_VMEM_BUDGET = 48 << 20
+_FUSED_BWD_VMEM_LIMIT = 64 << 20
+
+
+def fused_bwd_vmem_bytes(seq_q: int, head_dim: int, value_dim: int,
+                         block_q: int, block_k: int, itemsize: int = 4,
+                         rope: bool = False) -> int:
+    """VMEM the fused backward holds at these shapes (blocks as the call
+    runs them): the row's f32 dq and its output block, double-buffered;
+    the tiles in and out, double-buffered; the dk/dv accumulators; and four
+    f32 score-sized tiles (S, P, dP, dS).  Sublanes round up to 8 and
+    lanes to 128, as VMEM lays them out."""
+    def tile(rows, cols, size):
+        return -(-rows // 8) * 8 * -(-cols // 128) * 128 * size
+
+    d, dv, bq, bk = head_dim, value_dim, block_q, block_k
+    row = tile(pl.cdiv(seq_q, bq) * bq, d, 4) + 2 * tile(seq_q, d, itemsize)
+    ins = (tile(bq, d, itemsize) + tile(bk, d, itemsize)
+           + tile(bk, dv, itemsize) + tile(bq, dv, itemsize)
+           + 2 * tile(1, bq, 4))
+    if rope:
+        ins += 2 * tile(bq, d, 4) + 2 * tile(bk, d, 4)
+    outs = tile(bk, d, itemsize) + tile(bk, dv, itemsize)
+    scratch = (tile(bk, d, 4) + tile(bk, dv, 4)
+               + (tile(bk, d, itemsize) if rope else 0))
+    return row + 2 * (ins + outs) + scratch + 4 * tile(bq, bk, 4)
+
+
+def bwd_plan(seq_q: int, seq_k: int, head_dim: int, value_dim: int, *,
+             dtype=jnp.bfloat16, causal: bool = True, rope: bool = False,
+             window: int | None = None, block_q: int | None = None,
+             block_k: int | None = None):
+    """``(path, vmem_bytes)`` of a :func:`flash_attention` call's backward,
+    from its shapes alone: ``"fused"`` (one kernel, ``flash_bwd`` or
+    ``flash_swa_bwd``) where :func:`fused_bwd_vmem_bytes` is within
+    :data:`FUSED_BWD_VMEM_BUDGET`, else ``"pair"`` (``*_bwd_dq`` and
+    ``*_bwd_dkv``, which hold a block of the row and not the row)."""
+    bq, bk = _call_blocks(head_dim, seq_q, seq_k, causal, block_q, block_k,
+                          window)
+    vmem = fused_bwd_vmem_bytes(seq_q, head_dim, value_dim, bq, bk,
+                                jnp.dtype(dtype).itemsize, rope)
+    return ("fused" if vmem <= FUSED_BWD_VMEM_BUDGET else "pair"), vmem
+
 
 def _bwd(scale, causal, block_q, block_k, res, do_4d, tabs=None,
          window=None):
     q, k, v, o, lse = res
-    bh, sq, d = q.shape
+    _, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
-    rope = tabs is not None
     band = _band_of(window, block_q, block_k, sq, sk)
     do = do_4d
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, None, :]          # [bh, 1, sq]
+    path, _ = bwd_plan(sq, sk, d, dv, dtype=q.dtype, rope=tabs is not None,
+                       block_q=block_q, block_k=block_k)
+    run = _bwd_fused if path == "fused" else _bwd_pair
+    return run(scale, causal, block_q, block_k, band,
+               (q, k, v, do, lse, delta), tabs)
 
+
+def _bwd_pair(scale, causal, block_q, block_k, band, operands, tabs):
+    """The backward as two ``pallas_call``s, for a row whose dq the fused
+    kernel cannot hold: ``_bwd_dq_kernel`` on the forward's grid, then
+    :func:`_bwd_kernel` without dq (``flash_bwd_dkv``) on the dkv grid."""
+    q, k, v, do = operands[:4]
+    bh, sq, d = q.shape
+    sk, dv = k.shape[1], v.shape[2]
+    rope = tabs is not None
     kmap = band.kmap if band else _kmaps(causal, block_q, block_k, sk - sq,
                                          lead_b=True)
     grid_dq = (bh, pl.cdiv(sq, block_q),
@@ -725,7 +829,6 @@ def _bwd(scale, causal, block_q, block_k, res, do_4d, tabs=None,
         pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
     ]
-    operands = (q, k, v, do, lse, delta)
     if rope:
         tmap = _kmaps(causal, block_q, block_k, sk - sq, lead_b=False)
         in_specs_dq += [
@@ -734,7 +837,6 @@ def _bwd(scale, causal, block_q, block_k, res, do_4d, tabs=None,
             pl.BlockSpec((block_k, d), tmap),
             pl.BlockSpec((block_k, d), tmap),
         ]
-        operands += tabs
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_k=sk,
@@ -748,43 +850,19 @@ def _bwd(scale, causal, block_q, block_k, res, do_4d, tabs=None,
                         + ([_vmem((block_q, d), q.dtype)] if rope else [])),
         interpret=_use_interpret(),
         **_pallas_kwargs(),
-    )(*operands)
-
-    nq = pl.cdiv(sq, block_q)
-    qmap = band.qmap if band else _qmaps(causal, block_q, block_k, sk - sq,
-                                         nq, lead_b=True)
-    qmap_s = _qmaps(causal, block_q, block_k, sk - sq, nq, lead_b=False)
-
-    def _lse_map(b, j, i):
-        bi, ii, _ = qmap(b, j, i)
-        return (bi, 0, ii)
+    )(*operands, *(tabs or ()))
 
     grid_dkv = (bh, pl.cdiv(sk, block_k),
-                nq if band is None else band.q_steps)
-    in_specs_dkv = [
-        pl.BlockSpec((1, block_q, d), qmap),
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        pl.BlockSpec((1, block_k, dv), lambda b, j, i: (b, j, 0)),
-        pl.BlockSpec((1, block_q, dv), qmap),
-        pl.BlockSpec((1, 1, block_q), _lse_map),
-        pl.BlockSpec((1, 1, block_q), _lse_map),
-    ]
-    operands = (q, k, v, do, lse, delta)
-    if rope:
-        in_specs_dkv += [
-            pl.BlockSpec((block_q, d), qmap_s),
-            pl.BlockSpec((block_q, d), qmap_s),
-            pl.BlockSpec((block_k, d), lambda b, j, i: (j, 0)),
-            pl.BlockSpec((block_k, d), lambda b, j, i: (j, 0)),
-        ]
-        operands += tabs
+                pl.cdiv(sq, block_q) if band is None else band.q_steps)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
+        functools.partial(_bwd_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_k=sk,
-                          seq_q=sq, off=sk - sq, rope=rope, band=band),
+                          seq_q=sq, off=sk - sq, rope=rope, band=band,
+                          with_dq=False),
         name="flash_bwd_dkv" if band is None else "flash_swa_bwd_dkv",
         grid=grid_dkv,
-        in_specs=in_specs_dkv,
+        in_specs=_dkv_specs(causal, block_q, block_k, band, sq, sk, d, dv,
+                            rope),
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_k, dv), lambda b, j, i: (b, j, 0)),
@@ -797,7 +875,78 @@ def _bwd(scale, causal, block_q, block_k, res, do_4d, tabs=None,
                         + ([_vmem((block_k, d), k.dtype)] if rope else [])),
         interpret=_use_interpret(),
         **_pallas_kwargs(),
-    )(*operands)
+    )(*operands, *(tabs or ()))
+    return dq, dk, dv
+
+
+def _dkv_specs(causal, block_q, block_k, band, sq, sk, d, dv, rope):
+    """In-specs of the grid ``(b, k block, q step)`` that the dkv kernel
+    and the fused kernel walk: the Q side clamped by :func:`_qmaps` (or
+    the band's own map), the K side fixed for a k block."""
+    nq = pl.cdiv(sq, block_q)
+    qmap = band.qmap if band else _qmaps(causal, block_q, block_k, sk - sq,
+                                         nq, lead_b=True)
+
+    def lse_map(b, j, i):
+        bi, ii, _ = qmap(b, j, i)
+        return (bi, 0, ii)
+
+    specs = [
+        pl.BlockSpec((1, block_q, d), qmap),
+        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+        pl.BlockSpec((1, block_k, dv), lambda b, j, i: (b, j, 0)),
+        pl.BlockSpec((1, block_q, dv), qmap),
+        pl.BlockSpec((1, 1, block_q), lse_map),
+        pl.BlockSpec((1, 1, block_q), lse_map),
+    ]
+    if rope:
+        qmap_s = _qmaps(causal, block_q, block_k, sk - sq, nq, lead_b=False)
+        specs += [
+            pl.BlockSpec((block_q, d), qmap_s),
+            pl.BlockSpec((block_q, d), qmap_s),
+            pl.BlockSpec((block_k, d), lambda b, j, i: (j, 0)),
+            pl.BlockSpec((block_k, d), lambda b, j, i: (j, 0)),
+        ]
+    return specs
+
+
+def _bwd_fused(scale, causal, block_q, block_k, band, operands, tabs):
+    """The backward as one ``pallas_call`` (:func:`_bwd_kernel`) on the
+    dkv grid.  The k axis carries the row's dq, so it is sequential too."""
+    q, k, v = operands[:3]
+    bh, sq, d = q.shape
+    sk, dv = k.shape[1], v.shape[2]
+    rope = tabs is not None
+    grid = (bh, pl.cdiv(sk, block_k),
+            pl.cdiv(sq, block_q) if band is None else band.q_steps)
+    vma = _vma_of(q, k, v, operands[3])
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, seq_k=sk,
+                          seq_q=sq, off=sk - sq, rope=rope, band=band),
+        name="flash_bwd" if band is None else "flash_swa_bwd",
+        grid=grid,
+        in_specs=_dkv_specs(causal, block_q, block_k, band, sq, sk, d, dv,
+                            rope),
+        out_specs=[
+            pl.BlockSpec((1, sq, d), lambda b, j, i: (b, 0, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, j, i: (b, j, 0)),
+        ],
+        out_shape=[
+            _sds((bh, sq, d), q.dtype, vma),
+            _sds((bh, sk, d), k.dtype, vma),
+            _sds((bh, sk, dv), v.dtype, vma),
+        ],
+        scratch_shapes=([_vmem((pl.cdiv(sq, block_q) * block_q, d),
+                               jnp.float32),
+                         _scratch(block_k, d)[2], _scratch(block_k, dv)[2]]
+                        + ([_vmem((block_k, d), k.dtype)] if rope else [])),
+        interpret=_use_interpret(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_FUSED_BWD_VMEM_LIMIT),
+    )(*operands, *(tabs or ()))
     return dq, dk, dv
 
 
@@ -907,8 +1056,9 @@ def band_tiles(seq_q: int, seq_k: int, head_dim: int, window: int,
                block_q: int | None = None,
                block_k: int | None = None) -> dict:
     """What a windowed call costs, from its shapes alone, a head a row: the
-    blocks it runs at; the sequential grid steps of the forward (and dq)
-    grid and of the dkv grid; of those the tiles each **computes** (its
+    blocks it runs at; the sequential grid steps of the forward (and the
+    pair's dq) grid and of the dkv grid (the fused backward's too); of
+    those the tiles each **computes** (its
     guard is true: the rest neither multiply nor fetch); the tiles that
     hold a pair of the band (``needed``: the least any kernel at these
     blocks computes) and its pairs; and the tiles a causal call at these
@@ -953,7 +1103,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
     accumulator and ``dv`` at the value's.  ``scale`` defaults to the
     query/key size's ``1 / sqrt``; the block table is keyed by that size.
 
-    Differentiable (custom VJP, recompute-based backward); O(seq) memory.
+    Differentiable (custom VJP, recompute-based backward: one kernel, or
+    the pair for a row too long for it, :func:`bwd_plan`); O(seq) memory.
     On the CPU platform the same kernel code runs under the Pallas
     interpreter (tests).
 
@@ -976,13 +1127,14 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
     ``window=W`` (with ``causal``, without ``rope``): query ``i``,
     bottom-aligned as above, sees keys ``j`` with ``i - W < j <= i``: ``W``
-    keys with its own.  The three kernels mask by the band, a tile wholly
+    keys with its own.  The kernels mask by the band, a tile wholly
     outside it is neither computed nor fetched from either side, and the
     sequential grid axis spans the blocks the band touches (three at two
     blocks' width), not the row: a call's cost follows from its shapes
-    (:func:`band_tiles`).  The calls are named ``flash_swa_fwd``,
-    ``flash_swa_bwd_dq``, ``flash_swa_bwd_dkv``.  ``window=None`` is the
-    call this function always made.
+    (:func:`band_tiles`).  The calls are named ``flash_swa_fwd`` and
+    ``flash_swa_bwd`` (or the pair ``flash_swa_bwd_dq``,
+    ``flash_swa_bwd_dkv``).  ``window=None`` is the call this function
+    always made.
     """
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]

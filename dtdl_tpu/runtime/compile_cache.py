@@ -56,7 +56,11 @@ the grid steps, the tiles computed beside the tiles the band needs and the
 tiles a causal call would compute): :func:`record_window_call`,
 :func:`window_calls`, and ``swa_calls``, ``swa_computed_tiles``,
 ``swa_needed_tiles``, ``swa_causal_tiles`` (a head a row, summed over the
-traced layers) in the totals.
+traced layers) in the totals.  Every flash call of a layer adds which
+backward its shapes chose (``ops/attention.py:bwd_plan``: one kernel, or
+the pair where the row's dq would not fit the kernel's VMEM):
+:func:`record_flash_bwd_path`, :func:`flash_bwd_paths`, and
+``flash_bwd_fused_calls``, ``flash_bwd_pair_calls`` in the totals.
 
 **The collector's pauses.**  The same call registers one callback on
 ``gc.callbacks``: each collection of the Python collector, in any thread,
@@ -113,6 +117,7 @@ _EXPERT_BUFFERS: list = []      # one a held-experts layer a traced step
 _GDN_PATHS: list = []           # one a call of the delta rule a traced step
 _KDA_PATHS: list = []           # one a call of the channel-wise rule
 _WINDOW_CALLS: list = []        # one a windowed attention layer
+_FLASH_BWD_PATHS: list = []     # one a flash call a traced step
 _listening = False
 
 
@@ -239,6 +244,22 @@ def record_window_call(fun_name: str, shapes: tuple, tiles: dict) -> None:
                               shapes=tuple(shapes)))
 
 
+def record_flash_bwd_path(fun_name: str, path: str, shapes: tuple,
+                          vmem_bytes: int) -> None:
+    """Keep which backward a flash call of the train step that was just
+    traced takes (models/transformer.py): ``path`` is ``"fused"`` or
+    ``"pair"``, ``shapes`` the call's ``(rows, heads, positions, head size,
+    value head size)``, ``vmem_bytes`` what the fused kernel would hold."""
+    _FLASH_BWD_PATHS.append({"fun_name": fun_name, "path": path,
+                             "shapes": tuple(shapes),
+                             "vmem_bytes": vmem_bytes})
+
+
+def flash_bwd_paths() -> list:
+    """The flash calls so far, oldest first (a copy)."""
+    return list(_FLASH_BWD_PATHS)
+
+
 def window_calls() -> list:
     """The windowed layers so far, oldest first (a copy)."""
     return list(_WINDOW_CALLS)
@@ -314,6 +335,10 @@ def compile_totals() -> dict:
         totals["swa_calls"] = len(_WINDOW_CALLS)
         for key in ("computed_tiles", "needed_tiles", "causal_tiles"):
             totals[f"swa_{key}"] = sum(c[key] for c in _WINDOW_CALLS)
+    if _FLASH_BWD_PATHS:
+        for path in ("fused", "pair"):
+            totals[f"flash_bwd_{path}_calls"] = sum(
+                r["path"] == path for r in _FLASH_BWD_PATHS)
     for rule, rows in (("gdn", _GDN_PATHS), ("kda", _KDA_PATHS)):
         for path in sorted({r["path"] for r in rows}):
             totals[f"{rule}_{path}_calls"] = sum(

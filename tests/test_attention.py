@@ -6,8 +6,28 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import dtdl_tpu.ops.attention as attention
 from dtdl_tpu.ops.attention import flash_attention, mha_reference
 from dtdl_tpu.ops.rope import apply_rope, rope_frequencies
+
+# the backward a call takes: one kernel (``flash_bwd``), or the pair
+# (``flash_bwd_dq``, ``flash_bwd_dkv``), which a budget of 0 forces
+PATHS = ["fused", "pair"]
+
+
+def _budget(monkeypatch, budget):
+    """Set the fused backward's budget; jax's caches go with the old one,
+    which a function traced before would otherwise keep."""
+    monkeypatch.setattr(attention, "FUSED_BWD_VMEM_BUDGET", budget)
+    jax.clear_caches()
+
+
+@pytest.fixture(params=PATHS)
+def path(request, monkeypatch):
+    if request.param == "pair":
+        _budget(monkeypatch, 0)
+    yield request.param
+    jax.clear_caches()
 
 
 def _rand(shape, seed=0):
@@ -42,7 +62,7 @@ def test_flash_forward_matches_dense(causal):
                                atol=2e-6, rtol=1e-5)
 
 
-def test_flash_grads_match_dense():
+def test_flash_grads_match_dense(path):
     q, k, v = (_rand((1, 1, 256, 16), s) for s in range(3))
 
     def loss(fn):
@@ -60,7 +80,7 @@ def test_flash_grads_match_dense():
 @pytest.mark.parametrize("dims, causal", [
     ((192, 128), True), ((192, 128), False), ((24, 16), True),
     ((16, 40), True)])
-def test_flash_takes_a_value_head_size_of_its_own(dims, causal):
+def test_flash_takes_a_value_head_size_of_its_own(dims, causal, path):
     """Latent attention's shapes: scores over the query/key size, the
     output, the accumulator and ``dv`` at the value's, over a ragged
     multi-block grid; values and all three gradients against the dense
@@ -105,7 +125,7 @@ _BANDS = [
     "narrower_than_a_block", "a_block_wide", "wider_than_a_block",
     "wider_than_the_row", "padded_tail", "tall_blocks", "wide_blocks",
     "more_keys_than_queries", "its_own_key_alone"])
-def test_windowed_flash_matches_dense(sq, sk, window, bq, bk):
+def test_windowed_flash_matches_dense(sq, sk, window, bq, bk, path):
     """The three kernels under a band against ``mha_reference`` with the
     same window: the output and all three gradients."""
     from dtdl_tpu.ops.attention import band_tiles
@@ -170,10 +190,11 @@ def test_a_window_is_a_band_under_the_causal_diagonal_alone():
 
 # sha256 of the lowered text of the call without a window, forward and all
 # three gradients, as the parent of PR 35 lowers it (bf16, 2 heads of 32,
-# 300 positions, 128 x 128 blocks; plain and with the fused rotation)
+# 300 positions, 128 x 128 blocks; plain and with the fused rotation),
+# computed anew when the backward became one kernel (``flash_bwd``)
 _PARENT_TEXT = {
-    False: "0dddf4ae5852f4f57c756c6407377b87fd9dd42325d2f68e86ee67a336f9b0e9",
-    True: "4d3858374a9f8e8d5b5417e7d5a1dca3f8875d5cc9b4846a4ef055ff34e5580d",
+    False: "8317d6b983d87acff641cb7565608490fbb82644c21e631ae0dfe1b021beb1b0",
+    True: "cebb1d447fa146ef9352c675b630ed05f6bdf6e6667d6e242196fa18e6fccf32",
 }
 
 
@@ -216,7 +237,7 @@ def test_windowed_block_table_entries():
 
 
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_cross_attention(causal):
+def test_flash_cross_attention(causal, path):
     """q shorter than k/v; causal must be bottom-aligned like the oracle.
     q gets a ragged 128+32 grid, k/v a ragged 2.5-block grid."""
     q = _rand((2, 2, 160, 16), 0)
@@ -241,7 +262,7 @@ def test_flash_cross_attention(causal):
 
 
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_ragged_blocks(causal):
+def test_flash_ragged_blocks(causal, path):
     # seq not a multiple of the block size exercises padded edge tiles
     q, k, v = (_rand((1, 1, 200, 32), s) for s in range(3))
     out = flash_attention(q, k, v, causal=causal, block_q=128, block_k=128)
@@ -261,7 +282,7 @@ def test_flash_ragged_blocks(causal):
                                    atol=5e-5, rtol=1e-4)
 
 
-def test_flash_bf16_forward_and_grads():
+def test_flash_bf16_forward_and_grads(path):
     """bf16 inputs exercise the native-dtype matmul paths (the astype calls
     at every dot site are no-ops under f32); f32 reference with loose
     tolerance bounds the bf16 rounding."""
@@ -291,7 +312,7 @@ def test_flash_bf16_forward_and_grads():
 
 
 @pytest.mark.parametrize("causal", [True, False])
-def test_fused_rope_matches_unfused(causal):
+def test_fused_rope_matches_unfused(causal, path):
     """rope=(cos, sin) fused into the kernels == apply_rope outside then
     the plain kernels, fwd AND grads — the round-13 fusion contract.
     f32 is exact (the in-kernel rotation is the same f32 arithmetic);
@@ -324,7 +345,7 @@ def test_fused_rope_matches_unfused(causal):
 
 
 @pytest.mark.slow
-def test_fused_rope_ragged_and_cross():
+def test_fused_rope_ragged_and_cross(path):
     """Odd shapes through the fused path: a ragged 200-row tail tile and
     a cross-attention 160/320 (q bottom-aligned, the default positions:
     unfused parity needs apply_rope(q, offset=sk-sq)).  slow: four
@@ -378,7 +399,7 @@ def test_fused_rope_explicit_positions():
 
 
 @pytest.mark.slow
-def test_fused_rope_bf16():
+def test_fused_rope_bf16(path):
     """bf16 through the fused kernels: XLA may fold the rotate→cast→dot
     chain differently than the pre-rotated path (observed: ~0.03% of
     elements one bf16 ulp apart), so the pin is one-ulp-loose against
@@ -474,3 +495,171 @@ def test_ring_attention_bf16_matches_dense():
                         v.astype(jnp.float32), causal=True)
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
                                atol=5e-2, rtol=5e-2)
+
+
+# ---------------------------------------------------------------------------
+# the backward: one kernel, or the pair where the row's dq does not fit
+# ---------------------------------------------------------------------------
+
+def _rope(d):
+    return dict(rope=rope_frequencies(d, 512))
+
+
+# (q shape, k/v positions, value head, flash_attention's keywords, dtype):
+# the geometries above, each small
+_GEOMETRIES = {
+    "causal": ((1, 2, 256, 32), 256, 32, {}, jnp.float32),
+    "not_causal": ((1, 2, 256, 32), 256, 32, dict(causal=False),
+                   jnp.float32),
+    "ragged": ((1, 1, 200, 32), 200, 32, {}, jnp.float32),
+    "ragged_not_causal": ((1, 1, 200, 32), 200, 32, dict(causal=False),
+                          jnp.float32),
+    "more_keys": ((1, 2, 160, 16), 320, 16, {}, jnp.float32),
+    "more_queries": ((1, 2, 320, 16), 160, 16, {}, jnp.float32),
+    "value_head_192_128": ((1, 1, 300, 192), 300, 128, {}, jnp.float32),
+    "value_head_16_40": ((1, 2, 300, 16), 300, 40, dict(causal=False),
+                         jnp.float32),
+    "band_tall_blocks": ((1, 1, 511, 32), 511, 32,
+                         dict(window=200, block_q=256), jnp.float32),
+    "band_more_keys": ((1, 1, 200, 32), 456, 32, dict(window=64),
+                       jnp.float32),
+    "rope": ((1, 2, 256, 32), 256, 32, _rope(32), jnp.float32),
+    "rope_ragged": ((1, 2, 200, 16), 200, 16, _rope(16), jnp.float32),
+    "rope_cross": ((1, 2, 160, 16), 320, 16, _rope(16), jnp.float32),
+    "rope_positions": ((1, 2, 256, 16), 256, 16, dict(
+        _rope(16), rope_positions=(jnp.arange(256) * 2,) * 2), jnp.float32),
+    "rope_bf16": ((1, 2, 256, 32), 256, 32, _rope(32), jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("name", list(_GEOMETRIES))
+def test_the_fused_backward_equals_the_pairs(name, monkeypatch):
+    """The one kernel and the pair walk the same tiles in the same order and
+    sum in f32 in the same order (dq over ascending k blocks, dk and dv over
+    ascending q blocks): on the interpreter all three gradients agree to
+    the bit, and each path ran the kernels of its name."""
+    from test_device_names import _pallas_names
+    shape, sk, dv, kw, dtype = _GEOMETRIES[name]
+    kw = dict(dict(causal=True, block_q=128, block_k=128), **kw)
+    q = _rand(shape, 0).astype(dtype)
+    k = _rand(shape[:2] + (sk, shape[3]), 1).astype(dtype)
+    v = _rand(shape[:2] + (sk, dv), 2).astype(dtype)
+    grad = jax.grad(_sq_loss(lambda q, k, v: flash_attention(q, k, v, **kw)),
+                    (0, 1, 2))
+    fused = grad(q, k, v)
+    names = _pallas_names(jax.make_jaxpr(grad)(q, k, v).jaxpr)
+    _budget(monkeypatch, 0)
+    pair = grad(q, k, v)
+    swa = "swa_" if "window" in kw else ""
+    assert names == [f"flash_{swa}fwd", f"flash_{swa}bwd"]
+    assert _pallas_names(jax.make_jaxpr(grad)(q, k, v).jaxpr)[1:] == [
+        f"flash_{swa}bwd_dq", f"flash_{swa}bwd_dkv"]
+    for a, b in zip(fused, pair):
+        assert a.dtype == b.dtype == dtype
+        assert np.all(np.isfinite(np.asarray(a, np.float32)))
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    jax.clear_caches()
+
+
+def _cell_model(name):
+    """The cell's model as the benchmark's runner builds it, and its rows
+    and positions a step (benchmarks/runners/train.py:make_plan)."""
+    import json
+    import os
+    import sys
+    from dtdl_tpu.models.transformer import TransformerLM
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from lib import modules
+
+    def load(*path):
+        with open(os.path.join(bench, *path)) as f:
+            return json.load(f)
+    cell = load("workloads", name + ".json")
+    traffic = load("traffic", cell["traffic"] + ".json")
+    cfg = load("configs", cell["config"] + ".json")
+    family = modules.load_file(os.path.join(
+        bench, "families", cfg["model_type"] + ".py"), "families")
+    model = TransformerLM(dtype=jnp.bfloat16, **family.model_kwargs(cfg, True))
+    return model, traffic["batch_per_chip"], traffic["row_tokens"] - 1
+
+
+# the benchmark's eight cells: the flash calls a step's forward makes, as
+# (rows, heads, positions, query/key head, value head) and how many
+_CELL_CALLS = {
+    "olmo1b-train-b4s2048": {(4, 16, 2047, 128, 128): 8},
+    "olmo1b-train-b4s2048-zipf": {(4, 16, 2047, 128, 128): 8},
+    "olmo1b-train-ddp4": {(4, 16, 2047, 128, 128): 8},
+    "olmo7b-train-b2s2048": {(2, 32, 2047, 128, 128): 2},
+    "qwen3next-train-share16": {(2, 16, 4095, 256, 256): 1},
+    "kimilinear-train-share32": {(2, 32, 4095, 192, 128): 1},
+    "trinitymini-train-share16": {(2, 32, 8191, 128, 128): 5},
+    "joyaiflash-train-share32": {(2, 32, 4095, 192, 128): 5,
+                                 (2, 32, 4094, 192, 128): 1},
+}
+
+
+@pytest.mark.parametrize("cell", list(_CELL_CALLS))
+def test_each_cells_attention_takes_the_fused_backward(cell):
+    """Every flash call of every cell's step takes the one kernel: the
+    compile account keeps one row a call traced in a train step, each
+    ``fused``, with the call's shapes and the VMEM the kernel holds, within
+    the budget and under the limit the call asks Mosaic for."""
+    from dtdl_tpu.models import remat_plan
+    from dtdl_tpu.runtime import compile_cache
+    model, rows, positions = _cell_model(cell)
+    tokens = jax.ShapeDtypeStruct((rows, positions), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    before = len(compile_cache.flash_bwd_paths())
+    with remat_plan.step_memory("lm_train_step", 0, None):
+        jax.eval_shape(model.apply, params, tokens)
+    got = compile_cache.flash_bwd_paths()[before:]
+    calls = {}
+    for row in got:
+        calls[row["shapes"]] = calls.get(row["shapes"], 0) + 1
+        assert (row["fun_name"], row["path"]) == ("lm_train_step", "fused")
+        assert 0 < row["vmem_bytes"] <= attention.FUSED_BWD_VMEM_BUDGET \
+            < attention._FUSED_BWD_VMEM_LIMIT
+    assert calls == _CELL_CALLS[cell]
+    totals = compile_cache.compile_totals()
+    assert totals["flash_bwd_fused_calls"] >= len(got)
+
+
+def test_a_row_over_the_budget_takes_the_pair(monkeypatch):
+    """The choice follows the shapes alone: the same call takes the pair
+    once its row's dq no longer fits the budget, and so does a row of 64K
+    positions at the budget as it is; a train step's account says which."""
+    from dtdl_tpu.models import remat_plan
+    from dtdl_tpu.models.transformer import TransformerLM
+    from dtdl_tpu.runtime import compile_cache
+    from test_device_names import _pallas_names
+    path, vmem = attention.bwd_plan(256, 256, 32, 32, dtype=jnp.float32)
+    assert path == "fused"
+    assert attention.bwd_plan(65536, 65536, 128, 128)[0] == "pair"
+    assert attention.bwd_plan(8191, 8191, 128, 128)[0] == "fused"
+    _budget(monkeypatch, vmem - 1)
+    assert attention.bwd_plan(256, 256, 32, 32, dtype=jnp.float32) == (
+        "pair", vmem)
+    q = _rand((1, 2, 256, 32))
+    grad = jax.grad(_sq_loss(lambda q, k, v: flash_attention(q, k, v)),
+                    (0, 1, 2))
+    assert _pallas_names(jax.make_jaxpr(grad)(q, q, q).jaxpr) == [
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+    model = TransformerLM(vocab_size=64, d_model=64, n_layers=2, n_heads=2,
+                          d_ff=64, max_seq=256, attn_impl="flash",
+                          dtype=jnp.float32)
+    tokens = jax.ShapeDtypeStruct((1, 256), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    before = len(compile_cache.flash_bwd_paths())
+    with remat_plan.step_memory("lm_train_step", 0, None):
+        jax.eval_shape(model.apply, params, tokens)
+    rotated = attention.bwd_plan(256, 256, 32, 32, dtype=jnp.float32,
+                                 rope=True)
+    assert rotated[0] == "pair" and rotated[1] > vmem
+    assert compile_cache.flash_bwd_paths()[before:] == [
+        {"fun_name": "lm_train_step", "path": "pair",
+         "shapes": (1, 2, 256, 32, 32), "vmem_bytes": rotated[1]}] * 2
+    assert compile_cache.compile_totals()["flash_bwd_pair_calls"] >= 2
+    jax.clear_caches()

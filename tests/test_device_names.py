@@ -228,6 +228,8 @@ STACKS = [
      "pallas_call", "flash", "backward"),
     (_BWD + "jvp(TransformerLM)/checkpoint/block_3/attn/flash_bwd_dkv/"
      "pallas_call", "flash", "backward"),
+    (_BWD + "jvp(TransformerLM)/checkpoint/block_3/attn/flash_bwd/"
+     "pallas_call", "flash", "backward"),
     (_BWD + "jvp(TransformerLM)/checkpoint/rematted_computation/block_1/"
      "mlp/wg/dot_general", "mlp", "recompute"),
     (_BWD + "jvp(TransformerLM)/checkpoint/block_1/mlp/wo/dot_general",
@@ -264,6 +266,8 @@ STACKS = [
     (_FWD + "block_3/block_3._hybrid/attn/attn._grouped_attend/gate/mul",
      "attn_gate", "forward"),
     (_BWD + "block_3/block_3._hybrid/attn/attn._grouped_attend/flash_bwd_dq/"
+     "pallas_call", "flash", "backward"),
+    (_BWD + "block_3/block_3._hybrid/attn/attn._grouped_attend/flash_bwd/"
      "pallas_call", "flash", "backward"),
     (_BWD + "block_1/block_1._hybrid/moe/experts/moe_tgmm/pallas_call",
      "moe_gmm", "backward"),
@@ -325,6 +329,8 @@ STACKS = [
     (_BWD + "mtp/checkpoint/rematted_computation/block/block._hybrid/attn/"
      "q_b/dot_general", "mtp", "recompute"),
     (_BWD + "mtp/block/block._hybrid/attn/flash_bwd_dq/pallas_call",
+     "flash", "backward"),
+    (_BWD + "mtp/block/block._hybrid/attn/flash_bwd/pallas_call",
      "flash", "backward"),
     (_BWD + "mtp/block/block._hybrid/moe/cond/branch_0_fun/"
      "transpose(rematted_computation)/jvp(experts)/moe_tgmm/pallas_call",
@@ -585,11 +591,21 @@ def _pallas_names(jaxpr, out=None):
     return out
 
 
+# the backward's names: one kernel where the row's dq fits the budget, the
+# pair where it does not (``ops/attention.py:bwd_plan``)
+_BWD_NAMES = {"fused": ["flash_bwd"], "pair": ["flash_bwd_dq", "flash_bwd_dkv"]}
+
+
+@pytest.mark.parametrize("path", ["fused", "pair"])
 @pytest.mark.parametrize("fused_rope", [False, True], ids=["plain", "rope"])
-def test_flash_pallas_calls_carry_their_names(fused_rope):
+def test_flash_pallas_calls_carry_their_names(fused_rope, path, monkeypatch):
+    import dtdl_tpu.ops.attention as attention
     from dtdl_tpu.ops.attention import flash_attention
     from dtdl_tpu.ops.rope import rope_frequencies
 
+    if path == "pair":
+        monkeypatch.setattr(attention, "FUSED_BWD_VMEM_BUDGET", 0)
+        jax.clear_caches()
     q = jnp.zeros((1, 2, 16, 8), jnp.float32)
     rope = rope_frequencies(8, 16) if fused_rope else None
 
@@ -597,8 +613,9 @@ def test_flash_pallas_calls_carry_their_names(fused_rope):
         return flash_attention(q, k, v, causal=True, rope=rope).sum()
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
-    assert _pallas_names(jaxpr.jaxpr) == [
-        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+    names = _pallas_names(jaxpr.jaxpr)
+    assert names == ["flash_fwd"] + _BWD_NAMES[path]
+    assert {KERNEL_NAMES[n] for n in names} == {"flash"}
 
 
 def test_paged_pallas_call_carries_its_name():
@@ -613,9 +630,10 @@ def test_paged_pallas_call_carries_its_name():
                                   jnp.ones(b, jnp.int32), scale=0.25))(
         jnp.zeros((b, h, 1, d), jnp.float32))
     assert _pallas_names(jaxpr.jaxpr) == ["paged_attn"]
-    assert set(KERNEL_NAMES) == {"flash_fwd", "flash_bwd_dq",
+    assert set(KERNEL_NAMES) == {"flash_fwd", "flash_bwd", "flash_bwd_dq",
                                  "flash_bwd_dkv", "flash_swa_fwd",
-                                 "flash_swa_bwd_dq", "flash_swa_bwd_dkv",
+                                 "flash_swa_bwd", "flash_swa_bwd_dq",
+                                 "flash_swa_bwd_dkv",
                                  "paged_attn",
                                  "moe_gmm", "moe_tgmm",
                                  "gdn_chunk_fwd", "gdn_chunk_bwd",
@@ -704,7 +722,7 @@ def test_the_lowered_tpu_step_holds_kda_kernels_at_kernel_sized_heads_alone(
                           monkeypatch)
     assert taken["kda_chunk_fwd"] >= 1 and taken["kda_chunk_bwd"] >= 1
     assert KERNEL_NAMES.keys() >= taken.keys() >= {
-        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "moe_gmm", "moe_tgmm"}
+        "flash_fwd", "flash_bwd", "moe_gmm", "moe_tgmm"}
     small = _mosaic_calls(_kimi_lm(**common), 73, monkeypatch)
     assert small["flash_fwd"] >= 1
     for step in (taken, small):
@@ -811,9 +829,14 @@ def test_every_op_of_the_rehearsed_trinity_step_has_a_component():
     assert not inside, inside[:5]
 
 
-def test_windowed_flash_pallas_calls_carry_their_names():
+@pytest.mark.parametrize("path", ["fused", "pair"])
+def test_windowed_flash_pallas_calls_carry_their_names(path, monkeypatch):
+    import dtdl_tpu.ops.attention as attention
     from dtdl_tpu.ops.attention import flash_attention
 
+    if path == "pair":
+        monkeypatch.setattr(attention, "FUSED_BWD_VMEM_BUDGET", 0)
+        jax.clear_caches()
     q = jnp.zeros((1, 2, 16, 8), jnp.float32)
 
     def loss(q, k, v):
@@ -821,8 +844,8 @@ def test_windowed_flash_pallas_calls_carry_their_names():
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
     names = _pallas_names(jaxpr.jaxpr)
-    assert names == ["flash_swa_fwd", "flash_swa_bwd_dq",
-                     "flash_swa_bwd_dkv"]
+    assert names == ["flash_swa_fwd"] + [
+        n.replace("flash_", "flash_swa_") for n in _BWD_NAMES[path]]
     assert {KERNEL_NAMES[n] for n in names} == {"flash_swa"}
 
 
@@ -834,8 +857,8 @@ def test_the_lowered_tpu_step_holds_windowed_and_full_flash_calls(
     common = dict(attn_impl="flash", remat=True, dtype=jnp.bfloat16)
     taken = _mosaic_calls(_windowed_lm(**common), 73, monkeypatch)
     assert KERNEL_NAMES.keys() >= taken.keys() >= {
-        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_swa_fwd",
-        "flash_swa_bwd_dq", "flash_swa_bwd_dkv", "moe_gmm", "moe_tgmm"}
+        "flash_fwd", "flash_bwd", "flash_swa_fwd", "flash_swa_bwd",
+        "moe_gmm", "moe_tgmm"}
     plain = _mosaic_calls(_windowed_lm(layer_windows=(), **common), 73,
                           monkeypatch)
     assert plain["flash_fwd"] >= 1
@@ -887,10 +910,11 @@ def test_compile_account_rows_totals_and_single_registration():
 
     whole = compile_cache.compile_totals()
     # (and the newest checkpoint plan, experts' buffer and delta-rule paths, where a step of
-    # this process made one: tests/test_remat_plan.py, test_qwen3_next.py)
+    # this process made one: tests/test_remat_plan.py, test_qwen3_next.py;
+    # the flash calls' backward paths: tests/test_attention.py)
     assert ({k for k in whole
              if not k.startswith(("remat_", "moe_", "gdn_", "kda_",
-                                  "swa_", "gc_"))}
+                                  "swa_", "flash_bwd_", "gc_"))}
             == set(compile_cache.ACCOUNT_EVENTS.values()))
     assert whole["compile_trace_s"] > 0 and whole["compile_backend_s"] > 0
     summary = Observer().summary()
@@ -924,6 +948,7 @@ def test_compile_totals_count_a_nested_trace_and_a_retrieval_once(
     monkeypatch.setattr(compile_cache, "_GDN_PATHS", [])
     monkeypatch.setattr(compile_cache, "_KDA_PATHS", [])
     monkeypatch.setattr(compile_cache, "_WINDOW_CALLS", [])
+    monkeypatch.setattr(compile_cache, "_FLASH_BWD_PATHS", [])
 
     def totals():       # the collector's own: tests/test_gc_account.py
         return {k: v for k, v in compile_cache.compile_totals().items()

@@ -109,7 +109,7 @@ def test_the_ring_holds_its_bound_and_the_totals_never_roll_out(monkeypatch):
 def test_compile_totals_carry_the_collector_exactly(monkeypatch):
     _fresh(monkeypatch)
     for name in ("_ROWS", "_PLANS", "_EXPERT_BUFFERS", "_GDN_PATHS",
-                 "_KDA_PATHS", "_WINDOW_CALLS"):
+                 "_KDA_PATHS", "_WINDOW_CALLS", "_FLASH_BWD_PATHS"):
         monkeypatch.setattr(compile_cache, name, [])
     assert compile_cache.compile_totals() == {}
     monkeypatch.setattr(compile_cache, "time", _Clock(
